@@ -1,12 +1,11 @@
-"""Local Mosaic/XLA-TPU compile check — NO device or claim needed.
+"""Ask the TPU's compiler about the opt-in Pallas programs — no chip.
 
 libtpu ships in this image, so the real TPU compiler (including
-Mosaic's jaxpr->vreg pipeline) runs locally against a compile-only
-v5e topology. This is how the 'Invalid vector register cast' in the
-bool Kogge-Stone recode was found and fixed in minutes after weeks of
-blind 70-second remote probes and wedged claims (PERF.md session 2).
+Mosaic's jaxpr->vreg pipeline) runs here against a compile-only v5e
+topology. This is how the 'Invalid vector register cast' in the bool
+Kogge-Stone recode was found and fixed.
 
-Run on CPU only: env PYTHONPATH= JAX_PLATFORMS=cpu python scripts/aot_check.py
+Run on CPU only: JAX_PLATFORMS=cpu python scripts/aot_check.py
 
 Checks, each compiled under shard_map over a 4-chip v5e:2x2 mesh
 (batch axis sharded — the production layout of parallel/sharding.py):
@@ -15,9 +14,11 @@ Checks, each compiled under shard_map over a 4-chip v5e:2x2 mesh
   sr-hybrid   — _verify_tile_sr with the same Pallas dual-mult
   monolithic  — verify_pallas (whole tile in one kernel)
 
-All three compile as of 2026-07-31 (~35s / ~38s / ~22s) after two
-bool-lattice fixes: the i1-vreg concatenate in _recode_signed and the
-scalar-True i8 select in _lt_const_dev.
+All three compile on the installed JAX 0.9.0 / libtpu 0.0.34 (about
+half a minute each here). They are too slow for tier-1; the default XLA programs at
+real widths are compiled by tests/test_chip_compile.py instead. A
+compile that passes is not a chip run: none of the three has executed
+on hardware.
 """
 
 from __future__ import annotations
@@ -37,7 +38,6 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tendermint_tpu.ops import sr25519_kernel as S
@@ -56,12 +56,12 @@ def main() -> int:
 
     def aot(inner, name, rows):
         nonlocal failures
-        fn = shard_map(
+        fn = jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(P(None, "x"),) * 3,
             out_specs=P("x"),
-            check_rep=False,
+            check_vma=False,
         )
         args = [
             jax.ShapeDtypeStruct(

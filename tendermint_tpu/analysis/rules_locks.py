@@ -80,7 +80,7 @@ class LockDaemonThread(Rule):
     title = "Thread/Timer without daemon=True"
     rationale = (
         "A non-daemon worker blocks interpreter exit: a breaker probe "
-        "timer or gather watchdog parked on a wedged device claim "
+        "timer or gather watchdog parked on a hung device "
         "would hang node shutdown forever. Every background thread in "
         "this codebase must be a daemon (threading.Timer takes no "
         "daemon kwarg — assign `t.daemon = True` before start())."

@@ -139,7 +139,7 @@ def _classify_subprocess(call: ast.Call):
 def _classify_device_sync(call: ast.Call):
     return (
         UNBOUNDED,
-        "device sync point: a wedged claim parks the caller until the "
+        "device sync point: a hung device parks the caller until the "
         "runtime gives up",
     )
 

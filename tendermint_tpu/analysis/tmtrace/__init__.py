@@ -1,9 +1,9 @@
 """tmtrace — whole-program device-dispatch proof.
 
-The TPU claim has been wedged for rounds; the dispatch layer is the
+Chip time is scarce; the dispatch layer is the
 code that executes *least* yet carries the north-star number, so a
-trace error or recompilation storm discovered mid-claim burns the one
-granted hour. PRs 4-6 machine-proved the consensus side (sign-bytes
+trace error or recompilation storm discovered on the chip burns the
+budget it was meant to measure with. PRs 4-6 machine-proved the consensus side (sign-bytes
 taint, wire schemas, races); tmtrace is the same move applied to the
 JAX side, on the same substrate (the PR-5 call graph):
 

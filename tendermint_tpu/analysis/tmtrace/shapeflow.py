@@ -13,8 +13,8 @@ reported twice):
    float()` conversions, `.item()`/`.tolist()`, and `np.asarray`/
    `np.array` on ARRAY values are trace-time errors (ConcretizationError
    or a silent constant-fold) that only detonate when the root is
-   finally jitted on a device claim — exactly what the no-TPU gate
-   exists to catch *before* the claim. Shape reads (`.shape`, `.ndim`,
+   finally jitted on a device — exactly what the no-TPU gate
+   exists to catch *before* a chip run. Shape reads (`.shape`, `.ndim`,
    `len()` of a traced array) are static during tracing and do not
    taint.
 

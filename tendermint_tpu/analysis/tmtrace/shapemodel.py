@@ -395,6 +395,18 @@ def _build_model() -> Dict[str, RootModel]:
         # entries; mesh placement is proven by the divisibility gate
         lambda full: [],
     )
+    add(
+        "parallel/sharding.py:sha512_fixed",
+        "fast",
+        lambda: [
+            f"sharded(sig axis): 64+M x base bucket {b} -> "
+            "roundup(b, mesh) per mesh size M∈msg-len"
+            for b in _buckets()
+        ],
+        # no direct trace: the body is the ed25519_kernel sha512 entry,
+        # partitioned like the tile it feeds
+        lambda full: [],
+    )
     return model
 
 

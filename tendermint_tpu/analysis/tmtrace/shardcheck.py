@@ -3,13 +3,13 @@ donated buffers.
 
 The sharded dispatch path (parallel/sharding.py) partitions the
 bucketed device programs over a 1-D `sig` mesh. Three properties die
-silently if an edit breaks them, and each only detonates once a
-multi-chip claim is finally granted — so they are gates here:
+silently if an edit breaks them, and each only detonates on the
+first multi-chip run — so they are gates here:
 
 - **trace-mesh-axis** (static): every axis name appearing in a
   `PartitionSpec(...)` must be declared by some `Mesh(..., (<axes>,))`
   in the package. An undeclared axis raises at dispatch time on the
-  first sharded call — i.e. mid-claim. Axis names are resolved
+  first sharded call — i.e. on the chips. Axis names are resolved
   through module-level string constants (`SIG_AXIS = "sig"`), the
   import aliases `P`/`PartitionSpec`, and constant tuples.
 
@@ -140,7 +140,7 @@ def mesh_axis_violations(pkg: Package) -> List[Violation]:
                     f"PartitionSpec axis '{axis}' is not declared by "
                     f"any Mesh in the package (declared: "
                     f"{sorted(declared) or 'none'}) — dispatch would "
-                    "raise on the first sharded call, i.e. mid-claim"
+                    "raise on the first sharded call, i.e. on the chips"
                 ),
                 source=src,
             )

@@ -2,8 +2,8 @@
 
 Every device program must *trace* (build a jaxpr through abstract
 evaluation) before it can compile, and every trace failure a device
-campaign would hit is reproducible on CPU with `jax.eval_shape` — no
-backend, no claim, no hour burned. This module drives eval_shape over
+run would hit is reproducible on CPU with `jax.eval_shape` — no
+backend, no chip time spent. This module drives eval_shape over
 the shapemodel's concrete (root × bucket) cases and converts
 exceptions into `trace-compile-fail` violations, plus the live
 bucket-divisibility check (shardcheck) that needs the real sharded
@@ -20,10 +20,9 @@ Two tiers (rationale in shapemodel.py):
 
 - full (`scripts/lint.py --trace-full`, bench.py `trace_all_buckets`):
   every declared root × bucket — ~6-8 s of pure tracing per crypto
-  tile per bucket, minutes total. This IS the campaign pre-flight:
-  run it (or read its freshest bench row) before `device_wait` gets a
-  claim, so the granted hour starts at compilation, not at the first
-  trace error. An optional budget stops the sweep late rather than
+  tile per bucket, minutes total. This IS the pre-flight of a chip
+  run: run it (or read its freshest bench row) first, so chip time
+  starts at compilation, not at the first trace error. An optional budget stops the sweep late rather than
   hanging a bench run; whatever was skipped is listed in
   stats["skipped_budget"].
 
@@ -80,8 +79,8 @@ def run_cases(
                     message=(
                         f"jit root `{case.rid}` fails to trace at "
                         f"{case.label}: {msg} — this is the error a "
-                        "device claim would hit mid-campaign; fix it "
-                        "on CPU first"
+                        "chip run would hit at its first dispatch; "
+                        "fix it on CPU first"
                     ),
                     source="",
                 )

@@ -44,8 +44,8 @@ def register_device_factory(
     key_type: str, factory: Callable[[int], Optional[BatchVerifier]]
 ) -> None:
     # tmlint: disable=lock-global-mutation — single GIL-atomic dict
-    # write from install(), a main-thread seam (PERF.md claim
-    # discipline keeps install off worker threads)
+    # write from install(), a main-thread seam (nodes install at
+    # construction, never from worker threads)
     _DEVICE_FACTORIES[key_type] = factory
 
 
@@ -76,7 +76,7 @@ def cpu_factory(key_type: str) -> Optional[Callable[[], BatchVerifier]]:
 # but on a CPU-backed kernel the padding waste inverts the win.
 # The value may be provided lazily (set_group_affinity_fn): deciding
 # it can require jax backend initialization, which must not happen at
-# install() time — a wedged device claim would hang node startup.
+# install() time — a device that hangs there would hang node startup.
 _GROUP_AFFINITY: Optional[int] = 1
 _GROUP_AFFINITY_FN: Optional[Callable[[], int]] = None
 _GROUP_AFFINITY_EXPLICIT = False
@@ -117,8 +117,8 @@ def group_affinity() -> int:
         if value is not None:
             return value
         # resolve the deferred fn OUTSIDE the lock: it may initialize
-        # the jax backend (slow, possibly wedged) and must never park
-        # every verify path behind one device claim
+        # the jax backend (slow, possibly hung) and must never park
+        # every verify path behind it
         computed = max(1, int(fn())) if fn is not None else 1
         with _affinity_lock:
             if _GROUP_AFFINITY is not None:

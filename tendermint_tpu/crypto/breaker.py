@@ -14,8 +14,8 @@ State machine (docs/resilience.md has the full diagram):
       │                  └────────probe failed────────┤
       └───────────────────probe succeeded─────────────┘
 
-Policy, inherited from the machinery it replaces (the device-claim
-discipline in PERF.md — "never pile onto a wedged claim"):
+Policy, inherited from the machinery it replaces ("never pile onto
+a hung device"):
 
 - OPEN serves every caller a CPU fallback instantly; nobody waits.
 - Re-arming is probed by ONE background thread, never by consensus

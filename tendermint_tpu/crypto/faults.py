@@ -2,8 +2,8 @@
 
 The north star puts consensus-critical crypto on an accelerator, which
 makes the dispatch/gather boundary of crypto/tpu_verifier.py a new
-Byzantine surface: the XLA runtime can raise, the device (or its
-tunnel) can wedge, and a mis-compiled or mis-sharded program can return
+Byzantine surface: the XLA runtime can raise, the device can hang,
+and a mis-compiled or mis-sharded program can return
 wrong-shaped or bit-flipped results. Tendermint tolerates 1/3 Byzantine
 validators; this module exists so the test suite can prove the port
 tolerates Byzantine *devices* too — the same treat-the-offload-engine-
@@ -124,7 +124,7 @@ class DeviceFault(RuntimeError):
 
 
 class DeviceTimeout(DeviceFault):
-    """A gather exceeded its deadline (hung device / lost tunnel)."""
+    """A gather exceeded its deadline (hung device)."""
 
 
 _RAISE_MODES = {"raise", "io_error"}

@@ -9,8 +9,8 @@ addVote -> batch_accumulate -> tpu_dispatch -> merkle_hash with
 per-stage attributes (batch size, pad waste, host-prep vs device-wall
 split, and the verified-signature cache's sigcache_hits /
 sigcache_misses on batch_accumulate — the count of triples that skipped
-crypto entirely vs. those actually assembled into the batch). PERF.md's claim discipline is the motivation: device sessions
-die mid-run, so every surviving number must be attributable to a stage.
+crypto entirely vs. those actually assembled into the batch). The motivation: a run that
+dies midway must leave every surviving number attributable to a stage.
 
 Completed spans land in a bounded ring (old spans are evicted, never
 blocked on) and export as Chrome-trace JSON (chrome://tracing /

@@ -5,10 +5,11 @@ runner's transport), each with a REAL TCP JSON-RPC listener on an
 ephemeral 127.0.0.1 port — load flows over actual HTTP/websocket so the
 per-route metrics recorded in rpc/jsonrpc.py measure the same code path
 production traffic takes. The device verifier stays OFF
-(`tpu.enable=false`): the load harness must never initialize the jax
-backend (bench.py's banked CPU block runs it before the device probe —
-a wedged claim hangs backend init), and single-validator-scale commits
-never reach the batch threshold anyway.
+(`tpu.enable=false`): bench.py runs this harness in its jax-free CPU
+block, and commits at this validator count never reach the batch
+threshold anyway. No served benchmark has had the device on yet
+(ROADMAP S3/D7); chip_smoke.py's node phase is the one place a served
+node runs with it.
 """
 
 from __future__ import annotations

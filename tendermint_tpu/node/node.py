@@ -145,12 +145,16 @@ class Node(Service):
                     "min_batch; overriding process-wide",
                     prior=prior, new=cfg.tpu.min_batch_size,
                 )
+            from ..ops import compile_cache, merkle_kernel
+
+            # before the first compile: without it a node recompiles
+            # every bucket (about half a minute each on a v5e) at
+            # every start
+            compile_cache.enable()
             tpu_verifier.install(
                 min_batch=cfg.tpu.min_batch_size,
                 mesh=self._device_mesh(cfg.tpu.devices),
             )
-            from ..ops import merkle_kernel
-
             merkle_kernel.install()
         elif tpu_verifier.installed() is not None:
             self.logger.info(

@@ -240,6 +240,11 @@ class Sr25519Verifier:
             self._compiled[size] = fn
         return fn
 
+    def _place(self, rows):
+        """Host byte rows -> the device array the program takes (see
+        Ed25519Verifier._place; the mesh verifier shards here)."""
+        return jnp.asarray(rows)
+
     def verify(
         self,
         pubkeys: Sequence[bytes],
@@ -296,7 +301,7 @@ class Sr25519Verifier:
 
         ok = run_with_pallas_fallback(
             prog,
-            (jnp.asarray(pk_b), jnp.asarray(sig_b), jnp.asarray(k_b)),
+            (self._place(pk_b), self._place(sig_b), self._place(k_b)),
             is_pallas=(
                 _JIT_VERIFY_SR_HYBRID is not None
                 and prog is _JIT_VERIFY_SR_HYBRID

@@ -1,30 +1,30 @@
-"""Toolchain capability probes for the Mosaic/XLA lowering contracts.
+"""Toolchain capability probe for the Mosaic/XLA lowering contracts.
 
 The kernels in this package keep their jaxprs free of primitives
 Mosaic cannot lower (scatter, gather, dynamic_slice, rev, rank-1
-iota — each found the hard way on hardware, PERF.md). That contract
-is enforced by tests/test_ops_pallas.py::test_mosaic_jaxpr_clean, but
-the *jaxpr a given jax version produces for the same source* is not
-stable: jax 0.4.37 lowers a static slice written with a
-zero-width ellipsis (`x[..., :-1, :]` on a rank-2 array — the
-field25519 carry-pass idiom) to `gather`, where newer versions emit
-`slice`. On such a toolchain the cleanliness check cannot
-distinguish "our code regressed" from "the tracer spells static
-slices differently", so the test must skip — with the probe result
-recorded, not silently.
+iota — each found the hard way on hardware). That contract is
+enforced by tests/test_ops_pallas.py::test_mosaic_jaxpr_clean, but
+the *jaxpr a given jax produces for the same source* is a property of
+the tracer, not of this code: a tracer may spell a static slice
+written with a zero-width ellipsis (`x[..., :-1, :]` on a rank-2
+array — the field25519 carry-pass idiom) as `gather` where another
+emits `slice`. The cleanliness check cannot then tell "our code
+regressed" from "the tracer spells static slices differently", so it
+asks this probe first and records the answer.
 
 `mosaic_probe()` traces a catalog of known-clean constructs (each one
 an idiom the kernels actually use, none of which *semantically*
 needs a banned primitive) and reports which banned primitives the
-installed toolchain introduces for them. A non-empty `introduced`
-map means jaxpr-level cleanliness checks are meaningless on this
-toolchain; the device campaign's AOT path (scripts/aot_check.py, on
-real hardware) remains the ground truth there.
+installed toolchain introduces for them. The one supported
+installation is JAX 0.9.0 with libtpu 0.0.34, where the probe is
+clean; a non-empty `introduced` map would mean jaxpr-level
+cleanliness checks are meaningless here, and the chip's own compiler
+(tests/test_chip_compile.py, scripts/aot_check.py) is the ground
+truth either way.
 
 The probe is cheap (<100 ms after jax import), touches no backend
 (pure abstract tracing of constant-free functions), and its result
-rides in the bench JSON (`mosaic_probe` key) so every BENCH_* record
-names the toolchain capability it was measured under.
+rides in the bench JSON (`mosaic_probe` key).
 """
 
 from __future__ import annotations

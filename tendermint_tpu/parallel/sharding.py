@@ -67,9 +67,35 @@ class _MeshSharded:
         bucket_sizes: Optional[Sequence[int]] = None,
     ) -> None:
         self.mesh = mesh
+        self._sha512 = None
         n = mesh.devices.size
         sizes = bucket_sizes or self._DEFAULT_SIZES
         super().__init__(sorted({-(-s // n) * n for s in sizes}))
+
+    def _mat(self) -> NamedSharding:
+        """Batch axis is MINOR (see field25519 layout note): every
+        program takes (rows, N) byte matrices, sharded over N."""
+        return NamedSharding(self.mesh, P(None, SIG_AXIS))
+
+    def _place(self, rows):
+        """Shard host rows over the mesh straight from the host: a
+        plain jnp.asarray would land the whole batch on the first
+        device and leave the program to reshard it."""
+        return jax.device_put(rows, self._mat())
+
+    def _sha512_program(self):
+        """SHA-512 partitioned like the tile, so every device hashes
+        its own shard and the digests never gather on the first one
+        (only the ed25519 verifier hashes on device)."""
+        if self._sha512 is None:
+            from ..ops.sha512_kernel import sha512_fixed
+
+            self._sha512 = jax.jit(
+                sha512_fixed,
+                in_shardings=self._mat(),
+                out_shardings=self._mat(),
+            )
+        return self._sha512
 
     def _bucket(self, n: int) -> int:
         b = super()._bucket(n)
@@ -79,15 +105,13 @@ class _MeshSharded:
     def _program(self, size: int):
         fn = self._compiled.get(size)
         if fn is None:
-            # batch axis is MINOR (see field25519 layout note): the
-            # program takes (32, N) pk bytes, (64, N) sig bytes, and a
-            # (64|32, N) digest/challenge matrix, returns the (N,) bitmap
-            vec = NamedSharding(self.mesh, P(SIG_AXIS))
-            mat = NamedSharding(self.mesh, P(None, SIG_AXIS))
+            # (32, N) pk bytes, (64, N) sig bytes, and a (64|32, N)
+            # digest/challenge matrix in; the (N,) bitmap out
+            mat = self._mat()
             fn = jax.jit(
                 type(self)._TILE_FN,
                 in_shardings=(mat, mat, mat),
-                out_shardings=vec,
+                out_shardings=NamedSharding(self.mesh, P(SIG_AXIS)),
             )
             self._compiled[size] = fn
         return fn

@@ -1,6 +1,6 @@
 """Seeded sharding mismatch: the mesh declares only the `sig` axis
 but one PartitionSpec names `model` — dispatch would raise on the
-first sharded call, mid-claim."""
+first sharded call, on the chips."""
 
 import numpy as np
 

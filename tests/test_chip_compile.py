@@ -1,0 +1,210 @@
+"""The chip's compiler, asked without a chip.
+
+libtpu is installed here, and it compiles for a TPU v5e that is only
+described (`jax.experimental.topologies`), not attached. These tests
+hand it the device programs of the node's main path at the widths
+chip_smoke.py drives them at, so what the compiler would refuse on the
+chip — a shape, a memory budget, a partitioning — is refused here, at
+no chip time. A compile that passes is not a chip run.
+
+Two things steer the compiles, both in the tests and not in the
+program: `jax.default_backend` is patched to "tpu" while a program is
+traced, because the SHA kernels pick their fully unrolled form from it
+(ops/sha512_kernel.py, ops/sha256_kernel.py) and the CPU suite
+otherwise only ever sees the scan form; and the persistent compile
+cache is off around the compiles, because an entry compiled for a
+described chip cannot be read back without one.
+
+Width: on an accelerator `_TpuBatchVerifier.add()` launches a dispatch
+per full STREAM_CHUNK (2048), so the 10,000-validator mixed commit —
+5,000 signatures a key class — reaches the device as three 2048-lane
+batches a class, never as one 8192 or 12288 bucket. That is the bucket
+compiled here. The merkle programs are the first level of a
+10,000-leaf root (5,000 pairs, padded to 8192) and the proof batch of
+all 10,000 leaves (16384 lanes by 16 levels).
+
+The topology is described inside a fixture, once this file's tests
+have started, and nowhere at import time: only one process may load
+libtpu, the suite's workers each import every test file, and the one
+worker that is handed this file must be the only one that loads it.
+"""
+
+import os
+from unittest import mock
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+# a v5e chip has 16 GB; one program may take a quarter of it, so the
+# other buckets' programs and the batches in flight fit beside it
+HBM_BYTES = 16 * 10**9
+PROGRAM_BUDGET = HBM_BYTES // 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    # or libtpu writes its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(topo):
+    """compile(fn, *shapes) -> (lowered, compiled), traced as on a TPU
+    and with the persistent cache off for the module's duration."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def compile(fn, *shapes):
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            lowered = fn.lower(*shapes)
+        return lowered, lowered.compile()
+
+    yield compile
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def lanes():
+    """The bucket a streamed chunk lands in (see module docstring)."""
+    from tendermint_tpu.config import DEFAULT_BUCKET_SIZES
+    from tendermint_tpu.crypto.tpu_verifier import _TpuBatchVerifier
+    from tendermint_tpu.ops.ed25519_kernel import bucket_for
+
+    n = bucket_for(_TpuBatchVerifier.STREAM_CHUNK, DEFAULT_BUCKET_SIZES)
+    assert n == 2048
+    return n
+
+
+def _rows(rows: int, n: int, sharding, dtype=jnp.uint8):
+    return jax.ShapeDtypeStruct((rows, n), dtype, sharding=sharding)
+
+
+def _assert_fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = (
+        m.generated_code_size_in_bytes
+        + m.temp_size_in_bytes
+        + m.argument_size_in_bytes
+        + m.output_size_in_bytes
+    )
+    assert 0 < total < PROGRAM_BUDGET, m
+    return total
+
+
+def test_ed25519_tile(compile_for_chip, one_chip, lanes):
+    from tendermint_tpu.ops import ed25519_kernel as K
+
+    _lowered, compiled = compile_for_chip(
+        jax.jit(K._verify_tile),
+        _rows(32, lanes, one_chip),
+        _rows(64, lanes, one_chip),
+        _rows(64, lanes, one_chip),
+    )
+    _assert_fits(compiled)
+
+
+def test_sr25519_tile(compile_for_chip, one_chip, lanes):
+    from tendermint_tpu.ops import sr25519_kernel as SR
+
+    _lowered, compiled = compile_for_chip(
+        jax.jit(SR._verify_tile_sr),
+        _rows(32, lanes, one_chip),
+        _rows(64, lanes, one_chip),
+        _rows(32, lanes, one_chip),
+    )
+    _assert_fits(compiled)
+
+
+def test_sha512_unrolled_at_the_smokes_sign_bytes(
+    compile_for_chip, one_chip, lanes
+):
+    """SHA-512 over R || A || sign-bytes at the one sign-bytes length
+    every phase of chip_smoke.py signs — and in the unrolled form the
+    chip gets, which no CPU test otherwise compiles."""
+    import chip_smoke as S
+    from tendermint_tpu.ops.sha512_kernel import sha512_fixed
+
+    privs, vals = S.make_validators(1, seed=0)
+    commit = S.sign_commit(privs, vals, S._block_id(1), 1, S.BASE_TIME_NS)
+    (sign_bytes,) = commit.sign_bytes_batch(S.CHAIN_ID)
+    lowered, compiled = compile_for_chip(
+        jax.jit(sha512_fixed),
+        _rows(64 + len(sign_bytes), lanes, one_chip),
+    )
+    assert "stablehlo.while" not in lowered.as_text()
+    _assert_fits(compiled)
+
+
+def test_merkle_root_first_level_of_10k_leaves(compile_for_chip, one_chip):
+    from tendermint_tpu.ops import merkle_kernel as MK
+    from tendermint_tpu.ops import sha256_kernel as S256
+
+    pairs = MK._bucket(10_000 // 2)
+    assert pairs == 8192
+    lowered, compiled = compile_for_chip(
+        jax.jit(S256.inner_hash_batch),
+        _rows(32, pairs, one_chip),
+        _rows(32, pairs, one_chip),
+    )
+    assert "stablehlo.while" not in lowered.as_text()
+    _assert_fits(compiled)
+
+
+def test_merkle_proof_batch_of_10k_leaves(compile_for_chip, one_chip):
+    from tendermint_tpu.ops import merkle_kernel as MK
+
+    k = MK._bucket(10_000)
+    depth = MK._bucket(len(MK._sides_for(0, 10_000)))
+    assert (k, depth) == (16384, 16)
+    _lowered, compiled = compile_for_chip(
+        MK._verify_program,
+        _rows(32, k, one_chip),
+        jax.ShapeDtypeStruct((depth, 32, k), jnp.uint8, sharding=one_chip),
+        _rows(depth, k, one_chip, jnp.int32),
+    )
+    _assert_fits(compiled)
+
+
+def test_sharded_ed25519_tile_on_four_chips(compile_for_chip, topo, lanes):
+    """`[tpu] devices = 4`: ShardedEd25519Verifier's own program over a
+    mesh of the four described chips. The compiled module must hold the
+    batch axis partitioned — each chip a quarter of the lanes — and no
+    chip the whole batch."""
+    from tendermint_tpu.parallel import ShardedEd25519Verifier, make_mesh
+
+    mesh = make_mesh(topo.devices)
+    assert mesh.devices.size == 4
+    v = ShardedEd25519Verifier(mesh)
+    n = v._bucket(lanes)
+    mat = NamedSharding(mesh, P(None, "sig"))
+    _lowered, compiled = compile_for_chip(
+        v._program(n),
+        _rows(32, n, mat),
+        _rows(64, n, mat),
+        _rows(64, n, mat),
+    )
+    text = compiled.as_text()
+    per_chip = n // 4
+    assert f"u8[32,{per_chip}]" in text and f"u8[64,{per_chip}]" in text
+    assert f"u8[32,{n}]" not in text and f"u8[64,{n}]" not in text
+    _assert_fits(compiled)

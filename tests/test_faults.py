@@ -12,7 +12,7 @@ containment layer (docs/resilience.md):
   cache;
 - a tripped breaker routes new work to CPU with zero device touches,
   re-arms through a single-flight probe, and never admits traffic onto
-  a possibly-wedged claim before its backoff (the probe-delay policy
+  a possibly-hung device before its backoff (the probe-delay policy
   the old trip_sr_singles machinery implemented by hand);
 - fault-path metrics count only work the device actually completed.
 """

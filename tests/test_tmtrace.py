@@ -52,6 +52,7 @@ EXPECTED_ROOT_IDS = {
     "ops/sr25519_kernel.py:functools.partial(_verify_tile_sr, "
     "dual_fn=dual_mult_pallas)",
     "parallel/sharding.py:type(self)._TILE_FN",
+    "parallel/sharding.py:sha512_fixed",
 }
 
 
