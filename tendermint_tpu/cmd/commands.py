@@ -1015,12 +1015,17 @@ def cmd_debug_dump(args) -> int:
 
 def _capture_device_profile(tar, n: int = 256) -> dict:
     """Run one warmed batch through the device verifier under the XLA
-    profiler and pack the trace into the bundle (TensorBoard-loadable)."""
+    profiler and pack the trace into the bundle (TensorBoard-loadable).
+    Span tracing is on for the length of the capture, mirrored into the
+    profiler's trace, so the device's events sit beside the program's
+    own `pack_rows` / `device_launch` spans and not bare runtime names;
+    the same spans land in the bundle's trace.json."""
     import tempfile
 
     import jax
 
     from ..crypto.ed25519 import PrivKeyEd25519
+    from ..libs import trace
     from ..ops.ed25519_kernel import Ed25519Verifier
 
     pks, msgs, sigs = [], [], []
@@ -1037,10 +1042,19 @@ def _capture_device_profile(tar, n: int = 256) -> dict:
     if not bool(ok.all()):
         raise RuntimeError("profile batch failed to verify")
     with tempfile.TemporaryDirectory(prefix="tt-device-profile-") as prof_dir:
-        with jax.profiler.trace(prof_dir):
-            t0 = time.perf_counter()
-            verifier.verify(pks, msgs, sigs)
-            run_s = time.perf_counter() - t0
+        was_on = trace.is_enabled()
+        held = trace.set_mirror(jax.profiler.TraceAnnotation)
+        trace.enable()
+        try:
+            with jax.profiler.trace(prof_dir):
+                t0 = time.perf_counter()
+                with trace.span("debug_profile_batch", batch=n):
+                    verifier.verify(pks, msgs, sigs)
+                run_s = time.perf_counter() - t0
+        finally:
+            trace.set_mirror(held)
+            if not was_on:
+                trace.disable()
         for root, _dirs, files in os.walk(prof_dir):
             for f in files:
                 full = os.path.join(root, f)

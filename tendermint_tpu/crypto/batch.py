@@ -13,6 +13,7 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
+from ..libs import trace
 from .keys import BatchVerifier, PubKey
 
 __all__ = [
@@ -186,18 +187,17 @@ def drain_and_cache(verifier: BatchVerifier, cache_keys) -> tuple:
     ok, bits = verifier.verify()
     if getattr(verifier, "faulted", False):
         return ok, bits
-    if ok:
-        sigcache.add_keys_bulk(
-            [key for key in cache_keys if key is not None]
-        )
-    else:
-        sigcache.add_keys_bulk(
-            [
+    with trace.span("sigcache_populate") as span:
+        if ok:
+            proven = [key for key in cache_keys if key is not None]
+        else:
+            proven = [
                 key
                 for key, bit in zip(cache_keys, bits)
                 if bit and key is not None
             ]
-        )
+        sigcache.add_keys_bulk(proven)
+        span.set(keys=len(proven))
     return ok, bits
 
 

@@ -60,9 +60,9 @@ _m_verify_time = M.new_histogram(
 )
 # dispatch telemetry: decompose verify_seconds into the host-side
 # assembly (packing triples into device arrays + async launch) and the
-# device wall (gather barrier) — the split PERF.md demands before any
-# device number is believed — plus bucket-padding waste and
-# warm-generation hit/miss for compile-stall attribution.
+# host's wait at the gather barrier — a wait, not device time: the
+# device's own time is read from a profiler trace — plus bucket-padding
+# waste and warm-generation hit/miss for compile-stall attribution.
 _m_host_prep = M.new_histogram(
     "tpu",
     "host_prep_seconds",
@@ -70,10 +70,10 @@ _m_host_prep = M.new_histogram(
     buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
              0.025, 0.05, 0.1, 0.25),
 )
-_m_device_wall = M.new_histogram(
+_m_gather_wait = M.new_histogram(
     "tpu",
-    "device_wall_seconds",
-    "Device wall time (gather barrier) of one batch.",
+    "gather_wait_seconds",
+    "Host time blocked at the gather barrier of one batch.",
     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
              0.25, 0.5, 1.0, 2.5),
 )
@@ -482,14 +482,22 @@ class _TpuBatchVerifier(BatchVerifier):
                 and hasattr(v, "gather")
                 and _breaker(self.KEY_TYPE).state() == _breaker_mod.CLOSED
             ):
-                try:
-                    self._dispatch_pending(v)
-                except Exception as e:
-                    # a faulted async launch must not raise out of
-                    # add() — its contract is malformed-input errors
-                    # only. The window stays queued; verify() sees the
-                    # recorded fault and drains everything on CPU.
-                    self._stream_fault = e
+                with trace.span(
+                    "tpu_stream_dispatch",
+                    key=self.KEY_TYPE,
+                    n=len(self._pks),
+                    chunk=len(self._handles),
+                ) as span:
+                    try:
+                        self._dispatch_pending(v)
+                    except Exception as e:
+                        # a faulted async launch must not raise out of
+                        # add() — its contract is malformed-input
+                        # errors only. The window stays queued;
+                        # verify() sees the recorded fault and drains
+                        # everything on CPU.
+                        self._stream_fault = e
+                    span.set(bucket=self._last_bucket)
 
     def verify(self) -> Tuple[bool, List[bool]]:
         """Drains the queue: a verifier is a one-shot batch (matching
@@ -499,12 +507,14 @@ class _TpuBatchVerifier(BatchVerifier):
         dispatch + gather barrier (chunk dispatches already ran inside
         add, overlapped with the caller's assembly loop).
 
-        The tpu_dispatch span (and the host_prep/device_wall
-        histograms) split the wall time at the async-launch boundary:
-        everything before the handle exists is host packing, everything
-        after is the device barrier. Backings without the
-        dispatch()/gather() pair (injected test verifiers) report one
-        undivided wall time.
+        The tpu_dispatch span splits at the async-launch boundary:
+        everything before the last handle exists is host packing
+        (`host_prep_s`, tpu_host_prep_seconds), everything after is the
+        `tpu_gather` child span, the host blocked on the device
+        (tpu_gather_wait_seconds). The chunks add() streamed earlier
+        are `tpu_stream_dispatch` spans of their own. Backings without
+        the dispatch()/gather() pair (injected test verifiers) report
+        one undivided wall time.
 
         Any device fault — including a mis-shaped bitmap or a lane the
         device invalidated that the CPU disproves — re-verifies the
@@ -547,15 +557,24 @@ class _TpuBatchVerifier(BatchVerifier):
                     host_prep = time.perf_counter() - t0
                     got: List[bool] = []
                     try:
-                        for bv, handle, n in self._handles:
-                            lane = _gather_guarded(bv, handle, self.KEY_TYPE)
-                            if len(lane) != n:
-                                raise DeviceFault(
-                                    f"mis-shaped device result: "
-                                    f"{len(lane)} lanes for {n} signatures"
+                        with trace.span(
+                            "tpu_gather",
+                            hist=_m_gather_wait,
+                            handles=len(self._handles),
+                            sigs=total,
+                        ):
+                            for bv, handle, n in self._handles:
+                                lane = _gather_guarded(
+                                    bv, handle, self.KEY_TYPE
                                 )
-                            got.extend(lane)
-                            device_sigs += n
+                                if len(lane) != n:
+                                    raise DeviceFault(
+                                        f"mis-shaped device result: "
+                                        f"{len(lane)} lanes for {n} "
+                                        f"signatures"
+                                    )
+                                got.extend(lane)
+                                device_sigs += n
                     finally:
                         # a gather that raises mid-loop must still
                         # leave the verifier drained: a retry would
@@ -572,7 +591,13 @@ class _TpuBatchVerifier(BatchVerifier):
                     handle = v.dispatch(self._pks, self._msgs, self._sigs)
                     host_prep = time.perf_counter() - t0
                     _m_batches.inc()
-                    bits = _gather_guarded(v, handle, self.KEY_TYPE)
+                    with trace.span(
+                        "tpu_gather",
+                        hist=_m_gather_wait,
+                        handles=1,
+                        sigs=total,
+                    ):
+                        bits = _gather_guarded(v, handle, self.KEY_TYPE)
                     if len(bits) != total:
                         raise DeviceFault(
                             f"mis-shaped device result: {len(bits)} "
@@ -614,13 +639,8 @@ class _TpuBatchVerifier(BatchVerifier):
                 return self._cpu_fallback(work, fault, total)
             _breaker(self.KEY_TYPE).record_success()
             if host_prep is not None:
-                device_wall = time.perf_counter() - t0 - host_prep
                 _m_host_prep.observe(host_prep)
-                _m_device_wall.observe(device_wall)
-                trace.add_attrs(
-                    host_prep_s=round(host_prep, 6),
-                    device_wall_s=round(device_wall, 6),
-                )
+                trace.add_attrs(host_prep_s=round(host_prep, 6))
             trace.add_attrs(
                 batch=total,
                 bucket=self._last_bucket,
@@ -646,18 +666,20 @@ class _TpuBatchVerifier(BatchVerifier):
         verify_signature_cpu for exactly this — an oracle that asked
         the device about the device's own verdict could never catch it
         lying (and would recurse through the single route)."""
-        for i, ok in enumerate(bits):
-            if ok:
-                continue
-            pub_key, msg, sig = work[i]
-            oracle = getattr(
-                pub_key, "verify_signature_cpu", pub_key.verify_signature
-            )
-            if oracle(msg, sig):
-                raise DeviceFault(
-                    f"device invalidated lane {i} but the CPU verifies "
-                    f"it: result disproven"
+        with trace.span("cpu_disprove", lanes=bits.count(False)):
+            for i, ok in enumerate(bits):
+                if ok:
+                    continue
+                pub_key, msg, sig = work[i]
+                oracle = getattr(
+                    pub_key, "verify_signature_cpu",
+                    pub_key.verify_signature,
                 )
+                if oracle(msg, sig):
+                    raise DeviceFault(
+                        f"device invalidated lane {i} but the CPU "
+                        f"verifies it: result disproven"
+                    )
 
     def _cpu_fallback(self, work, fault, total: int) -> Tuple[bool, List[bool]]:
         """Drain `work` through the registered CPU factory. With
@@ -950,6 +972,13 @@ def install(
         return 32 if jax.default_backend() == "tpu" else 1
 
     set_group_affinity_fn(_affinity)
+    # while span tracing is on, every program span is also a host event
+    # of a running jax.profiler capture, on the device's time base
+    # (importing jax.profiler initializes no backend; a TraceAnnotation
+    # outside a capture costs one atomic load)
+    import jax.profiler
+
+    trace.set_mirror(jax.profiler.TraceAnnotation)
 
 
 def uninstall() -> None:
@@ -979,3 +1008,4 @@ def uninstall() -> None:
     for name in ("ed25519", "sr25519", _SR_SINGLE):
         _breaker_mod.discard(name)
     set_group_affinity_fn(native_cpu_affinity)
+    trace.set_mirror(None)

@@ -5,12 +5,13 @@ often / how long on average", spans answer "what happened inside THIS
 call": each `span(name, **attrs)` records one timed interval with its
 parent (nesting follows the asyncio task / thread via contextvars), so
 a single commit verification decomposes into
-addVote -> batch_accumulate -> tpu_dispatch -> merkle_hash with
-per-stage attributes (batch size, pad waste, host-prep vs device-wall
-split, and the verified-signature cache's sigcache_hits /
-sigcache_misses on batch_accumulate — the count of triples that skipped
-crypto entirely vs. those actually assembled into the batch). The motivation: a run that
-dies midway must leave every surviving number attributable to a stage.
+addVote -> batch_accumulate -> its phases (docs/metrics.md draws the
+tree) -> tpu_dispatch -> tpu_gather, with per-stage attributes (batch
+size, pad waste, host prep, and the verified-signature cache's
+sigcache_hits / sigcache_misses on batch_accumulate — the count of
+triples that skipped crypto entirely vs. those actually assembled into
+the batch). The motivation: a run that dies midway must leave every
+surviving number attributable to a stage.
 
 Completed spans land in a bounded ring (old spans are evicted, never
 blocked on) and export as Chrome-trace JSON (chrome://tracing /
@@ -18,13 +19,27 @@ Perfetto "traceEvents" format). Spans can additionally feed an existing
 metrics Histogram (`span(..., hist=h)`), replacing `h.time()` at the
 call site; the histogram is observed whether or not tracing is enabled.
 
+Spans of one tree share a `root_id` (the id of the tree's outermost
+span), so one commit verification is one group without a parent walk.
+While tracing is on, every collection of Python's garbage collector
+that lands inside an open span is itself a `gc_collect` child span, so
+a phase's self time leaves the collector's pauses out.
+
+A mirror (`set_mirror`) puts every span on a second timeline as well:
+crypto/tpu_verifier.install() registers jax.profiler.TraceAnnotation,
+so a profiler capture of a traced process shows the program's phases
+on the host thread's line, in the capture's own time base, beside the
+device's events. This module itself imports nothing outside the
+standard library.
+
 Tracing is OFF by default. The disabled path is consensus-grade cheap:
 `span()` returns a shared no-op singleton — no Span object, no ring
-write, no contextvar touch.
+write, no contextvar touch, no mirror call, no gc hook.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -53,6 +68,7 @@ __all__ = [
     "reset_exemplars",
     "set_capacity",
     "set_exemplar_capacity",
+    "set_mirror",
     "snapshot",
     "span",
     "to_chrome_trace",
@@ -73,6 +89,9 @@ _current: ContextVar[Optional["Span"]] = ContextVar(
 # perf_counter epoch: Chrome-trace ts is relative anyway, and
 # perf_counter is the only clock monotonic enough to nest spans.
 _EPOCH = time.perf_counter()
+# factory(name) -> context manager entered and left alongside every
+# real Span (set_mirror); None costs one global load a span
+_mirror = None
 
 
 class Span:
@@ -84,6 +103,7 @@ class Span:
         "attrs",
         "span_id",
         "parent_id",
+        "root_id",
         "tid",
         "start_us",
         "dur_us",
@@ -91,6 +111,7 @@ class Span:
         "_hist_labels",
         "_t0",
         "_token",
+        "_mirrored",
     )
 
     def __init__(
@@ -104,6 +125,7 @@ class Span:
         self.attrs: Dict[str, Any] = attrs if attrs is not None else {}
         self.span_id = _next_id()
         self.parent_id = 0
+        self.root_id = self.span_id
         self.tid = 0
         self.start_us = 0.0
         self.dur_us = 0.0
@@ -111,6 +133,7 @@ class Span:
         self._hist_labels = hist_labels
         self._t0 = 0.0
         self._token = None
+        self._mirrored = None
 
     def set(self, **attrs: Any) -> "Span":
         """Attach attributes mid-span (batch sizes known only after
@@ -122,8 +145,14 @@ class Span:
         parent = _current.get()
         if parent is not None:
             self.parent_id = parent.span_id
+            self.root_id = parent.root_id
         self.tid = threading.get_ident()
         self._token = _current.set(self)
+        # the mirror opens before the clock is read and closes after,
+        # so on its timeline a child always lies inside its parent
+        if _mirror is not None:
+            self._mirrored = _mirror(self.name)
+            self._mirrored.__enter__()
         self._t0 = time.perf_counter()
         self.start_us = (self._t0 - _EPOCH) * 1e6
         return self
@@ -131,6 +160,9 @@ class Span:
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
         self.dur_us = (t1 - self._t0) * 1e6
+        if self._mirrored is not None:
+            self._mirrored.__exit__(exc_type, exc, tb)
+            self._mirrored = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -194,19 +226,56 @@ def current() -> Optional[Span]:
     return _current.get()
 
 
+def set_mirror(factory):
+    """Mirror every span onto a second timeline: `factory(name)`
+    returns a context manager that a real Span enters and leaves with
+    itself; None clears it. The no-op span and `hist.time()` of the
+    disabled path never call it. Returns the mirror it replaces, for a
+    caller that restores it."""
+    global _mirror
+    held, _mirror = _mirror, factory
+    return held
+
+
+# the collection in progress, as a span: collections neither nest nor
+# overlap (the collector holds the interpreter throughout), so one slot
+_gc_span: Optional[Span] = None
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    """gc.callbacks hook while tracing is on: a collection that lands
+    inside an open span is a `gc_collect` child of it."""
+    global _gc_span
+    if phase == "start":
+        if _current.get() is not None:
+            _gc_span = Span(
+                "gc_collect", attrs={"generation": info["generation"]}
+            )
+            _gc_span.__enter__()
+    elif _gc_span is not None:
+        s, _gc_span = _gc_span, None
+        s.attrs["collected"] = info["collected"]
+        s.__exit__(None, None, None)
+
+
 def enable(capacity: Optional[int] = None) -> None:
     """Turn the recorder on (optionally resizing the ring first)."""
     global _enabled
     if capacity is not None:
         set_capacity(capacity)
     _enabled = True
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def disable() -> None:
     """Kill switch: spans created after this return the no-op
-    singleton; spans already open stop recording at exit."""
+    singleton; spans already open stop recording at exit. The
+    collector's hook goes with it."""
     global _enabled
     _enabled = False
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def is_enabled() -> bool:
@@ -292,6 +361,7 @@ def _span_dict(s: Span) -> Dict[str, Any]:
         "name": s.name,
         "span_id": s.span_id,
         "parent_id": s.parent_id,
+        "root_id": s.root_id,
         "start_us": round(s.start_us, 3),
         "dur_us": round(s.dur_us, 3),
         "attrs": dict(s.attrs),
@@ -346,13 +416,14 @@ def exemplars_to_json() -> str:
 def to_chrome_trace() -> str:
     """Export the ring as Chrome-trace JSON ("traceEvents" complete
     events, loadable in chrome://tracing and Perfetto). `span_id` /
-    `parent_id` ride in args so the exact nesting survives export even
-    across interleaved asyncio tasks on one thread."""
+    `parent_id` / `root_id` ride in args so the exact nesting survives
+    export even across interleaved asyncio tasks on one thread."""
     events = []
     for s in snapshot():
         args = dict(s.attrs)
         args["span_id"] = s.span_id
         args["parent_id"] = s.parent_id
+        args["root_id"] = s.root_id
         events.append(
             {
                 "name": s.name,

@@ -12,6 +12,15 @@ this module sets nothing: whoever runs the program places the cache.
 Otherwise the cache is `<checkout>/.jax_cache`, derived from this
 file's own path. The path is part of the cache's key, so it is never
 built from a temporary name, a pid or the time.
+
+An executable read back from the cache keeps the metadata it was
+compiled with, and by default JAX leaves metadata out of the cache's
+key: a program whose stage names (`jax.named_scope` in ops/) changed
+since the entry was written would show the old names, or none, in every
+profile. So wherever the process may reach an accelerator — where
+profiles are read — metadata is part of the key. A CPU-pinned process
+(the test suite) keeps JAX's default, and with it the hits between
+tests that reach one program from different call sites.
 """
 
 from __future__ import annotations
@@ -31,6 +40,11 @@ def enable() -> str:
     programs compiled before the call are not written."""
     import jax
 
+    platforms = jax.config.jax_platforms  # a string: no backend starts
+    if not platforms or set(platforms.split(",")) != {"cpu"}:
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True
+        )
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed

@@ -53,6 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..crypto import ed25519_math as em
+from ..libs import trace
 from . import edwards as E
 from . import field25519 as F
 
@@ -209,12 +210,14 @@ def dual_mult_sb_minus_ka(
     override exists for device A/B attribution (scripts/probe_r3.py)."""
     if mxu is None:
         mxu = not mosaic
-    TA = _build_neg_a_table(A)  # (9, 4, L, N)
+    with jax.named_scope("neg_a_table"):
+        TA = _build_neg_a_table(A)  # (9, 4, L, N)
 
     tb0 = _tb0()  # (9, 4, L, 1)
 
-    dS = _recode_signed(dS)
-    dk = _recode_signed(dk)
+    with jax.named_scope("scalar_prep"):
+        dS = _recode_signed(dS)
+        dk = _recode_signed(dk)
 
     # The carry is the T-less 3-stack (X, Y, Z): doublings never
     # read T and the final comparison is projective, so only the ops
@@ -233,25 +236,30 @@ def dual_mult_sb_minus_ka(
         )
         return acc
 
-    if mosaic:
-        rows = lax.broadcasted_iota(dS.dtype, dS.shape, 0)  # (64, N)
+    # the 64-window walk: the profiler's trace names its operations by
+    # this scope
+    with jax.named_scope("dual_mult"):
+        if mosaic:
+            rows = lax.broadcasted_iota(dS.dtype, dS.shape, 0)  # (64, N)
 
-        def body(w, acc):
-            sel = (rows == 63 - w).astype(dS.dtype)  # MSB-first walk
-            return step(
-                acc, jnp.sum(dS * sel, axis=0), jnp.sum(dk * sel, axis=0)
-            )
+            def body(w, acc):
+                sel = (rows == 63 - w).astype(dS.dtype)  # MSB-first walk
+                return step(
+                    acc,
+                    jnp.sum(dS * sel, axis=0),
+                    jnp.sum(dk * sel, axis=0),
+                )
 
-        return lax.fori_loop(0, 64, body, acc0)
+            return lax.fori_loop(0, 64, body, acc0)
 
-    def scan_body(acc, xs):
-        ds_w, dk_w = xs
-        return step(acc, ds_w, dk_w), None
+        def scan_body(acc, xs):
+            ds_w, dk_w = xs
+            return step(acc, ds_w, dk_w), None
 
-    acc, _ = lax.scan(
-        scan_body, acc0, (jnp.flip(dS, axis=0), jnp.flip(dk, axis=0))
-    )
-    return acc
+        acc, _ = lax.scan(
+            scan_body, acc0, (jnp.flip(dS, axis=0), jnp.flip(dk, axis=0))
+        )
+        return acc
 
 
 def _scalar_mult_check(
@@ -265,26 +273,28 @@ def _scalar_mult_check(
     (the segmented Pallas kernel plugs in here; everything around it —
     decompression, cofactor clearing, the projective compare — stays
     XLA, which fuses those fine)."""
-    A, okA = E.decompress(yA, signA)
-    R, okR = E.decompress(yR, signR)
+    with jax.named_scope("decode_points"):
+        A, okA = E.decompress(yA, signA)
+        R, okR = E.decompress(yR, signR)
     if dual_fn is None:
         acc = dual_mult_sb_minus_ka(A, dS, dk, mosaic=mosaic)
     else:
         acc = dual_fn(A, dS, dk)
-    # ZIP-215 cofactored equation, rearranged so nothing needs T:
-    # [8]([S]B - [k]A) == [8]R  <=>  [8]([S]B - [k]A - R) == identity.
-    for _ in range(3):  # cofactor 8, both sides
-        acc = E.point_double(acc, with_t=False)
-        R = E.point_double(R, with_t=False)
-    # projective equality: X1 Z2 == X2 Z1 and Y1 Z2 == Y2 Z1
-    lhs = jnp.stack([acc[..., 0, :, :], acc[..., 1, :, :]], axis=-3)
-    rhs = jnp.stack([R[..., 0, :, :], R[..., 1, :, :]], axis=-3)
-    z_acc = jnp.broadcast_to(acc[..., 2:3, :, :], lhs.shape)
-    z_r = jnp.broadcast_to(R[..., 2:3, :, :], rhs.shape)
-    cross_l = F.mul(lhs, z_r)
-    cross_r = F.mul(rhs, z_acc)
-    same = jnp.all(F.eq(cross_l, cross_r), axis=-2)
-    return same & okA & okR
+    with jax.named_scope("final_check"):
+        # ZIP-215 cofactored equation, rearranged so nothing needs T:
+        # [8]([S]B - [k]A) == [8]R  <=>  [8]([S]B - [k]A - R) == identity.
+        for _ in range(3):  # cofactor 8, both sides
+            acc = E.point_double(acc, with_t=False)
+            R = E.point_double(R, with_t=False)
+        # projective equality: X1 Z2 == X2 Z1 and Y1 Z2 == Y2 Z1
+        lhs = jnp.stack([acc[..., 0, :, :], acc[..., 1, :, :]], axis=-3)
+        rhs = jnp.stack([R[..., 0, :, :], R[..., 1, :, :]], axis=-3)
+        z_acc = jnp.broadcast_to(acc[..., 2:3, :, :], lhs.shape)
+        z_r = jnp.broadcast_to(R[..., 2:3, :, :], rhs.shape)
+        cross_l = F.mul(lhs, z_r)
+        cross_r = F.mul(rhs, z_acc)
+        same = jnp.all(F.eq(cross_l, cross_r), axis=-2)
+        return same & okA & okR
 
 
 # -- device-side scalar prep --
@@ -446,11 +456,16 @@ def _verify_tile(pk_b, sig_b, dig_b, mosaic: bool = False, dual_fn=None) -> jnp.
     signR = r[31] >> 7
     r = r & _TOPCLEAR
     s = sig[32:]
-    yA = _fe_from_bytes_dev(pk)
-    yR = _fe_from_bytes_dev(r)
-    s_ok = _s_lt_l_dev(s)
-    dS = _nibbles_dev(s)
-    dk = _nibbles_dev(_mod_l_dev(dig))
+    # the stage names are one vocabulary with _verify_tile_sr
+    # (ops/sr25519_kernel.py): decode_points / ristretto_decode,
+    # scalar_prep, neg_a_table, dual_mult, final_check
+    with jax.named_scope("decode_points"):
+        yA = _fe_from_bytes_dev(pk)
+        yR = _fe_from_bytes_dev(r)
+    with jax.named_scope("scalar_prep"):
+        s_ok = _s_lt_l_dev(s)
+        dS = _nibbles_dev(s)
+        dk = _nibbles_dev(_mod_l_dev(dig))
     ok = _scalar_mult_check(
         yA, signA, yR, signR, dS, dk, mosaic=mosaic, dual_fn=dual_fn
     )
@@ -624,41 +639,50 @@ class Ed25519Verifier:
         n = len(pubkeys)
         if n == 0:
             return (None, 0, np.zeros(0, dtype=bool))
-        size_ok = np.array(
-            [
-                len(pk) == 32 and len(sig) == 64
-                for pk, sig in zip(pubkeys, sigs)
-            ],
-            dtype=bool,
-        )
-        if not size_ok.all():
-            pubkeys = [
-                pk if ok else b"\x00" * 32
-                for pk, ok in zip(pubkeys, size_ok)
-            ]
-            sigs = [
-                sig if ok else b"\x00" * 64
-                for sig, ok in zip(sigs, size_ok)
-            ]
-        # host work is byte joins only; hashing (SHA-512 of R||A||M),
-        # limb unpacking, mod-L, S-canonicality, digits, and the curve
-        # math all run on device
         bucket = self._bucket(n)
         pad = bucket - n
-        pk_b = _join_cols(pubkeys, 32, pad)
-        sig_b = _join_cols(sigs, 64, pad)
-        dig_b = self._digest_rows(pubkeys, msgs, sigs, bucket)
+        with trace.span("pack_rows", n=n, bucket=bucket):
+            size_ok = np.array(
+                [
+                    len(pk) == 32 and len(sig) == 64
+                    for pk, sig in zip(pubkeys, sigs)
+                ],
+                dtype=bool,
+            )
+            if not size_ok.all():
+                pubkeys = [
+                    pk if ok else b"\x00" * 32
+                    for pk, ok in zip(pubkeys, size_ok)
+                ]
+                sigs = [
+                    sig if ok else b"\x00" * 64
+                    for sig, ok in zip(sigs, size_ok)
+                ]
+            # host work is byte joins only; hashing (SHA-512 of
+            # R||A||M), limb unpacking, mod-L, S-canonicality, digits,
+            # and the curve math all run on device
+            pk_b = _join_cols(pubkeys, 32, pad)
+            sig_b = _join_cols(sigs, 64, pad)
+            pre = self._preimage_rows(pubkeys, msgs, sigs, bucket)
+        dig_b = self._digest_rows(pubkeys, msgs, sigs, bucket, pre)
         prog = self._program(bucket)
-        ok = run_with_pallas_fallback(
-            prog,
-            (self._place(pk_b), self._place(sig_b), self._place(dig_b)),
-            is_pallas=self._is_pallas(prog),
-            bucket=bucket,
-            proven=self._pallas_proven,
-            compiled=self._compiled,
-            xla_factory=_jit_verify_tile,
-            label="ed25519",
-        )
+        with trace.span(
+            "device_launch", program=_program_name(prog), bucket=bucket
+        ):
+            ok = run_with_pallas_fallback(
+                prog,
+                (
+                    self._place(pk_b),
+                    self._place(sig_b),
+                    self._place(dig_b),
+                ),
+                is_pallas=self._is_pallas(prog),
+                bucket=bucket,
+                proven=self._pallas_proven,
+                compiled=self._compiled,
+                xla_factory=_jit_verify_tile,
+                label="ed25519",
+            )
         return (ok, n, size_ok)
 
     def _place(self, rows):
@@ -672,18 +696,46 @@ class Ed25519Verifier:
         verifiers partition it like the tile)."""
         return _jit_sha512()
 
-    def _digest_rows(self, pubkeys, msgs, sigs, bucket):
+    def _preimage_rows(self, pubkeys, msgs, sigs, bucket):
+        """(64 + len, bucket) rows of R || A || M when every message
+        has one length — every sign-bytes in a Commit has the same
+        shape — so that the digests stay on device, feeding the verify
+        program without a host round-trip; None otherwise (mixed
+        lengths, or TM_TPU_HOST_SHA512=1)."""
+        import os
+
+        if os.environ.get("TM_TPU_HOST_SHA512"):
+            return None
+        if len(set(map(len, msgs))) != 1:
+            return None
+        return _join_cols(
+            [
+                sig[:32] + pk + msg
+                for pk, msg, sig in zip(pubkeys, msgs, sigs)
+            ],
+            64 + len(msgs[0]),
+            bucket - len(pubkeys),
+        )
+
+    def _digest_rows(self, pubkeys, msgs, sigs, bucket, pre=None):
         """(64, bucket) rows of SHA512(R || A || M).
 
         Device-hashed per message-length group (ops/sha512_kernel.py
-        compiles one program per length); the single-length common case
-        — every sign-bytes in a Commit has the same shape — keeps the
-        digests on device, feeding the verify program without a host
-        round-trip. TM_TPU_HOST_SHA512=1 restores hashlib (bench
+        compiles one program per length); `pre` is the single-length
+        case's pre-image (_preimage_rows), joined here when the caller
+        has not. TM_TPU_HOST_SHA512=1 restores hashlib (bench
         comparisons)."""
         import os
 
         n = len(pubkeys)
+        if pre is None:
+            pre = self._preimage_rows(pubkeys, msgs, sigs, bucket)
+        if pre is not None:
+            prog = self._sha512_program()
+            with trace.span(
+                "device_launch", program=_program_name(prog), bucket=bucket
+            ):
+                return prog(self._place(pre))
         if os.environ.get("TM_TPU_HOST_SHA512"):
             return _join_cols(
                 [
@@ -696,17 +748,6 @@ class Ed25519Verifier:
         groups: dict = {}
         for i, m in enumerate(msgs):
             groups.setdefault(len(m), []).append(i)
-        if len(groups) == 1:
-            ((mlen, _),) = groups.items()
-            pre = _join_cols(
-                [
-                    sig[:32] + pk + msg
-                    for pk, msg, sig in zip(pubkeys, msgs, sigs)
-                ],
-                64 + mlen,
-                bucket - n,
-            )
-            return self._sha512_program()(self._place(pre))
         dig = np.zeros((64, bucket), dtype=np.uint8)
         for mlen, idxs in groups.items():
             g = len(idxs)
@@ -719,7 +760,12 @@ class Ed25519Verifier:
                 64 + mlen,
                 gb - g,
             )
-            out = np.asarray(self._sha512_program()(self._place(pre)))
+            prog = self._sha512_program()
+            with trace.span(
+                "device_launch", program=_program_name(prog), bucket=gb
+            ):
+                launched = prog(self._place(pre))
+            out = np.asarray(launched)
             dig[:, idxs] = out[:, :g]
         return dig
 
@@ -729,6 +775,13 @@ class Ed25519Verifier:
         if ok is None:
             return size_ok
         return np.asarray(ok)[:n] & size_ok
+
+
+def _program_name(prog) -> str:
+    """The traced function's own name (`_verify_tile`, `sha512_fixed`,
+    a Pallas variant's): what the profiler calls the program's
+    executions, less its `jit_` prefix."""
+    return getattr(prog, "__name__", type(prog).__name__)
 
 
 _JIT_VERIFY = None

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -118,8 +119,6 @@ def _compress(state: jnp.ndarray, block: jnp.ndarray) -> jnp.ndarray:
       in ~2-4 s per (length, bucket) program, which multiplies across
       the test suite's many message lengths; the scan compiles the
       ~40-op body once and CPU throughput is not the target."""
-    import jax
-
     if jax.default_backend() != "tpu":
         return _compress_scan(state, block)
     w = [block[i] for i in range(16)]
@@ -222,8 +221,9 @@ def sha512_fixed(data: jnp.ndarray) -> jnp.ndarray:
     state = jnp.broadcast_to(
         jnp.asarray(_H0)[:, :, None], (8, 2, n)
     ).astype(jnp.uint32)
-    for b in range(nblocks):
-        state = _compress(state, words[b])
+    with jax.named_scope("sha512_blocks"):
+        for b in range(nblocks):
+            state = _compress(state, words[b])
     # big-endian unpack: (8, 2, N) words -> (64, N) bytes
     shifts = np.array([24, 16, 8, 0], dtype=np.uint32)
     out = (state[:, :, None, :] >> jnp.asarray(shifts)[None, None, :, None]) & np.uint32(0xFF)

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..crypto import ed25519_math as em
+from ..libs import trace
 from . import field25519 as F
 from .ed25519_kernel import (
     DEFAULT_BUCKET_SIZES,
@@ -48,6 +49,7 @@ from .ed25519_kernel import (
     _join_cols,
     _lt_const_dev,
     _nibbles_dev,
+    _program_name,
     _s_lt_l_dev,
     bucket_for,
     dual_mult_sb_minus_ka,
@@ -159,18 +161,22 @@ def _verify_tile_sr(pk_b, sig_b, k_b, dual_fn=None) -> jnp.ndarray:
     pk = pk_b.astype(jnp.int32)
     sig = sig_b.astype(jnp.int32)
     kb = k_b.astype(jnp.int32)
-    marker_ok = (sig[63] >> 7) == 1  # schnorrkel v1 marker bit
-    s = sig[32:] & _TOPCLEAR
-    s_ok = _s_lt_l_dev(s)
-    A, okA = ristretto_decode_dev(pk)
-    R, okR = ristretto_decode_dev(sig[:32])
-    dS = _nibbles_dev(s)
-    dk = _nibbles_dev(kb)
+    # stage names: one vocabulary with ed25519_kernel._verify_tile
+    with jax.named_scope("scalar_prep"):
+        marker_ok = (sig[63] >> 7) == 1  # schnorrkel v1 marker bit
+        s = sig[32:] & _TOPCLEAR
+        s_ok = _s_lt_l_dev(s)
+        dS = _nibbles_dev(s)
+        dk = _nibbles_dev(kb)
+    with jax.named_scope("ristretto_decode"):
+        A, okA = ristretto_decode_dev(pk)
+        R, okR = ristretto_decode_dev(sig[:32])
     if dual_fn is None:
         acc = dual_mult_sb_minus_ka(A, dS, dk)  # [s]B - [k]A, T-less
     else:
         acc = dual_fn(A, dS, dk)
-    return _ristretto_eq_dev(acc, R) & okA & okR & s_ok & marker_ok
+    with jax.named_scope("final_check"):
+        return _ristretto_eq_dev(acc, R) & okA & okR & s_ok & marker_ok
 
 
 _JIT_VERIFY_SR = None
@@ -266,52 +272,65 @@ class Sr25519Verifier:
         n = len(pubkeys)
         if n == 0:
             return (None, 0, np.zeros(0, dtype=bool))
-        size_ok = np.array(
-            [
-                len(pk) == 32 and len(sig) == 64
-                for pk, sig in zip(pubkeys, sigs)
-            ],
-            dtype=bool,
-        )
-        if not size_ok.all():
-            pubkeys = [
-                pk if ok else b"\x00" * 32
-                for pk, ok in zip(pubkeys, size_ok)
-            ]
-            sigs = [
-                sig if ok else b"\x00" * 64
-                for sig, ok in zip(sigs, size_ok)
-            ]
-        # host: the merlin Fiat-Shamir challenges, vectorized per
-        # message-length group (crypto/sr25519.py challenge_batch —
-        # one native keccakf_n permutation call per transcript step)
-        ks = [
-            k.to_bytes(32, "little")
-            for k in challenge_batch(
-                pubkeys, msgs, [sig[:32] for sig in sigs]
-            )
-        ]
         bucket = self._bucket(n)
         pad = bucket - n
-        pk_b = _join_cols(pubkeys, 32, pad)
-        sig_b = _join_cols(sigs, 64, pad)
-        k_b = _join_cols(ks, 32, pad)
+        with trace.span("pack_rows", n=n, bucket=bucket):
+            size_ok = np.array(
+                [
+                    len(pk) == 32 and len(sig) == 64
+                    for pk, sig in zip(pubkeys, sigs)
+                ],
+                dtype=bool,
+            )
+            if not size_ok.all():
+                pubkeys = [
+                    pk if ok else b"\x00" * 32
+                    for pk, ok in zip(pubkeys, size_ok)
+                ]
+                sigs = [
+                    sig if ok else b"\x00" * 64
+                    for sig, ok in zip(sigs, size_ok)
+                ]
+            pk_b = _join_cols(pubkeys, 32, pad)
+            sig_b = _join_cols(sigs, 64, pad)
+        # host: the merlin Fiat-Shamir challenges, vectorized per
+        # message-length group (crypto/sr25519.py challenge_batch —
+        # one native keccakf_n permutation call per transcript step),
+        # and their 32-byte rows
+        with trace.span("merlin_challenges", n=n):
+            k_b = _join_cols(
+                [
+                    k.to_bytes(32, "little")
+                    for k in challenge_batch(
+                        pubkeys, msgs, [sig[:32] for sig in sigs]
+                    )
+                ],
+                32,
+                pad,
+            )
         prog = self._program(bucket)
         from .ed25519_kernel import run_with_pallas_fallback
 
-        ok = run_with_pallas_fallback(
-            prog,
-            (self._place(pk_b), self._place(sig_b), self._place(k_b)),
-            is_pallas=(
-                _JIT_VERIFY_SR_HYBRID is not None
-                and prog is _JIT_VERIFY_SR_HYBRID
-            ),
-            bucket=bucket,
-            proven=self._pallas_proven,
-            compiled=self._compiled,
-            xla_factory=_jit_verify_tile_sr,
-            label="sr25519",
-        )
+        with trace.span(
+            "device_launch", program=_program_name(prog), bucket=bucket
+        ):
+            ok = run_with_pallas_fallback(
+                prog,
+                (
+                    self._place(pk_b),
+                    self._place(sig_b),
+                    self._place(k_b),
+                ),
+                is_pallas=(
+                    _JIT_VERIFY_SR_HYBRID is not None
+                    and prog is _JIT_VERIFY_SR_HYBRID
+                ),
+                bucket=bucket,
+                proven=self._pallas_proven,
+                compiled=self._compiled,
+                xla_factory=_jit_verify_tile_sr,
+                label="sr25519",
+            )
         return (ok, n, size_ok)
 
     def gather(self, handle) -> np.ndarray:

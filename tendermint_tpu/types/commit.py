@@ -11,6 +11,7 @@ from typing import List, Optional
 
 from ..crypto import merkle
 from ..encoding.proto import FieldReader, ProtoWriter, iter_fields
+from ..libs import trace
 from ..libs.bits import BitArray
 from .block_id import BlockID
 from .canonical import PRECOMMIT_TYPE
@@ -450,15 +451,18 @@ class Commit:
         round_ = 0
         block_id = BlockID()
         sigs: List[CommitSig] = []
-        for f, _wt, v in iter_fields(data):
-            if f == 1:
-                height = v
-            elif f == 2:
-                round_ = v
-            elif f == 3:
-                block_id = BlockID.from_proto(v)
-            elif f == 4:
-                sigs.append(CommitSig.from_proto(v))
-        return cls(
-            height=height, round=round_, block_id=block_id, signatures=sigs
-        )
+        with trace.span("commit_decode", bytes=len(data)) as span:
+            for f, _wt, v in iter_fields(data):
+                if f == 1:
+                    height = v
+                elif f == 2:
+                    round_ = v
+                elif f == 3:
+                    block_id = BlockID.from_proto(v)
+                elif f == 4:
+                    sigs.append(CommitSig.from_proto(v))
+            span.set(sigs=len(sigs))
+            return cls(
+                height=height, round=round_, block_id=block_id,
+                signatures=sigs,
+            )

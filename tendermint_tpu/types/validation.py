@@ -282,37 +282,50 @@ def verify_triples_grouped(triples) -> None:
         keys: list = []
         hit_set: set = set()
         if use_cache:
-            keys = [
-                sigcache.key_for(pk.bytes(), sb, sig)
-                for pk, sb, sig in triples
-            ]
-            hit_set = sigcache.seen_keys_bulk(keys)
-        for n, (pk, sb, sig) in enumerate(triples):
-            ckey = None
-            if use_cache:
-                ckey = keys[n]
-                if ckey in hit_set:
-                    hits += 1
+            with trace.span("sigcache_probe") as probe:
+                keys = [
+                    sigcache.key_for(pk.bytes(), sb, sig)
+                    for pk, sb, sig in triples
+                ]
+                hit_set = sigcache.seen_keys_bulk(keys)
+                probe.set(
+                    hits=len(hit_set), misses=len(keys) - len(hit_set)
+                )
+        with trace.span("batch_route") as route:
+            inline = 0
+            for n, (pk, sb, sig) in enumerate(triples):
+                ckey = None
+                if use_cache:
+                    ckey = keys[n]
+                    if ckey in hit_set:
+                        hits += 1
+                        continue
+                    misses += 1
+                if not supports_batch_verifier(pk):
+                    inline += 1
+                    if not pk.verify_signature(sb, sig):
+                        if use_cache:  # keep the scanned hit/miss counts
+                            sigcache.observe(hits, misses)
+                        raise InvalidCommitError(
+                            "wrong signature in merged batch"
+                        )
+                    if ckey is not None:
+                        sigcache.add_key(ckey)
                     continue
-                misses += 1
-            if not supports_batch_verifier(pk):
-                if not pk.verify_signature(sb, sig):
-                    if use_cache:  # keep the scanned hit/miss counts
-                        sigcache.observe(hits, misses)
-                    raise InvalidCommitError(
-                        "wrong signature in merged batch"
-                    )
-                if ckey is not None:
-                    sigcache.add_key(ckey)
-                continue
-            pending.setdefault(pk.type(), []).append((pk, sb, sig, ckey))
+                pending.setdefault(pk.type(), []).append(
+                    (pk, sb, sig, ckey)
+                )
+            route.set(inline=inline)
         if use_cache:
             sigcache.observe(hits, misses)
             trace.add_attrs(sigcache_hits=hits, sigcache_misses=misses)
-        for items in pending.values():
-            bv = create_batch_verifier(items[0][0], size_hint=len(items))
-            for pk, sb, sig, _ckey in items:
-                bv.add(pk, sb, sig)
+        for key_type, items in pending.items():
+            with trace.span("batch_add", key=key_type, sigs=len(items)):
+                bv = create_batch_verifier(
+                    items[0][0], size_hint=len(items)
+                )
+                for pk, sb, sig, _ckey in items:
+                    bv.add(pk, sb, sig)
             ok, _bits = drain_and_cache(bv, [it[3] for it in items])
             if not ok:
                 raise InvalidCommitError("wrong signature in merged batch")
@@ -565,52 +578,61 @@ def _verify_commit_batch_vector(
     verification work are identical."""
     use_cache = sigcache.enabled()
     sigs = commit.signatures
-    powers = vals.powers_array()
+    with trace.span("commit_plan") as plan:
+        powers = vals.powers_array()
 
-    # --- the plan: processed indexes (ascending) + precomputed tally
-    if count_all_signatures:
-        tallied = int(powers[flags == BLOCK_ID_FLAG_COMMIT].sum())
-        idx_list = np.flatnonzero(flags != BLOCK_ID_FLAG_ABSENT).tolist()
-    elif look_up_by_index:
-        tallied, end = _prefix_crossing(
-            np.where(flags == BLOCK_ID_FLAG_COMMIT, powers, 0),
-            voting_power_needed,
-        )
-        idx_list = np.flatnonzero(
-            (flags if end is None else flags[:end]) == BLOCK_ID_FLAG_COMMIT
-        ).tolist()
-    else:
-        fb = np.flatnonzero(flags == BLOCK_ID_FLAG_COMMIT)
-        addr_index = vals._addr_index
-        vi = np.fromiter(
-            (
-                addr_index.get(sigs[i].validator_address, -1)
-                for i in fb.tolist()
-            ),
-            dtype=np.int64,
-            count=fb.size,
-        )
-        tallied, end = _prefix_crossing(
-            np.where(vi >= 0, powers[np.maximum(vi, 0)], 0),
-            voting_power_needed,
-        )
-        idx_list = (fb if end is None else fb[:end]).tolist()
+        # --- the plan: processed indexes (ascending) + precomputed
+        # tally
+        if count_all_signatures:
+            tallied = int(powers[flags == BLOCK_ID_FLAG_COMMIT].sum())
+            idx_list = np.flatnonzero(
+                flags != BLOCK_ID_FLAG_ABSENT
+            ).tolist()
+        elif look_up_by_index:
+            tallied, end = _prefix_crossing(
+                np.where(flags == BLOCK_ID_FLAG_COMMIT, powers, 0),
+                voting_power_needed,
+            )
+            idx_list = np.flatnonzero(
+                (flags if end is None else flags[:end])
+                == BLOCK_ID_FLAG_COMMIT
+            ).tolist()
+        else:
+            fb = np.flatnonzero(flags == BLOCK_ID_FLAG_COMMIT)
+            addr_index = vals._addr_index
+            vi = np.fromiter(
+                (
+                    addr_index.get(sigs[i].validator_address, -1)
+                    for i in fb.tolist()
+                ),
+                dtype=np.int64,
+                count=fb.size,
+            )
+            tallied, end = _prefix_crossing(
+                np.where(vi >= 0, powers[np.maximum(vi, 0)], 0),
+                voting_power_needed,
+            )
+            idx_list = (fb if end is None else fb[:end]).tolist()
 
-    # --- commit-level memo: a commit this process fully verified
-    # before, in this mode, against this exact set composition and
-    # these exact live powers, short-circuits to the (deterministic)
-    # success in O(1) probes. Failures are never recorded, the token
-    # components die with any mutation, and TM_TPU_NO_SIGCACHE /
-    # TM_TPU_NO_COMMIT_MEMO disable the whole consult.
-    ckey_commit = None
-    if use_cache and sigcache.commit_memo_enabled():
-        ckey_commit = _commit_memo_key(
-            chain_id, vals, commit, voting_power_needed,
-            count_all_signatures, look_up_by_index, powers,
-        )
-        if sigcache.seen_commit(ckey_commit):
-            trace.add_attrs(sigcache_commit_hit=True, sigs_warm=len(idx_list))
-            return
+        # --- commit-level memo: a commit this process fully verified
+        # before, in this mode, against this exact set composition and
+        # these exact live powers, short-circuits to the
+        # (deterministic) success in O(1) probes. Failures are never
+        # recorded, the token components die with any mutation, and
+        # TM_TPU_NO_SIGCACHE / TM_TPU_NO_COMMIT_MEMO disable the whole
+        # consult.
+        ckey_commit = None
+        memo_hit = False
+        if use_cache and sigcache.commit_memo_enabled():
+            ckey_commit = _commit_memo_key(
+                chain_id, vals, commit, voting_power_needed,
+                count_all_signatures, look_up_by_index, powers,
+            )
+            memo_hit = sigcache.seen_commit(ckey_commit)
+        plan.set(processed=len(idx_list), memo_hit=memo_hit)
+    if memo_hit:
+        trace.add_attrs(sigcache_commit_hit=True)
+        return
 
     # key type -> [(pub_key, sign_bytes, signature, commit idx, cache
     # key)]: the cache misses awaiting batch verification
@@ -621,69 +643,80 @@ def _verify_commit_batch_vector(
 
     if look_up_by_index:
         validators = vals.validators
-        if count_all_signatures:
-            rows = commit.sign_bytes_batch(chain_id)
-        else:
-            # early-exit variant: encode only the processed prefix,
-            # lazily and memoized — no discarded rows are paid for
-            rows = None
-            vsb = commit.vote_sign_bytes
+        with trace.span("sign_bytes", rows=len(idx_list)):
+            if count_all_signatures:
+                rows = commit.sign_bytes_batch(chain_id)
+            else:
+                # early-exit variant: encode only the processed prefix,
+                # in one pass and memoized — no discarded rows are paid
+                # for, and the lookups below are memo reads
+                rows = None
+                vsb = commit.vote_sign_bytes
+                prefix_rows = [vsb(chain_id, i) for i in idx_list]
         misses = idx_list
-        hits_n = 0
         if use_cache:
-            pkb = vals.pubkeys_bytes()
-            if rows is not None:
-                # rows is None exactly at absent indexes, i.e. exactly
-                # the complement of idx_list — the zip form skips three
-                # indexed lookups per signature vs iterating idx_list
-                keys = [
-                    (b, r, cs.signature)
-                    for b, r, cs in zip(pkb, rows, sigs)
-                    if r is not None
-                ]
-            else:
-                keys = [
-                    (pkb[i], vsb(chain_id, i), sigs[i].signature)
-                    for i in idx_list
-                ]
-            hit_set = sigcache.seen_keys_bulk(keys)
-            hits_n = len(hit_set)
-            if hits_n == len(keys):
-                misses = []
-            else:
-                misses = [
-                    i
-                    for i, k in zip(idx_list, keys)
-                    if k not in hit_set
-                ]
+            with trace.span("sigcache_probe") as probe:
+                pkb = vals.pubkeys_bytes()
+                if rows is not None:
+                    # rows is None exactly at absent indexes, i.e.
+                    # exactly the complement of idx_list — the zip form
+                    # skips three indexed lookups per signature vs
+                    # iterating idx_list
+                    keys = [
+                        (b, r, cs.signature)
+                        for b, r, cs in zip(pkb, rows, sigs)
+                        if r is not None
+                    ]
+                else:
+                    keys = [
+                        (pkb[i], r, sigs[i].signature)
+                        for i, r in zip(idx_list, prefix_rows)
+                    ]
+                hit_set = sigcache.seen_keys_bulk(keys)
+                hits_n = len(hit_set)
+                if hits_n == len(keys):
+                    misses = []
+                else:
+                    misses = [
+                        i
+                        for i, k in zip(idx_list, keys)
+                        if k not in hit_set
+                    ]
+                probe.set(hits=hits_n, misses=len(misses))
             sigcache.observe(hits_n, len(misses))
             trace.add_attrs(
                 sigcache_hits=hits_n, sigcache_misses=len(misses)
             )
-        for i in misses:
-            pub_key = validators[i].pub_key
-            sb = rows[i] if rows is not None else vsb(chain_id, i)
-            sig = sigs[i].signature
-            key_type = pub_key.type()
-            can_batch = batchable.get(key_type)
-            if can_batch is None:
-                can_batch = batchable[key_type] = supports_batch_verifier(
-                    pub_key
-                )
-            if not can_batch:
-                if not pub_key.verify_signature(sb, sig):
-                    raise InvalidCommitError(
-                        f"wrong signature (#{i}): {sig.hex()}"
+        with trace.span("batch_route") as route:
+            inline = 0
+            for i in misses:
+                pub_key = validators[i].pub_key
+                sb = rows[i] if rows is not None else vsb(chain_id, i)
+                sig = sigs[i].signature
+                key_type = pub_key.type()
+                can_batch = batchable.get(key_type)
+                if can_batch is None:
+                    can_batch = batchable[key_type] = (
+                        supports_batch_verifier(pub_key)
                     )
-                if use_cache:
-                    sigcache.add_key((pub_key.bytes(), sb, sig))
-            else:
-                pending.setdefault(key_type, []).append(
-                    (
-                        pub_key, sb, sig, i,
-                        (pub_key.bytes(), sb, sig) if use_cache else None,
+                if not can_batch:
+                    inline += 1
+                    if not pub_key.verify_signature(sb, sig):
+                        raise InvalidCommitError(
+                            f"wrong signature (#{i}): {sig.hex()}"
+                        )
+                    if use_cache:
+                        sigcache.add_key((pub_key.bytes(), sb, sig))
+                else:
+                    pending.setdefault(key_type, []).append(
+                        (
+                            pub_key, sb, sig, i,
+                            (pub_key.bytes(), sb, sig)
+                            if use_cache
+                            else None,
+                        )
                     )
-                )
+            route.set(inline=inline)
     else:
         # trusting: per-index replay of the reference body over the
         # precomputed prefix — the double-vote ordering machinery stays
@@ -691,53 +724,59 @@ def _verify_commit_batch_vector(
         _seen_key = sigcache.seen_key
         hits_n = misses_n = 0
         seen_vals: dict[int, int] = {}
-        for idx in idx_list:
-            commit_sig = sigs[idx]
-            val_idx, val = vals.get_by_address(commit_sig.validator_address)
-            if val is None:
-                continue
-            if val_idx in seen_vals:
-                raise InvalidCommitError(
-                    f"double vote from {val.address.hex()} "
-                    f"({seen_vals[val_idx]} and {idx})"
+        with trace.span("batch_route") as route:
+            inline = 0
+            for idx in idx_list:
+                commit_sig = sigs[idx]
+                val_idx, val = vals.get_by_address(
+                    commit_sig.validator_address
                 )
-            seen_vals[val_idx] = idx
-            vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
-            pub_key = val.pub_key
-            ckey = None
-            if use_cache:
-                ckey = (
-                    pub_key.bytes(), vote_sign_bytes, commit_sig.signature
-                )
-                if _seen_key(ckey):
-                    hits_n += 1
+                if val is None:
                     continue
-                misses_n += 1
-            key_type = pub_key.type()
-            can_batch = batchable.get(key_type)
-            if can_batch is None:
-                can_batch = batchable[key_type] = supports_batch_verifier(
-                    pub_key
-                )
-            if not can_batch:
-                if not pub_key.verify_signature(
-                    vote_sign_bytes, commit_sig.signature
-                ):
-                    if use_cache:  # keep the scanned hit/miss counts
-                        sigcache.observe(hits_n, misses_n)
+                if val_idx in seen_vals:
                     raise InvalidCommitError(
-                        f"wrong signature (#{idx}): "
-                        f"{commit_sig.signature.hex()}"
+                        f"double vote from {val.address.hex()} "
+                        f"({seen_vals[val_idx]} and {idx})"
                     )
-                if ckey is not None:
-                    sigcache.add_key(ckey)
-            else:
-                pending.setdefault(key_type, []).append(
-                    (
-                        pub_key, vote_sign_bytes, commit_sig.signature,
-                        idx, ckey,
+                seen_vals[val_idx] = idx
+                vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
+                pub_key = val.pub_key
+                ckey = None
+                if use_cache:
+                    ckey = (
+                        pub_key.bytes(), vote_sign_bytes, commit_sig.signature
                     )
-                )
+                    if _seen_key(ckey):
+                        hits_n += 1
+                        continue
+                    misses_n += 1
+                key_type = pub_key.type()
+                can_batch = batchable.get(key_type)
+                if can_batch is None:
+                    can_batch = batchable[key_type] = supports_batch_verifier(
+                        pub_key
+                    )
+                if not can_batch:
+                    inline += 1
+                    if not pub_key.verify_signature(
+                        vote_sign_bytes, commit_sig.signature
+                    ):
+                        if use_cache:  # keep the scanned hit/miss counts
+                            sigcache.observe(hits_n, misses_n)
+                        raise InvalidCommitError(
+                            f"wrong signature (#{idx}): "
+                            f"{commit_sig.signature.hex()}"
+                        )
+                    if ckey is not None:
+                        sigcache.add_key(ckey)
+                else:
+                    pending.setdefault(key_type, []).append(
+                        (
+                            pub_key, vote_sign_bytes, commit_sig.signature,
+                            idx, ckey,
+                        )
+                    )
+            route.set(inline=inline)
         if use_cache:
             sigcache.observe(hits_n, misses_n)
             trace.add_attrs(sigcache_hits=hits_n, sigcache_misses=misses_n)
@@ -746,7 +785,8 @@ def _verify_commit_batch_vector(
         raise NotEnoughVotingPowerError(tallied, voting_power_needed)
     _drain_pending(commit, pending)
     if ckey_commit is not None:
-        sigcache.add_commit(ckey_commit)
+        with trace.span("sigcache_populate", keys=1):
+            sigcache.add_commit(ckey_commit)
 
 
 def _verify_commit_batch_scalar(
@@ -870,10 +910,11 @@ def _drain_pending(commit: Commit, pending: dict) -> None:
     proven triples, and raise the reference error for the LOWEST bad
     commit index across groups."""
     first_bad: Optional[int] = None
-    for items in pending.values():
-        bv = create_batch_verifier(items[0][0], size_hint=len(items))
-        for pub_key, sb, sig, _idx, _ckey in items:
-            bv.add(pub_key, sb, sig)
+    for key_type, items in pending.items():
+        with trace.span("batch_add", key=key_type, sigs=len(items)):
+            bv = create_batch_verifier(items[0][0], size_hint=len(items))
+            for pub_key, sb, sig, _idx, _ckey in items:
+                bv.add(pub_key, sb, sig)
         ok, valid_sigs = drain_and_cache(bv, [it[4] for it in items])
         if ok:
             continue

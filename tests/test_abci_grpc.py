@@ -89,6 +89,7 @@ def test_node_runs_against_grpc_app(tmp_path):
         cfg.base.proxy_app = f"127.0.0.1:{app_srv.bound_port}"
         cfg.consensus.timeout_commit = 0.2
         cfg.rpc.laddr = "tcp://127.0.0.1:0"
+        cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
         cfg.ensure_dirs()
         genesis.save_as(cfg.base.path(cfg.base.genesis_file))
         FilePV.from_priv_key(

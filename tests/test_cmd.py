@@ -118,6 +118,7 @@ def test_start_runs_and_produces_blocks(tmp_path):
     cfg = load_config(cfg_path)
     cfg.consensus.timeout_commit = 0.2
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
     from tendermint_tpu.config import write_config
 
     write_config(cfg, cfg_path)
@@ -194,6 +195,7 @@ def test_debug_bundle(tmp_path, capsys):
     cfg = load_config(cfg_path)
     cfg.consensus.timeout_commit = 0.2
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
     write_config(cfg, cfg_path)
 
     async def produce():
@@ -242,6 +244,7 @@ def test_replay_console(tmp_path, monkeypatch, capsys):
     cfg = load_config(cfg_path)
     cfg.consensus.timeout_commit = 0.2
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
     write_config(cfg, cfg_path)
 
     async def produce():
@@ -326,6 +329,45 @@ def test_debug_bundle_device_profile(tmp_path):
         )
 
 
+def test_device_profile_carries_the_programs_spans(tmp_path):
+    """The capture turns span tracing on for its own length, mirrored
+    into the profiler's trace: the xplane file names the program's
+    phases around the runtime's events, the same spans are in the ring
+    the bundle exports as trace.json, and the recorder is left as it
+    was found."""
+    import tarfile
+
+    from jax.profiler import ProfileData
+
+    from tendermint_tpu.cmd import commands
+    from tendermint_tpu.libs import trace
+
+    trace.disable()
+    trace.reset()
+    trace.set_mirror(None)
+    out = str(tmp_path / "profile_only.tar")
+    with tarfile.open(out, "w") as tar:
+        summary = commands._capture_device_profile(tar, n=8)
+    assert summary["batch"] == 8
+    assert not trace.is_enabled() and trace.set_mirror(None) is None
+    names = [s.name for s in trace.snapshot()]
+    for phase in ("pack_rows", "device_launch", "debug_profile_batch"):
+        assert phase in names, names
+    trace.reset()
+    with tarfile.open(out) as tar:
+        (member,) = [
+            m for m in tar.getmembers() if m.name.endswith(".xplane.pb")
+        ]
+        assert member.name.startswith("device_profile/")
+        data = tar.extractfile(member).read()
+    host = next(
+        p for p in ProfileData.from_serialized_xspace(data).planes
+        if p.name == "/host:CPU"
+    )
+    seen = {e.name for line in host.lines for e in line.events}
+    assert {"debug_profile_batch", "pack_rows", "device_launch"} <= seen
+
+
 def test_light_proxy_serves_verified_headers(tmp_path):
     """Boot a full node in-process, run the light proxy logic against
     its RPC, and fetch a verified header through the proxy surface
@@ -353,6 +395,7 @@ def test_light_proxy_serves_verified_headers(tmp_path):
         cfg.base.db_backend = "memdb"
         cfg.consensus.timeout_commit = 0.2
         cfg.rpc.laddr = "tcp://127.0.0.1:0"
+        cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
         cfg.ensure_dirs()
         genesis.save_as(cfg.base.path(cfg.base.genesis_file))
         FilePV.from_priv_key(
@@ -452,6 +495,7 @@ def test_reindex_event_rebuilds_tx_index(tmp_path, capsys):
     cfg = load_config(cfg_path)
     cfg.consensus.timeout_commit = 0.2
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
     cfg.base.db_backend = "sqlite"
     write_config(cfg, cfg_path)
 

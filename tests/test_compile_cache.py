@@ -67,6 +67,31 @@ def test_default_is_the_checkout_from_any_cwd_and_process(
     assert _child(str(tmp_path)) == [DEFAULT_DIR, DEFAULT_DIR]
 
 
+_CHILD_KEY = (
+    "import jax\n"
+    "jax.config.update('jax_platforms', {platforms!r})\n"
+    "from tendermint_tpu.ops import compile_cache\n"
+    "compile_cache.enable()\n"
+    "print(jax.config.jax_compilation_cache_include_metadata_in_key)\n"
+)
+
+
+@pytest.mark.parametrize(
+    "platforms,keyed", [("cpu", "False"), ("tpu,cpu", "True")]
+)
+def test_metadata_is_in_the_key_where_profiles_are_read(platforms, keyed):
+    """Stage names are metadata: a process that may reach an
+    accelerator keys its cache on them, so a profile never shows the
+    names of an older compile; a CPU-pinned one keeps JAX's default.
+    Nothing here starts a backend."""
+    out = subprocess.run(
+        [sys.executable, "-c", _CHILD_KEY.format(platforms=platforms)],
+        cwd=REPO, env=dict(os.environ, PYTHONPATH=REPO),
+        capture_output=True, text=True, timeout=120, check=True,
+    ).stdout
+    assert out.strip().splitlines()[-1] == keyed
+
+
 def test_one_assignment_in_the_tree():
     """No other file sets the cache directory in code."""
     needle = re.compile(r"update\(\s*[\"']jax_compilation_" + "cache_dir")

@@ -241,6 +241,7 @@ def test_sql_sink_in_node_config(tmp_path):
         genesis = make_genesis([priv])
         cfg = make_home(tmp_path, 0, genesis, priv)
         cfg.tx_index.indexer = ["psql"]
+        cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
         node = make_node(cfg)
         from tendermint_tpu.state.sink_sql import SQLSink
 

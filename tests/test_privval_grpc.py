@@ -155,6 +155,7 @@ def test_node_with_grpc_signer_produces_blocks(tmp_path):
         priv = PrivKeyEd25519.from_seed(b"\x61" * 32)
         genesis = make_genesis([priv])
         cfg = make_home(tmp_path, 0, genesis, None)
+        cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
         cfg.base.mode = "validator"
 
         pv = FilePV.from_priv_key(

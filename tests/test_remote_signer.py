@@ -189,6 +189,7 @@ def test_node_with_remote_signer_produces_blocks(tmp_path):
         cfg.base.db_backend = "memdb"
         cfg.consensus.timeout_commit = 0.2
         cfg.rpc.laddr = "tcp://127.0.0.1:0"
+        cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
         cfg.priv_validator.listen_addr = "tcp://127.0.0.1:0"
         cfg.ensure_dirs()
         genesis.save_as(cfg.base.path(cfg.base.genesis_file))

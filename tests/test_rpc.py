@@ -42,6 +42,7 @@ def _make_cfg(tmp_path) -> tuple[Config, PrivKeyEd25519]:
     cfg.base.db_backend = "memdb"
     cfg.consensus.timeout_commit = 0.2
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
+    cfg.p2p.laddr = "tcp://127.0.0.1:0"  # a free port, not 26656
     cfg.ensure_dirs()
     genesis.save_as(cfg.base.path(cfg.base.genesis_file))
     FilePV.from_priv_key(

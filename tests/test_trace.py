@@ -5,6 +5,8 @@ with merkle_hash in the same tree) from a live 4-validator consensus
 run with the device batch-verifier seam installed."""
 
 import asyncio
+import contextlib
+import gc
 import json
 
 import pytest
@@ -73,6 +75,38 @@ class TestSpans:
             assert trace.current() is None
         assert trace.snapshot() == []
 
+    def test_disabled_path_on_the_verify_path(self, monkeypatch):
+        """With tracing off the commit-verification call sites — the
+        decode, the phases of validation, the batch seam and the
+        kernels' dispatch — create no Span, call no mirror and leave no
+        collector hook behind."""
+        made = []
+        real_init = trace.Span.__init__
+
+        def counting_init(self, *a, **kw):
+            made.append(a[0] if a else kw.get("name"))
+            real_init(self, *a, **kw)
+
+        monkeypatch.setattr(trace.Span, "__init__", counting_init)
+        mirrored = []
+        from tendermint_tpu.crypto import sigcache
+
+        with _device_seam(chunk=8):
+            # in place of the profiler's annotation install() registers
+            trace.set_mirror(
+                lambda name: mirrored.append(name)
+                or contextlib.nullcontext()
+            )
+            _verify_both(20)
+            assert made == [] and mirrored == []
+            assert trace._on_gc not in gc.callbacks
+            assert trace.snapshot() == []
+            # the same calls with tracing on, the cache cold again
+            sigcache.reset()
+            trace.enable()
+            _verify_both(20)
+        assert "tpu_stream_dispatch" in made and "pack_rows" in mirrored
+
     def test_span_feeds_histogram_enabled_and_disabled(self):
         h = Histogram("t_span_h", "help", buckets=(0.5, 10.0))
         # disabled: degrades to exactly hist.time()
@@ -116,6 +150,203 @@ class TestSpans:
             assert e["ph"] == "X"
             assert isinstance(e["ts"], float)
             assert isinstance(e["dur"], float)
+
+
+class TestMirrorRootAndCollector:
+    def test_mirror_enters_and_leaves_in_nesting_order(self):
+        log = []
+
+        @contextlib.contextmanager
+        def mirror(name):
+            log.append(("enter", name))
+            try:
+                yield
+            finally:
+                log.append(("exit", name))
+
+        trace.set_mirror(mirror)
+        try:
+            # off: neither the no-op span nor hist.time() touches it
+            with trace.span("off"):
+                pass
+            with trace.span("off", hist=Histogram("t_mir_h", "help")):
+                pass
+            with trace.NOOP_SPAN:
+                pass
+            assert log == []
+            trace.enable()
+            with trace.span("outer"):
+                with trace.span("inner"):
+                    pass
+                with trace.span("second"):
+                    pass
+            assert log == [
+                ("enter", "outer"), ("enter", "inner"), ("exit", "inner"),
+                ("enter", "second"), ("exit", "second"), ("exit", "outer"),
+            ]
+            with pytest.raises(ValueError):
+                with trace.span("boom"):
+                    raise ValueError("x")
+            assert log[-2:] == [("enter", "boom"), ("exit", "boom")]
+            # cleared: spans go on, the mirror hears nothing
+            trace.set_mirror(None)
+            with trace.span("unmirrored"):
+                pass
+            assert ("enter", "unmirrored") not in log
+        finally:
+            trace.set_mirror(None)
+
+    def test_root_id_is_shared_down_a_tree_and_differs_between_trees(self):
+        trace.enable()
+        for _ in range(2):
+            with trace.span("root"):
+                with trace.span("child"):
+                    with trace.span("grandchild"):
+                        pass
+        spans = trace.snapshot()
+        first, second = spans[:3], spans[3:]
+        assert {s.root_id for s in first} == {first[-1].span_id}
+        assert {s.root_id for s in second} == {second[-1].span_id}
+        assert first[-1].span_id != second[-1].span_id
+        doc = json.loads(trace.to_chrome_trace())
+        assert {e["args"]["root_id"] for e in doc["traceEvents"]} == {
+            first[-1].span_id, second[-1].span_id
+        }
+
+    def test_collection_inside_a_span_is_a_child_span(self):
+        trace.enable()
+        assert trace._on_gc in gc.callbacks
+        with trace.span("phase"):
+            gc.collect()
+        spans = trace.snapshot()
+        phase = next(s for s in spans if s.name == "phase")
+        pauses = [s for s in spans if s.name == "gc_collect"]
+        assert pauses and all(s.parent_id == phase.span_id for s in pauses)
+        full = [s for s in pauses if s.attrs["generation"] == 2]
+        assert full and "collected" in full[0].attrs
+        assert full[0].root_id == phase.span_id
+        # outside any span a collection is nobody's child: not recorded
+        trace.reset()
+        gc.collect()
+        assert trace.snapshot() == []
+        trace.disable()
+        assert trace._on_gc not in gc.callbacks
+
+
+@contextlib.contextmanager
+def _device_seam(chunk):
+    """The device factories installed on the CPU backend, streaming as
+    on an accelerator in chunks of `chunk` (a configured bucket, so no
+    further program shape is compiled)."""
+    from tendermint_tpu.crypto import tpu_verifier
+
+    seam = tpu_verifier._TpuBatchVerifier
+    held = (seam.__dict__["_streaming"], seam.STREAM_CHUNK)
+    seam._streaming = staticmethod(lambda: True)
+    seam.STREAM_CHUNK = chunk
+    tpu_verifier.install(min_batch=2)
+    try:
+        yield
+    finally:
+        tpu_verifier.uninstall()
+        seam._streaming, seam.STREAM_CHUNK = held
+
+
+def _verify_both(n):
+    """verify_commit and verify_commit_light of one n-validator commit,
+    decoded from its wire bytes as a node does with a block, each
+    against a cold verified-signature cache."""
+    from tendermint_tpu.crypto import sigcache
+    from tendermint_tpu.types import (
+        Commit,
+        verify_commit,
+        verify_commit_light,
+    )
+
+    from .test_types import CHAIN_ID
+    from .test_validation import make_commit
+
+    vals, bid, commit = make_commit(n)
+    wire = commit.to_proto()
+    verify_commit(CHAIN_ID, vals, bid, 1, Commit.from_proto(wire))
+    sigcache.reset()
+    verify_commit_light(CHAIN_ID, vals, bid, 1, Commit.from_proto(wire))
+
+
+# the phase spans of one commit verification: name -> the span it
+# opens under (docs/metrics.md "Span tracing")
+PHASE_PARENTS = {
+    "commit_plan": "batch_accumulate",
+    "sign_bytes": "batch_accumulate",
+    "sigcache_probe": "batch_accumulate",
+    "batch_route": "batch_accumulate",
+    "batch_add": "batch_accumulate",
+    "tpu_stream_dispatch": "batch_add",
+    "tpu_dispatch": "batch_accumulate",
+    "tpu_gather": "tpu_dispatch",
+    "sigcache_populate": "batch_accumulate",
+}
+
+
+def test_commit_verification_phase_tree():
+    """One commit verification is one tree: every phase of section B
+    under its parent, the streamed chunks under `batch_add`, the gather
+    under `tpu_dispatch`, the kernels' packing and launches under
+    either dispatch span, and next to nothing of `batch_accumulate`
+    left without a name."""
+    pytest.importorskip("jax")
+    trace.enable(capacity=65536)
+    with _device_seam(chunk=8):
+        _verify_both(20)  # verify_commit: 8 + 8 + 4; light: 8 + 6
+    spans = trace.snapshot()
+    by_id = {s.span_id: s for s in spans}
+    roots = [s for s in spans if not s.parent_id]
+    assert [s.name for s in roots] == [
+        "commit_decode", "batch_accumulate",
+        "commit_decode", "batch_accumulate",
+    ]
+    assert roots[0].attrs["sigs"] == 20 and roots[0].attrs["bytes"] > 0
+    for full, light in ((roots[1], False), (roots[3], True)):
+        tree = [s for s in spans if s.root_id == full.span_id]
+        for s in tree:
+            if s.name in PHASE_PARENTS:
+                assert by_id[s.parent_id].name == PHASE_PARENTS[s.name], s.name
+        names = [s.name for s in tree]
+        for name in PHASE_PARENTS:
+            assert name in names, (name, light)
+        streamed = [s for s in tree if s.name == "tpu_stream_dispatch"]
+        assert [s.attrs["chunk"] for s in streamed] == (
+            [0] if light else [0, 1]
+        )
+        assert all(
+            s.attrs["n"] == s.attrs["bucket"] == 8
+            and s.attrs["key"] == "ed25519"
+            for s in streamed
+        )
+        for leaf in ("pack_rows", "device_launch"):
+            under = {
+                by_id[s.parent_id].name for s in tree if s.name == leaf
+            }
+            assert under == {"tpu_stream_dispatch", "tpu_dispatch"}, leaf
+        launches = [s for s in tree if s.name == "device_launch"]
+        # a tile and its SHA-512 a dispatch
+        assert len(launches) == 2 * (len(streamed) + 1)
+        assert {s.attrs["program"] for s in launches} == {
+            "_verify_tile", "sha512_fixed"
+        }
+        (dispatch,) = [s for s in tree if s.name == "tpu_dispatch"]
+        assert dispatch.attrs["host_prep_s"] >= 0.0
+        assert "device_wall_s" not in dispatch.attrs
+        (gather,) = [s for s in tree if s.name == "tpu_gather"]
+        assert gather.attrs["handles"] == len(streamed) + 1
+        assert gather.attrs["sigs"] == (14 if light else 20)
+        (add,) = [s for s in tree if s.name == "batch_add"]
+        assert add.attrs == {"key": "ed25519", "sigs": gather.attrs["sigs"]}
+        assert full.attrs["sigcache_misses"] == gather.attrs["sigs"]
+        # what the phases leave of batch_accumulate is its self time
+        kids = [s for s in tree if s.parent_id == full.span_id]
+        covered = sum(s.dur_us for s in kids)
+        assert covered >= 0.9 * full.dur_us, (covered, full.dur_us)
 
 
 class _FakeKernel:
