@@ -1,10 +1,14 @@
-"""AddressSanitizer sweep of the native batch-equation kernel.
+"""AddressSanitizer sweep of the native batch-equation kernel and the
+commit signature scanner.
 
 Builds an ASAN variant of native/ed25519_batch.c and drives every
 exported entry point through all three MSM paths (Straus < 1024 terms,
 Pippenger w8, Pippenger w11), multi-block SHA-512 message shapes, the
 scalar/hash test hooks, and the sr25519 ristretto path — valid and
-corrupted batches. Run after ANY change to the C kernel:
+corrupted batches. Then an ASAN variant of native/commit_scan.c over a
+golden commit, every truncation of it and a few thousand seeded
+mutations, input and columns in exact-size sanitizer allocations. Run
+after ANY change to a C unit:
 
     python scripts/asan_check.py
 
@@ -22,17 +26,20 @@ import sys
 import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SRC = os.path.join(REPO, "tendermint_tpu", "native", "ed25519_batch.c")
+NATIVE = os.path.join(REPO, "tendermint_tpu", "native")
+UNITS = ("ed25519_batch", "commit_scan")
 
 
 def main() -> int:
     cc = os.environ.get("CC", "cc")
-    so = os.path.join(tempfile.mkdtemp(), "ed25519_batch_asan.so")
-    subprocess.run(
-        [cc, "-O1", "-g", "-fsanitize=address", "-shared", "-fPIC",
-         "-o", so, SRC],
-        check=True,
-    )
+    out = tempfile.mkdtemp()
+    sos = [os.path.join(out, f"{unit}_asan.so") for unit in UNITS]
+    for unit, so in zip(UNITS, sos):
+        subprocess.run(
+            [cc, "-O1", "-g", "-fsanitize=address", "-shared", "-fPIC",
+             "-o", so, os.path.join(NATIVE, f"{unit}.c")],
+            check=True,
+        )
     asan = subprocess.run(
         [cc, "-print-file-name=libasan.so"],
         capture_output=True, text=True, check=True,
@@ -40,7 +47,7 @@ def main() -> int:
     # re-exec under LD_PRELOAD so ASAN is initialized before python
     if not os.environ.get("TM_ASAN_CHILD"):
         env = dict(os.environ)
-        env["TM_ASAN_CHILD"] = so
+        env["TM_ASAN_CHILD"] = os.pathsep.join(sos)
         env["LD_PRELOAD"] = asan
         env.setdefault("ASAN_OPTIONS", "detect_leaks=0")
         env["JAX_PLATFORMS"] = "cpu"
@@ -48,7 +55,8 @@ def main() -> int:
         return subprocess.run(
             [sys.executable, os.path.abspath(__file__)], env=env
         ).returncode
-    return run_checks(os.environ["TM_ASAN_CHILD"])
+    batch_so, scan_so = os.environ["TM_ASAN_CHILD"].split(os.pathsep)
+    return run_checks(batch_so) or run_commit_scan_checks(scan_so)
 
 
 def _ed25519_keygen():
@@ -264,6 +272,98 @@ def run_checks(so: str) -> int:
         assert rc == 0, k
 
     print("ASAN PASS: all entry points, all MSM paths, no reports")
+    return 0
+
+
+def run_commit_scan_checks(so: str) -> int:
+    """tm_commit_scan over hostile bytes. A Python bytes object keeps a
+    NUL after its last byte inside an allocation the sanitizer does not
+    see, so each input is copied into a malloc of its exact size (the
+    preloaded runtime's, with redzones), and the columns likewise: a
+    read or write one past either end is a report. What the scan
+    accepts must hold the generic decoder's entry count."""
+    sys.path.insert(0, REPO)
+    os.environ["TM_TPU_NO_NATIVE"] = "1"  # the oracle below is generic
+    from tendermint_tpu.types.block_id import BlockID, PartSetHeader
+    from tendermint_tpu.types.commit import Commit, CommitSig
+
+    lib = ctypes.CDLL(so)
+    lib.tm_commit_scan.argtypes = [
+        ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p, ctypes.c_long,
+        ctypes.POINTER(ctypes.c_long),
+    ]
+    lib.tm_commit_scan.restype = ctypes.c_long
+    libc = ctypes.CDLL(None)
+    libc.malloc.argtypes = [ctypes.c_size_t]
+    libc.malloc.restype = ctypes.c_void_p
+    libc.free.argtypes = [ctypes.c_void_p]
+
+    def scan(data: bytes) -> int:
+        cap = len(data) // 2
+        buf = libc.malloc(len(data))
+        cols = libc.malloc(6 * 8 * cap)
+        ctypes.memmove(buf, data, len(data))
+        head_end = ctypes.c_long()
+        try:
+            return lib.tm_commit_scan(
+                buf, len(data), cols, cap, ctypes.byref(head_end)
+            )
+        finally:
+            libc.free(buf)
+            libc.free(cols)
+
+    rng = random.Random(27)
+    sigs = []
+    for i in range(12):
+        ts = (i - 3) * 1_000_000_007 * 10 ** (i % 4)
+        if i % 5 == 2:
+            sigs.append(CommitSig.absent())
+        elif i % 5 == 4:
+            sigs.append(CommitSig.for_nil(rng.randbytes(64), rng.randbytes(20), ts))
+        else:
+            sigs.append(CommitSig.for_block(rng.randbytes(64), rng.randbytes(20), ts))
+    golden = Commit(
+        height=9, round=2, signatures=sigs,
+        block_id=BlockID(
+            hash=b"\x03" * 32,
+            part_set_header=PartSetHeader(total=2, hash=b"\x04" * 32),
+        ),
+    ).to_proto()
+    assert scan(golden) == len(sigs), scan(golden)
+    for cut in range(len(golden) + 1):
+        scan(golden[:cut])
+    accepted = 0
+    for _ in range(4000):
+        b = bytearray(golden)
+        for _ in range(rng.randrange(1, 5)):
+            at = rng.randrange(len(b))
+            op = rng.randrange(4)
+            if op == 0:
+                b[at] = rng.randrange(256)
+            elif op == 1:
+                b[at] |= 0x80  # stretch a varint over what follows
+            elif op == 2:
+                del b[at : at + rng.randrange(1, 8)]
+            else:
+                b[at:at] = rng.randbytes(rng.randrange(1, 8))
+            if not b:
+                b = bytearray(b"\x22")
+        n = scan(bytes(b))
+        if n >= 0:
+            accepted += 1
+            try:
+                want = len(Commit.from_proto(bytes(b)).signatures)
+            except ValueError:
+                continue  # the head, which the scan only skips
+            assert n == want, bytes(b).hex()
+    # degenerate shapes: nothing, a lone tag, the most entries an input
+    # can hold, a length that claims 2**62 bytes
+    for data in (b"", b"\x22", b"\x22\x00" * 4096,
+                 b"\x22\xff\xff\xff\xff\xff\xff\xff\xff\x3f\x08"):
+        scan(data)
+    assert 0 < accepted < 4000, accepted
+    print(f"ASAN PASS: commit_scan, {len(golden) + 1} truncations, "
+          f"4000 mutations ({accepted} still canonical), no reports")
     return 0
 
 

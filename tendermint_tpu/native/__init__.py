@@ -23,7 +23,14 @@ import subprocess
 import tempfile
 from typing import Optional
 
-__all__ = ["load", "keccakf_lib", "signbytes_lib", "ed25519_batch_lib"]
+__all__ = [
+    "load",
+    "keccakf_lib",
+    "signbytes_lib",
+    "ed25519_batch_lib",
+    "commit_scan_lib",
+    "commit_scan",
+]
 
 _SRC_DIR = os.path.dirname(os.path.abspath(__file__))
 _LIBS: dict = {}
@@ -118,6 +125,54 @@ def signbytes_lib():
         lib.tm_vote_sign_bytes_batch.restype = ctypes.c_long
         lib._tm_configured = True
     return lib
+
+
+def commit_scan_lib():
+    """The commit signature scanner with argtypes set, or None. Exposes
+    ``tm_commit_scan`` (see commit_scan.c for the contract)."""
+    lib = load("commit_scan")
+    if lib is None:
+        return None
+    if not getattr(lib, "_tm_configured", False):
+        lib.tm_commit_scan.argtypes = [
+            ctypes.c_char_p,
+            ctypes.c_long,
+            ctypes.c_void_p,
+            ctypes.c_long,
+            ctypes.POINTER(ctypes.c_long),
+        ]
+        lib.tm_commit_scan.restype = ctypes.c_long
+        lib._tm_configured = True
+    return lib
+
+
+def commit_scan(data) -> Optional[tuple]:
+    """Scan an encoded Commit's `signatures` entries in one native
+    pass. Returns ``(head_end, columns)`` — the offset where the
+    entries start, and six equal-length lists (flag, address start,
+    address end, timestamp ns, signature start, signature end; the
+    ranges index `data`) — or None when the bytes are not the
+    canonical layout commit_scan.c accepts, `data` is not `bytes`, or
+    native is unavailable: the caller then decodes generically."""
+    if type(data) is not bytes:
+        return None
+    lib = commit_scan_lib()
+    if lib is None:
+        return None
+    import numpy as np
+
+    # an entry is at least two bytes (tag, length): the columns are
+    # sized from the input's length alone, and left uninitialised —
+    # only the rows the scan wrote are read back
+    cap = len(data) // 2
+    cols = np.empty((6, cap), dtype=np.int64)
+    head_end = ctypes.c_long()
+    n = lib.tm_commit_scan(
+        data, len(data), cols.ctypes.data, cap, ctypes.byref(head_end)
+    )
+    if n < 0:
+        return None
+    return head_end.value, cols[:, :n].tolist()
 
 
 def keccakf_lib():
