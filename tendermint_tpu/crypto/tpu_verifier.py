@@ -101,6 +101,13 @@ _m_pallas_fallbacks = M.new_counter(
     "Opt-in Pallas programs that failed and were swapped for XLA.",
 )
 
+_m_mesh_devices = M.new_gauge(
+    "tpu",
+    "mesh_devices",
+    "Chips the installed verifiers shard a batch over (1 without a "
+    "mesh, 0 when nothing is installed).",
+)
+
 __all__ = [
     "TpuEd25519BatchVerifier",
     "TpuSr25519BatchVerifier",
@@ -150,6 +157,13 @@ def _bucket_of(verifier, n: int) -> int:
         if b >= n:
             return b
     return n
+
+
+def _mesh_devices(verifier) -> int:
+    """How many chips a backing verifier divides a dispatch over: the
+    size of its mesh (parallel/sharding.py), 1 without one."""
+    mesh = getattr(verifier, "mesh", None)
+    return 1 if mesh is None else int(mesh.devices.size)
 
 
 def _note_bucket_warmth(key_type: str, verifier, bucket: int) -> bool:
@@ -487,6 +501,7 @@ class _TpuBatchVerifier(BatchVerifier):
                     key=self.KEY_TYPE,
                     n=len(self._pks),
                     chunk=len(self._handles),
+                    mesh_devices=_mesh_devices(v),
                 ) as span:
                     try:
                         self._dispatch_pending(v)
@@ -646,6 +661,7 @@ class _TpuBatchVerifier(BatchVerifier):
                 bucket=self._last_bucket,
                 pad_waste=self._pad_waste,
                 warm=not self._cold_dispatch,
+                mesh_devices=_mesh_devices(v),
             )
         _m_sigs.inc(device_sigs)
         return all(bits), bits
@@ -778,6 +794,7 @@ def stats() -> dict:
         "pad_waste": int(_m_pad_waste.value()),
         "warm_misses": int(_m_warm_misses.value()),
         "pallas_fallbacks": int(_m_pallas_fallbacks.value()),
+        "mesh_devices": int(_m_mesh_devices.value()),
     }
 
 
@@ -937,6 +954,7 @@ def install(
     # orphaned breaker nobody consults
     _SHARED_VERIFIER = new_ed
     _SHARED_VERIFIER_SR = new_sr  # tmrace: race-ok — same protocol
+    _m_mesh_devices.set(_mesh_devices(new_ed))
     # new generation: every bucket is cold again
     # tmlint: disable=lock-global-mutation — install() runs on the
     # startup/main thread before traffic
@@ -1005,6 +1023,7 @@ def uninstall() -> None:
     _WARM_BUCKETS.clear()
     _MIN_BATCH = DEFAULT_MIN_BATCH
     _INSTALLED = False
+    _m_mesh_devices.set(0)
     for name in ("ed25519", "sr25519", _SR_SINGLE):
         _breaker_mod.discard(name)
     set_group_affinity_fn(native_cpu_affinity)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..libs import trace
 from ..ops import ed25519_kernel as K
 from ..ops import sr25519_kernel as SR
 
@@ -80,8 +81,20 @@ class _MeshSharded:
     def _place(self, rows):
         """Shard host rows over the mesh straight from the host: a
         plain jnp.asarray would land the whole batch on the first
-        device and leave the program to reshard it."""
-        return jax.device_put(rows, self._mat())
+        device and leave the program to reshard it. Each transfer is a
+        `shard_place` span, a child of the `device_launch` around it;
+        rows that are on the mesh already (the sharded SHA-512's
+        digests, handed to the tile) move nothing and open no span."""
+        if isinstance(rows, jax.Array):
+            return jax.device_put(rows, self._mat())
+        n = self.mesh.devices.size
+        with trace.span(
+            "shard_place",
+            devices=n,
+            lanes_per_device=rows.shape[-1] // n,
+            bytes=rows.nbytes,
+        ):
+            return jax.device_put(rows, self._mat())
 
     def _sha512_program(self):
         """SHA-512 partitioned like the tile, so every device hashes

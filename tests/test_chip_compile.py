@@ -185,26 +185,63 @@ def test_merkle_proof_batch_of_10k_leaves(compile_for_chip, one_chip):
     _assert_fits(compiled)
 
 
-def test_sharded_ed25519_tile_on_four_chips(compile_for_chip, topo, lanes):
-    """`[tpu] devices = 4`: ShardedEd25519Verifier's own program over a
-    mesh of the four described chips. The compiled module must hold the
-    batch axis partitioned — each chip a quarter of the lanes — and no
-    chip the whole batch."""
-    from tendermint_tpu.parallel import ShardedEd25519Verifier, make_mesh
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from tendermint_tpu.parallel import make_mesh
 
     mesh = make_mesh(topo.devices)
     assert mesh.devices.size == 4
-    v = ShardedEd25519Verifier(mesh)
-    n = v._bucket(lanes)
-    mat = NamedSharding(mesh, P(None, "sig"))
-    _lowered, compiled = compile_for_chip(
-        v._program(n),
-        _rows(32, n, mat),
-        _rows(64, n, mat),
-        _rows(64, n, mat),
-    )
+    return mesh
+
+
+def _assert_batch_axis_partitioned(compiled, n: int, row_counts) -> None:
+    """Each chip holds a quarter of the lanes of every input, and none
+    the whole batch."""
     text = compiled.as_text()
-    per_chip = n // 4
-    assert f"u8[32,{per_chip}]" in text and f"u8[64,{per_chip}]" in text
-    assert f"u8[32,{n}]" not in text and f"u8[64,{n}]" not in text
+    for rows in row_counts:
+        assert f"u8[{rows},{n // 4}]" in text, rows
+        assert f"u8[{rows},{n}]" not in text, rows
+
+
+# the three partitioned programs of the cell commit-10k-mixed.cold-4chip
+# (`[tpu] devices = 4`), each the mesh verifier's own jitted program:
+# (verifier, rows of its three inputs)
+SHARDED_TILES = {
+    "ed25519": ("ShardedEd25519Verifier", (32, 64, 64)),
+    "sr25519": ("ShardedSr25519Verifier", (32, 64, 32)),
+}
+
+
+@pytest.mark.parametrize("key", sorted(SHARDED_TILES))
+def test_sharded_tile_on_four_chips(compile_for_chip, four_chips, lanes, key):
+    """A mesh verifier's tile over the four described chips, at the
+    bucket a streamed chunk lands in: 512 lanes a chip."""
+    from tendermint_tpu import parallel
+
+    name, rows = SHARDED_TILES[key]
+    v = getattr(parallel, name)(four_chips)
+    n = v._bucket(lanes)
+    mat = NamedSharding(four_chips, P(None, "sig"))
+    _lowered, compiled = compile_for_chip(
+        v._program(n), *(_rows(r, n, mat) for r in rows)
+    )
+    _assert_batch_axis_partitioned(compiled, n, set(rows))
+    _assert_fits(compiled)
+
+
+def test_sharded_sha512_on_four_chips(compile_for_chip, four_chips, lanes):
+    """The mesh verifier's SHA-512 over R || A || a 115-byte sign-bytes
+    (the benchmark's one length), partitioned like the tile: the
+    digests leave each chip as its own quarter and never gather."""
+    from tendermint_tpu.parallel import ShardedEd25519Verifier
+
+    v = ShardedEd25519Verifier(four_chips)
+    n = v._bucket(lanes)
+    mat = NamedSharding(four_chips, P(None, "sig"))
+    lowered, compiled = compile_for_chip(
+        v._sha512_program(), _rows(64 + 115, n, mat)
+    )
+    assert "stablehlo.while" not in lowered.as_text()
+    _assert_batch_axis_partitioned(compiled, n, (64 + 115, 64))
+    assert "all-gather" not in compiled.as_text()
     _assert_fits(compiled)
