@@ -29,6 +29,7 @@ import threading
 import time
 from typing import List, Optional, Tuple
 
+from ..libs import heap
 from ..libs import metrics as M
 from ..libs import trace
 from . import breaker as _breaker_mod
@@ -663,6 +664,11 @@ class _TpuBatchVerifier(BatchVerifier):
                 warm=not self._cold_dispatch,
                 mesh_devices=_mesh_devices(v),
             )
+            # every handle is gathered: if a dispatch of this batch (a
+            # chunk add() streamed, or the remainder) traced, compiled
+            # or loaded a program, its temporaries are dead and what it
+            # left behind is not going to die
+            heap.settle()
         _m_sigs.inc(device_sigs)
         return all(bits), bits
 
@@ -795,6 +801,7 @@ def stats() -> dict:
         "warm_misses": int(_m_warm_misses.value()),
         "pallas_fallbacks": int(_m_pallas_fallbacks.value()),
         "mesh_devices": int(_m_mesh_devices.value()),
+        **heap.stats(),
     }
 
 
@@ -874,16 +881,21 @@ def _device_probe(key_type: str, backing) -> bool:
     called from consensus threads."""
     pk, msg, sig = _probe_triple(key_type)
     v = backing()
-    if faults.armed():
-        faults.fire("tpu.dispatch", key=key_type)
-    if hasattr(v, "dispatch") and hasattr(v, "gather"):
-        handle = v.dispatch([pk], [msg], [sig])
-        bits = _gather_guarded(v, handle, key_type)
-    else:
-        raw = v.verify([pk], [msg], [sig])
-        bits = [bool(b) for b in raw]
+    with trace.span("tpu_probe", key=key_type):
         if faults.armed():
-            bits = faults.mangle("tpu.gather", bits, key=key_type)
+            faults.fire("tpu.dispatch", key=key_type)
+        if hasattr(v, "dispatch") and hasattr(v, "gather"):
+            handle = v.dispatch([pk], [msg], [sig])
+            bits = _gather_guarded(v, handle, key_type)
+        else:
+            raw = v.verify([pk], [msg], [sig])
+            bits = [bool(b) for b in raw]
+            if faults.armed():
+                bits = faults.mangle("tpu.gather", bits, key=key_type)
+        # the install's probe is the first touch of the smallest
+        # sr25519 bucket: settle before the verdict closes the breaker,
+        # so the route never opens over a heap still to be collected
+        heap.settle()
     return len(bits) == 1 and bool(bits[0])
 
 
@@ -914,6 +926,16 @@ def _sr_single_probe() -> bool:
     return _device_probe("sr25519", _sr_backing)
 
 
+def _on_compile_event(event: str, _duration: float, **_kw) -> None:
+    """jax.monitoring listener, registered between install() and
+    uninstall(): the event fires once for every program the backend
+    compiled or read back from the persistent cache — what a first
+    dispatch of a bucket does, and a re-install over programs `jit`
+    still holds does not."""
+    if event == "/jax/core/compile/backend_compile_duration":
+        heap.mark_dirty()
+
+
 def install(
     min_batch: int = DEFAULT_MIN_BATCH, mesh=None
 ) -> None:
@@ -924,8 +946,19 @@ def install(
     Each install is a new breaker generation: fresh instances replace
     the registered ones, so a probe still in flight from a superseded
     install publishes into an orphaned object nobody consults — the
-    atomicity the old _SR_WARM_GEN counter provided by hand."""
+    atomicity the old _SR_WARM_GEN counter provided by hand.
+
+    From here to uninstall() the process listens for JAX's compile
+    events: a seam call in which a program was traced, compiled or
+    loaded ends with one full collection and gc.freeze() (libs/heap.py),
+    so the collector never walks the traced programs again."""
     global _SHARED_VERIFIER, _SHARED_VERIFIER_SR, _MIN_BATCH, _INSTALLED
+    if not _INSTALLED:  # an install over an install is already listening
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event
+        )
     # tmrace: race-ok — install() runs on the startup/main thread; the
     # only cross-thread readers are breaker probes, and a probe from a
     # superseded generation publishes into an orphaned breaker (see
@@ -1006,7 +1039,9 @@ def uninstall() -> None:
     breakers are discarded — an in-flight probe publishes into an
     orphaned object — and the merged-window affinity falls back to the
     module default (batch.native_cpu_affinity) unless an operator
-    pinned a value explicitly."""
+    pinned a value explicitly. The compile listener goes and the heap
+    is thawed (gc.unfreeze()): the CPU seam gets the collector's whole
+    heap back, and a later install() freezes again at its next compile."""
     global _SHARED_VERIFIER, _SHARED_VERIFIER_SR, _MIN_BATCH, _INSTALLED
     from .batch import (
         native_cpu_affinity,
@@ -1021,6 +1056,12 @@ def uninstall() -> None:
     # tmlint: disable=lock-global-mutation — uninstall() is a
     # main-thread test/embedder seam, never concurrent with traffic
     _WARM_BUCKETS.clear()
+    if _INSTALLED:
+        import jax.monitoring
+
+        jax.monitoring.unregister_event_duration_listener(
+            _on_compile_event
+        )
     _MIN_BATCH = DEFAULT_MIN_BATCH
     _INSTALLED = False
     _m_mesh_devices.set(0)
@@ -1028,3 +1069,4 @@ def uninstall() -> None:
         _breaker_mod.discard(name)
     set_group_affinity_fn(native_cpu_affinity)
     trace.set_mirror(None)
+    heap.thaw()

@@ -263,6 +263,9 @@ def enable(capacity: Optional[int] = None) -> None:
     global _enabled
     if capacity is not None:
         set_capacity(capacity)
+    # tmrace: race-ok — GIL-atomic bool latch: a span another thread
+    # opens while it flips (the breaker's probe, crypto/tpu_verifier.py)
+    # is recorded or is the no-op singleton, both whole
     _enabled = True
     if _on_gc not in gc.callbacks:
         gc.callbacks.append(_on_gc)

@@ -43,11 +43,17 @@ def _release_compiled_executables():
     in a row, never in isolation). Clearing jax's caches lets the
     executables GC and unmap, so the per-process peak stays at the
     biggest single module, not the sum of all modules. Recompiles on
-    module boundaries are mostly persistent-cache hits."""
+    module boundaries are mostly persistent-cache hits.
+
+    A module that installed the device seam and dispatched may have left
+    the heap frozen (libs/heap.py): thaw first, or the executables'
+    cycles would sit in the permanent generation, where no collection
+    finds them."""
     yield
     import gc
 
     jax.clear_caches()
+    gc.unfreeze()
     gc.collect()
 
 

@@ -99,7 +99,8 @@ def test_the_manifest_has_the_cell_its_configuration_and_its_readers():
     assert set(one_chip["assumed"]) < set(config["assumed"])
     new = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in new] == list(MESH_METRICS)
-    assert [m["name"] for m in MANIFEST["per_layer"][-4:]] == list(MESH_METRICS)
+    at = MANIFEST["per_layer"].index(new[0])
+    assert MANIFEST["per_layer"][at : at + len(new)] == new  # added as one block
     for m in new:
         assert m["layer"] == "mesh (parallel/sharding.py)" and m["moves"] == "commits_per_s"
         assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", m["name"] + ".py"))
