@@ -29,8 +29,8 @@ batch harness, so the measured OpenSSL rate is the baseline and the
   - verify_commit_10k_warm: the same commit through the verified-
     signature cache (crypto/sigcache) after one priming run, plus the
     measured hit rate — the steady-state LastCommit shape. The cold
-    rows above run under sigcache.disabled() (equivalent to
-    TM_TPU_NO_SIGCACHE=1), so they stay comparable round over round
+    rows above run under sigcache.disabled(), so they stay comparable
+    round over round
   - the full config-5 mixed ed25519/sr25519 commits at 1k and 10k
     validators — both curves on device (ops/{ed25519,sr25519}_kernel)
   - per-signature batch curves for both key types at the reference
@@ -2095,17 +2095,6 @@ def bench_trace_all_buckets():
     }
 
 
-def bench_mosaic_probe():
-    """Toolchain capability verdict (ops/toolchain.mosaic_probe):
-    whether jaxpr-level Mosaic-cleanliness checks are decidable under
-    the installed jax — recorded so every BENCH_* line names the
-    capability it was measured under (and why
-    test_mosaic_jaxpr_clean may have skipped)."""
-    from tendermint_tpu.ops.toolchain import mosaic_probe
-
-    return mosaic_probe()
-
-
 def bench_device_rtt():
     import jax
     import jax.numpy as jnp
@@ -2864,9 +2853,8 @@ def main() -> None:
     fallback = not have_device
 
     # ---- chip-run pre-flight: the full trace sweep IS the pre-flight
-    # checklist's cost, and the mosaic probe names the toolchain
-    # capability this line was measured under. Both land in the line
-    # before any in-process device risk. eval_shape is abstract, but
+    # checklist's cost. It lands in the line before any in-process
+    # device risk. eval_shape is abstract, but
     # tracing still materializes trace-time constants on the default
     # backend — so on the fallback path pin this process to CPU FIRST
     # (the backend is not initialized yet; the probe ran in a
@@ -2874,8 +2862,6 @@ def main() -> None:
     # the subprocess probe just protected us from.
     if fallback:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    guard.tick("mosaic_probe", 120.0)
-    extra["mosaic_probe"] = attempt(bench_mosaic_probe)
     # the stage deadline derives from the SAME reader the sweep uses:
     # an operator raising TM_BENCH_TRACE_BUDGET_S must not outrun the
     # stall guard and get the line force-emitted mid-sweep

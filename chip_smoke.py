@@ -878,7 +878,12 @@ def phase_mesh_placement(n_sigs: int, seed: int) -> dict:
 
     stages = {
         "input_rows": where(v._place(np.zeros((32, bucket), np.uint8))),
-        "sha512_digests": where(v._digest_rows(pks, msgs, sigs, bucket)),
+        "sha512_digests": where(
+            v._third_operand(
+                pks, msgs, sigs, bucket,
+                v._pack_operand(pks, msgs, sigs, bucket),
+            )
+        ),
     }
     ok, n, _size_ok = v.dispatch(pks, msgs, sigs)
     stages["tile_bitmap"] = where(ok)

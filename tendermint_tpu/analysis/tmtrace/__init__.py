@@ -215,7 +215,7 @@ def analyze(
     violations.extend(shardcheck.mesh_axis_violations(pkg))
     violations.extend(shardcheck.donated_reuse_violations(pkg, roots))
     # the signature enumeration and the live tier need jax importable
-    # (bucket tables come from the live config through pallas_bucket);
+    # (bucket tables come from the live config);
     # on a jax-less box the nine static passes above still gate —
     # degrade these two to a RECORDED skip, never an exit-2 crash
     if signatures:

@@ -31,7 +31,7 @@ reported twice):
    three-valued provenance dataflow (static / unknown / dynamic):
    constants, SCREAMING names, attributes, `.shape` reads and
    arithmetic over them are static; results of the bucketizer family
-   (`bucket_for`, `pallas_bucket`, `*._bucket`) are static — that is
+   (`bucket_for`, `*._bucket`) are static — that is
    the pad-bucket table laundering a dynamic `len(batch)` into a
    compiled shape; `len(...)` is dynamic; function parameters take
    the meet of every resolved call site's argument provenance
@@ -73,7 +73,7 @@ FuncKey = Tuple[str, str]
 LEGACY_DEVICE_FILES = {"crypto/batch.py", "crypto/tpu_verifier.py"}
 LEGACY_DEVICE_PREFIXES = ("parallel/",)
 
-_BUCKETIZERS = ("bucket_for", "pallas_bucket")
+_BUCKETIZERS = ("bucket_for",)
 
 # attribute reads on an array that yield trace-static Python values
 _STATIC_ATTRS = {"shape", "ndim", "size", "dtype", "weak_type"}
@@ -95,8 +95,8 @@ def _line(pkg: Package, path: str, lineno: int) -> str:
 def _array_params(fi: FuncInfo, root: Optional[JitRoot]) -> Set[str]:
     """The parameters of a jit target that carry traced arrays: the
     ones without defaults, minus declared static args. Config flags
-    (`mosaic=False`, `dual_fn=None`) all carry defaults in this
-    codebase — a default marks a trace-time constant."""
+    (`with_t=True`, `mxu=False`) all carry defaults in this codebase —
+    a default marks a trace-time constant."""
     args = fi.node.args
     names = [a.arg for a in args.args]
     n_defaults = len(args.defaults)
@@ -701,7 +701,7 @@ def shape_leak_violations(pkg: Package) -> List[Violation]:
                             "every distinct value compiles a new XLA "
                             "program — derive the shape from the "
                             "pad-bucket table (bucket_for / "
-                            "pallas_bucket / *._bucket) or a "
+                            "*._bucket) or a "
                             "configured constant"
                         ),
                         source=_line(pkg, path, node.lineno),

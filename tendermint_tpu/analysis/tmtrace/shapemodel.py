@@ -7,8 +7,7 @@ the hot path (dev-shape-leak's rationale, made whole-program). This
 module declares, for every discovered jit root, how its input shapes
 are generated from the pad-bucket configuration — and enumerates the
 resulting signature set from the LIVE config
-(`config.DEFAULT_BUCKET_SIZES`, `pallas_bucket`, `TILE`,
-`field25519.NLIMBS`), so editing any of those regenerates a different
+(`config.DEFAULT_BUCKET_SIZES`), so editing it regenerates a different
 set and fails the drift gate until `scripts/lint.py
 --signatures-update` re-accepts it.
 
@@ -18,10 +17,11 @@ Three signature families:
   sha512 with its symbolic message-length dimension `M`),
 - *power-of-two*: merkle's `_bucket` (next pow2 ≥ n, min 8) yields an
   unbounded but structured family, recorded symbolically,
-- *mesh-sharded*: parallel/sharding.py's per-mesh programs, recorded
-  as the round-up formula over the base bucket table (the live
-  divisibility gate proves the formula; the underlying tile body
-  signatures are the ed25519/sr25519 entries).
+- *mesh-sharded*: ops/verifier.py's per-mesh programs (one jit site
+  partitions whichever shared program it is handed), recorded as the
+  round-up formula over the base bucket table (the live divisibility
+  gate proves the formula; the underlying body signatures are the
+  ed25519/sr25519/sha512 entries).
 
 A discovered root with no entry here is `trace-unknown-root` — the
 author of a new `jax.jit` must declare its shape family before the
@@ -31,12 +31,12 @@ exists to force.
 Trace cases: each entry also says how to build concrete
 (fn, avals) pairs for the no-TPU compile gate. `cost="fast"` cases
 (sha256/sha512/merkle — <0.5 s each) run in the default tier-1 gate;
-`cost="heavy"` cases (the crypto tiles and Pallas kernels, ~6-8 s of
-tracing EACH) run only in the full sweep
+`cost="heavy"` cases (the crypto tiles, ~6-8 s of tracing EACH) run
+only in the full sweep
 (`scripts/lint.py --trace-full`, timed by bench.py's
 `trace_all_buckets` row as the device-campaign pre-flight cost).
 The heavy tiles are still traced on every tier-1 run — by the
-differential tests (tests/test_ops_ed25519.py, test_ops_pallas.py),
+differential tests (tests/test_ops_ed25519.py, test_ops_sr25519.py),
 which execute them at small shapes — so the default gate skipping
 them costs no coverage, only the per-bucket enumeration.
 """
@@ -75,24 +75,6 @@ def _buckets() -> Tuple[int, ...]:
     from ...config import DEFAULT_BUCKET_SIZES
 
     return tuple(DEFAULT_BUCKET_SIZES)
-
-
-def _pallas_buckets() -> Tuple[int, ...]:
-    from ...ops.ed25519_kernel import pallas_bucket
-
-    return tuple(sorted({pallas_bucket(b) for b in _buckets()}))
-
-
-def _all_tile_buckets() -> Tuple[int, ...]:
-    # the XLA tile serves both the plain bucket table and, through
-    # run_with_pallas_fallback, the pallas-rounded buckets
-    return tuple(sorted(set(_buckets()) | set(_pallas_buckets())))
-
-
-def _nlimbs() -> int:
-    from ...ops import field25519 as F
-
-    return F.NLIMBS
 
 
 class TraceCase:
@@ -155,33 +137,17 @@ def _ed_tile_case(b: int) -> TraceCase:
     )
 
 
-def _sr_tile_case(b: int, hybrid: bool) -> TraceCase:
+def _sr_tile_case(b: int) -> TraceCase:
     def build():
         from ...ops.sr25519_kernel import _verify_tile_sr
 
-        if hybrid:
-            import functools
-
-            from ...ops.ed25519_pallas import dual_mult_pallas
-
-            fn = functools.partial(
-                _verify_tile_sr, dual_fn=dual_mult_pallas
-            )
-        else:
-            fn = _verify_tile_sr
-        return fn, _avals(
+        return _verify_tile_sr, _avals(
             ((32, b), "i32"), ((64, b), "i32"), ((32, b), "i32")
         )
 
-    rid = (
-        "ops/sr25519_kernel.py:functools.partial(_verify_tile_sr, "
-        "dual_fn=dual_mult_pallas)"
-        if hybrid
-        else "ops/sr25519_kernel.py:_verify_tile_sr"
-    )
     return TraceCase(
-        rid,
-        f"sr25519_{'hybrid' if hybrid else 'tile'}@{b}",
+        "ops/sr25519_kernel.py:_verify_tile_sr",
+        f"sr25519_tile@{b}",
         "heavy",
         build,
     )
@@ -233,33 +199,6 @@ def _merkle_proof_case(k: int, d: int) -> TraceCase:
     )
 
 
-def _pallas_case(kind: str, b: int) -> TraceCase:
-    def build():
-        import functools
-
-        from ...ops import ed25519_pallas as P
-
-        fn = getattr(P, kind)
-        fn = functools.partial(fn, interpret=False, tile=P.TILE)
-        L = _nlimbs()
-        if kind == "dual_mult_pallas":
-            avals = _avals(
-                ((4, L, b), "i32"), ((64, b), "i32"), ((64, b), "i32")
-            )
-        else:
-            avals = _avals(
-                ((32, b), "i32"), ((64, b), "i32"), ((64, b), "i32")
-            )
-        return fn, avals
-
-    return TraceCase(
-        f"ops/ed25519_pallas.py:{kind}",
-        f"{kind}@{b}",
-        "heavy",
-        build,
-    )
-
-
 def _sig(shapes_dtypes: Sequence[Tuple[str, str]]) -> str:
     return ",".join(f"{d}[{s}]" for s, d in shapes_dtypes)
 
@@ -275,9 +214,9 @@ def _build_model() -> Dict[str, RootModel]:
         "heavy",
         lambda: [
             _sig([(f"32,{b}", "i32"), (f"64,{b}", "i32"), (f"64,{b}", "i32")])
-            for b in _all_tile_buckets()
+            for b in _buckets()
         ],
-        lambda full: [_ed_tile_case(b) for b in _all_tile_buckets()]
+        lambda full: [_ed_tile_case(b) for b in _buckets()]
         if full
         else [],
     )
@@ -302,25 +241,10 @@ def _build_model() -> Dict[str, RootModel]:
         "heavy",
         lambda: [
             _sig([(f"32,{b}", "i32"), (f"64,{b}", "i32"), (f"32,{b}", "i32")])
-            for b in _all_tile_buckets()
+            for b in _buckets()
         ],
         lambda full: [
-            _sr_tile_case(b, hybrid=False) for b in _all_tile_buckets()
-        ]
-        if full
-        else [],
-    )
-    add(
-        "ops/sr25519_kernel.py:functools.partial(_verify_tile_sr, "
-        "dual_fn=dual_mult_pallas)",
-        "heavy",
-        lambda: [
-            _sig([(f"32,{b}", "i32"), (f"64,{b}", "i32"), (f"32,{b}", "i32")])
-            + " (pallas dual-mult segment)"
-            for b in _pallas_buckets()
-        ],
-        lambda full: [
-            _sr_tile_case(b, hybrid=True) for b in _pallas_buckets()
+            _sr_tile_case(b) for b in _buckets()
         ]
         if full
         else [],
@@ -345,66 +269,22 @@ def _build_model() -> Dict[str, RootModel]:
             for k, d in (((8, 8), (64, 16)) if full else ((8, 8),))
         ],
     )
-    for kind in ("verify_pallas", "dual_mult_pallas", "verify_hybrid"):
-        add(
-            f"ops/ed25519_pallas.py:{kind}",
-            "heavy",
-            (
-                lambda kind=kind: [
-                    (
-                        _sig(
-                            [
-                                (f"4,{_nlimbs()},{b}", "i32"),
-                                (f"64,{b}", "i32"),
-                                (f"64,{b}", "i32"),
-                            ]
-                        )
-                        if kind == "dual_mult_pallas"
-                        else _sig(
-                            [
-                                (f"32,{b}", "i32"),
-                                (f"64,{b}", "i32"),
-                                (f"64,{b}", "i32"),
-                            ]
-                        )
-                    )
-                    + " static:(interpret=False,tile=128)"
-                    for b in _pallas_buckets()
-                ]
-            ),
-            (
-                lambda full, kind=kind: [
-                    _pallas_case(kind, b)
-                    for b in (
-                        _pallas_buckets()
-                        if full
-                        else ()
-                    )
-                ]
-            ),
-        )
     add(
-        "parallel/sharding.py:type(self)._TILE_FN",
+        "ops/verifier.py:shared.__wrapped__",
         "heavy",
         lambda: [
             f"sharded(sig axis): base bucket {b} -> "
             "roundup(b, mesh) per mesh size"
             for b in _buckets()
-        ],
-        # no direct trace: the tile bodies are the ed25519/sr25519
-        # entries; mesh placement is proven by the divisibility gate
-        lambda full: [],
-    )
-    add(
-        "parallel/sharding.py:sha512_fixed",
-        "fast",
-        lambda: [
+        ]
+        + [
             f"sharded(sig axis): 64+M x base bucket {b} -> "
             "roundup(b, mesh) per mesh size M∈msg-len"
             for b in _buckets()
         ],
-        # no direct trace: the body is the ed25519_kernel sha512 entry,
-        # partitioned like the tile it feeds
+        # no direct trace: the bodies are the ed25519/sr25519 tile and
+        # sha512 entries, partitioned alike; mesh placement is proven
+        # by the divisibility gate
         lambda full: [],
     )
     return model

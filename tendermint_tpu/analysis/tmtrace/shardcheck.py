@@ -10,14 +10,15 @@ first multi-chip run — so they are gates here:
   `PartitionSpec(...)` must be declared by some `Mesh(..., (<axes>,))`
   in the package. An undeclared axis raises at dispatch time on the
   first sharded call — i.e. on the chips. Axis names are resolved
-  through module-level string constants (`SIG_AXIS = "sig"`), the
-  import aliases `P`/`PartitionSpec`, and constant tuples.
+  through module-level string constants (`SIG_AXIS = "sig"`, also
+  where another module of the package from-imports it), the import
+  aliases `P`/`PartitionSpec`, and constant tuples.
 
 - **trace-bucket-indivisible** (live, run by tracegate): for every
   virtual mesh size 1..8, the *real* sharded verifier classes are
   instantiated against a duck-typed mesh and every bucket they would
   dispatch must divide by the mesh size — the property
-  `_MeshSharded.__init__`/`_bucket` exists to guarantee, checked
+  `BucketedVerifier.__init__`/`_bucket` (ops/verifier.py) guarantees, checked
   against the production rounding code rather than a re-derived
   formula, so a refactor that drops the round-up turns the gate red.
 
@@ -89,9 +90,17 @@ def mesh_axis_violations(pkg: Package) -> List[Violation]:
     """Every PartitionSpec axis must exist in a declared Mesh."""
     declared: Set[str] = set()
     uses: List[Tuple[str, int, str]] = []  # (path, lineno, axis)
+    own = {p: _module_str_consts(m.tree) for p, m in pkg.modules.items()}
     for path in sorted(pkg.modules):
         mod = pkg.modules[path]
-        consts = _module_str_consts(mod.tree)
+        # the module's own constants, and those it from-imports from
+        # another module of the package (`from ..ops.verifier import
+        # SIG_AXIS`)
+        consts = dict(own[path])
+        for local, (tgt, _ext, orig) in mod.from_imports.items():
+            src = pkg.module_for_dotted(tgt) if tgt is not None else None
+            if src is not None and orig in own[src.path]:
+                consts[local] = own[src.path][orig]
         pspec_locals = _pspec_names(mod)
         for node in ast.walk(mod.tree):
             if not isinstance(node, ast.Call):
@@ -233,7 +242,7 @@ def divisibility_violations(
 ) -> List[Violation]:
     """Instantiate each sharded verifier against duck meshes of every
     virtual width and prove every bucket it would dispatch divides by
-    the mesh — exercising the REAL `_MeshSharded` rounding code, not a
+    the mesh — exercising the REAL ops/verifier.py rounding code, not a
     re-derivation of it. Needs jax importable (tracegate runs it)."""
     import numpy as np
 

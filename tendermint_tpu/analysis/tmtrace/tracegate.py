@@ -99,29 +99,20 @@ def run_cases(
 
 def jit_cache_stats() -> dict:
     """Sizes of the process's long-lived compiled-program caches: the
-    bucketed verifiers' per-instance dicts and the module-level jitted
-    wrappers (where this jax exposes `_cache_size`). Read-only — never
-    constructs a verifier that doesn't already exist."""
+    modules' shared jitted programs (where this jax exposes
+    `_cache_size`). Read-only: constructs no verifier."""
     out: dict = {}
     try:
         from ...ops import ed25519_kernel as K
-
-        if K._DEFAULT is not None:
-            out["ed25519_verifier_compiled"] = len(K._DEFAULT._compiled)
-        for name in ("_JIT_VERIFY", "_JIT_SHA512"):
-            fn = getattr(K, name, None)
-            if fn is not None and hasattr(fn, "_cache_size"):
-                out[f"ed25519{name}_cache"] = fn._cache_size()
-    except Exception:
-        pass
-    try:
         from ...ops import sr25519_kernel as SR
 
-        if SR._DEFAULT is not None:
-            out["sr25519_verifier_compiled"] = len(SR._DEFAULT._compiled)
-        fn = SR._JIT_VERIFY_SR
-        if fn is not None and hasattr(fn, "_cache_size"):
-            out["sr25519_jit_cache"] = fn._cache_size()
+        for name, fn in (
+            ("ed25519_tile_cache", K.Ed25519Verifier._TILE),
+            ("ed25519_sha512_cache", K._SHA512),
+            ("sr25519_tile_cache", SR.Sr25519Verifier._TILE),
+        ):
+            if hasattr(fn, "_cache_size"):
+                out[name] = fn._cache_size()
     except Exception:
         pass
     try:

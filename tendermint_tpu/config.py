@@ -38,13 +38,23 @@ MODE_FULL = "full"
 MODE_SEED = "seed"
 
 # Canonical device-batch bucket sizes: the single source both curves'
-# verifiers follow (ops.ed25519_kernel re-exports this as
+# verifiers follow (ops.verifier re-exports this as
 # DEFAULT_BUCKET_SIZES; config.py owns it because it must stay
 # importable without jax).
 # 12288 exists for the 10k-validator commit config (BASELINE 5): padding
 # 10k sigs to 16384 wastes 39% of the device program; 12288 = 96 * 128
-# stays Pallas-tile aligned and cuts that to 18%.
+# stays a multiple of the 128-lane vector tile and cuts that to 18%.
 DEFAULT_BUCKET_SIZES = (8, 32, 128, 512, 2048, 8192, 12288, 16384)
+
+
+def bucket_for(n: int, sizes) -> int:
+    """Smallest configured bucket >= n (`sizes` ascending), or n itself
+    when oversized. Beside the table for the same reason: the seam's
+    telemetry asks it without importing jax."""
+    for b in sizes:
+        if n <= b:
+            return b
+    return n
 
 
 @dataclass

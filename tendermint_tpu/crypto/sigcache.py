@@ -55,13 +55,13 @@ keys <= 2 generations x 20k triples; sign-bytes dominate at ~120 bytes
 each (pubkeys and signatures are references into live commit/validator
 objects), so the full cache tops out around 10 MB.
 
-`TM_TPU_NO_SIGCACHE=1` disables the cache at runtime (lookups miss,
-inserts are dropped) with no behavior difference except speed — the A/B
-switch idiom of TM_TPU_NO_PKCACHE / TM_TPU_NO_NATIVE. Note the
-consensus verify-ahead batch (consensus/state.py _preverify_votes) is
-BUILT ON this cache — its results are recorded here — so the gate also
-returns gossiped votes to sequential per-vote verification, not just
-commits to cold batches.
+A `disabled()` scope turns the cache off (lookups miss, inserts are
+dropped) with no behavior difference except speed: the tests' and the
+bench's A/B arm, and the only way off. Note the consensus verify-ahead
+batch (consensus/state.py _preverify_votes) is BUILT ON this cache —
+its results are recorded here — so the scope also returns gossiped
+votes to sequential per-vote verification, not just commits to cold
+batches.
 
 Instruments (process-global on DEFAULT_REGISTRY, like the tpu_* family —
 one cache per process): tendermint_tpu_sigcache_hits_total /
@@ -71,7 +71,6 @@ sigcache_misses_total / sigcache_evictions_total.
 from __future__ import annotations
 
 import contextlib
-import os
 import threading
 
 from ..libs import metrics as M
@@ -130,15 +129,15 @@ _capacity = DEFAULT_CAPACITY
 _gen0: set = set()  # young generation: inserts and promotions land here
 _gen1: set = set()  # old generation: dropped wholesale on rotation
 _lock = threading.Lock()  # guards rotation only; set ops are GIL-atomic
-_force_off = False  # tests/bench override, same effect as the env gate
+_force_off = False  # inside a disabled() scope (tests, bench cold rows)
 _force_commit_off = False  # bench A/B arm: triple probes only
 
 
 def enabled() -> bool:
-    """False under TM_TPU_NO_SIGCACHE=1 (or a disabled() scope): every
-    lookup misses and every insert is dropped — behavior identical to
-    the cache never existing, minus the speed."""
-    return not (_force_off or os.environ.get("TM_TPU_NO_SIGCACHE"))
+    """False inside a disabled() scope, the only way off: every lookup
+    misses and every insert is dropped — behavior identical to the
+    cache never existing, minus the speed."""
+    return not _force_off
 
 
 @contextlib.contextmanager
@@ -254,13 +253,10 @@ def _rotate() -> None:
 
 def commit_memo_enabled() -> bool:
     """The commit-level verification memo rides the same generations
-    but has its own off-switch (TM_TPU_NO_COMMIT_MEMO=1, or a
-    commit_memo_disabled() scope) on top of the cache-wide gate — the
-    bench's interleaved A/B arm measures the bulk triple-probe path
-    with only this half disabled."""
-    return enabled() and not (
-        _force_commit_off or os.environ.get("TM_TPU_NO_COMMIT_MEMO")
-    )
+    but has its own off-switch (a commit_memo_disabled() scope) on top
+    of the cache-wide gate — the bench's interleaved A/B arm measures
+    the bulk triple-probe path with only this half disabled."""
+    return enabled() and not _force_commit_off
 
 
 @contextlib.contextmanager

@@ -29,6 +29,7 @@ import threading
 import time
 from typing import List, Optional, Tuple
 
+from ..config import DEFAULT_BUCKET_SIZES, bucket_for
 from ..libs import heap
 from ..libs import metrics as M
 from ..libs import trace
@@ -93,15 +94,6 @@ _m_warm_misses = M.new_counter(
     "warm_bucket_misses_total",
     "First dispatches into a bucket (likely paying an XLA compile).",
 )
-# counted by ops/ed25519_kernel.run_with_pallas_fallback: with
-# TM_TPU_PALLAS set, a Pallas program the compiler or the device
-# refused is swapped for the XLA one — same verdicts, different program
-_m_pallas_fallbacks = M.new_counter(
-    "tpu",
-    "pallas_fallbacks_total",
-    "Opt-in Pallas programs that failed and were swapped for XLA.",
-)
-
 _m_mesh_devices = M.new_gauge(
     "tpu",
     "mesh_devices",
@@ -146,18 +138,16 @@ _WARM_BUCKETS: set = set()
 
 
 def _bucket_of(verifier, n: int) -> int:
-    """The padded bucket `n` signatures land in, from the backing
-    verifier's configured sizes (without importing the jax-backed ops
-    module: telemetry must not initialize a backend)."""
-    sizes = getattr(verifier, "bucket_sizes", None)
-    if not sizes:
-        from ..config import DEFAULT_BUCKET_SIZES
-
-        sizes = DEFAULT_BUCKET_SIZES
-    for b in sorted(sizes):
-        if b >= n:
-            return b
-    return n
+    """The padded bucket `n` signatures land in: the backing verifier's
+    own answer (ops/verifier.py `_bucket`, mesh rounding included). An
+    injected verifier that promises dispatch()/gather() alone is asked
+    for its sizes, read through the same rule (config.bucket_for:
+    telemetry must not import the jax-backed ops modules)."""
+    rule = getattr(verifier, "_bucket", None)
+    if rule is not None:
+        return rule(n)
+    sizes = getattr(verifier, "bucket_sizes", None) or DEFAULT_BUCKET_SIZES
+    return bucket_for(n, sorted(sizes))
 
 
 def _mesh_devices(verifier) -> int:
@@ -799,15 +789,14 @@ def stats() -> dict:
         "faults": int(_m_device_faults.value()),
         "pad_waste": int(_m_pad_waste.value()),
         "warm_misses": int(_m_warm_misses.value()),
-        "pallas_fallbacks": int(_m_pallas_fallbacks.value()),
+        # constant: no program falls back since the device layer runs
+        # one program a key class; chipbench/run.py and chip_smoke.py
+        # still read the key, and chipbench/ changes only in a
+        # benchmark PR (PERF.md §7)
+        "pallas_fallbacks": 0,
         "mesh_devices": int(_m_mesh_devices.value()),
         **heap.stats(),
     }
-
-
-def note_pallas_fallback() -> None:
-    """One opt-in Pallas program fell back to the XLA program."""
-    _m_pallas_fallbacks.inc()
 
 
 def _factory(size_hint: int) -> Optional[BatchVerifier]:

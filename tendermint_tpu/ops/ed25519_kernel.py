@@ -42,9 +42,8 @@ handful of programs once and reuses them for every Commit size.
 
 from __future__ import annotations
 
-import hashlib
 import threading
-from typing import Optional, Sequence
+from typing import Optional
 
 import numpy as np
 
@@ -53,9 +52,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..crypto import ed25519_math as em
-from ..libs import trace
 from . import edwards as E
 from . import field25519 as F
+from .sha512_kernel import sha512_fixed
+from .verifier import (
+    DEFAULT_BUCKET_SIZES,
+    ROWS,
+    BucketedVerifier,
+    _join_cols,
+    bucket_for,
+)
 
 __all__ = [
     "Ed25519Verifier",
@@ -64,18 +70,6 @@ __all__ = [
     "DEFAULT_BUCKET_SIZES",
     "bucket_for",
 ]
-
-# shared by the ed25519 and sr25519 verifiers (ops/sr25519_kernel.py)
-# and the [tpu] config section: tune once, everything follows
-from ..config import DEFAULT_BUCKET_SIZES  # noqa: E402
-
-
-def bucket_for(n: int, sizes: Sequence[int]) -> int:
-    """Smallest configured bucket >= n, or n itself when oversized."""
-    for b in sizes:
-        if n <= b:
-            return b
-    return n
 
 _TB0 = None  # lazy (9, 4, NLIMBS, 1) fixed-base niels table (host numpy;
 # converted per use so jit tracing never captures a cached tracer)
@@ -110,8 +104,11 @@ def _build_neg_a_table(A: jnp.ndarray) -> jnp.ndarray:
 
 def _onehot_select(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """table (K, 4, L, {N|1}), idx (N,) -> (4, L, N) via K-way masked
-    accumulate (no per-lane gather). broadcasted_iota (not arange):
-    Mosaic rejects rank-1 iota."""
+    accumulate (no per-lane gather). broadcasted_iota and not arange
+    is history: the form a fused TPU kernel's compiler (Mosaic, which
+    rejects rank-1 iota) could lower. It stays because another form
+    would be another compiled program (the kernel is in git's
+    history, at 8b8c9eb under ops/)."""
     k = table.shape[0]
     js = lax.broadcasted_iota(idx.dtype, (k, idx.shape[0]), 0)
     mask = (idx[None, :] == js).astype(table.dtype)  # (K, N)
@@ -131,10 +128,10 @@ def _recode_signed(d: jnp.ndarray) -> jnp.ndarray:
     result is irrelevant (same contract as the rest of the math on
     malformed inputs).
 
-    The generate/propagate lattice is kept in int32 0/1, not bool:
-    Mosaic cannot concatenate/shift i1 vregs (it bitcasts them to i32,
-    which fails with 'Invalid vector register cast' — found via local
-    AOT compile against a v5e topology)."""
+    The generate/propagate lattice is int32 0/1 and not bool, for the
+    same historical reason as _onehot_select's iota: Mosaic could not
+    concatenate or shift i1 vregs ('Invalid vector register cast',
+    found compiling for a described v5e)."""
     g = (d >= 8).astype(d.dtype)
     p = (d == 7).astype(d.dtype)
     shift = 1
@@ -180,11 +177,7 @@ def _select_signed(
 
 
 def dual_mult_sb_minus_ka(
-    A: jnp.ndarray,
-    dS: jnp.ndarray,
-    dk: jnp.ndarray,
-    mosaic: bool = False,
-    mxu: Optional[bool] = None,
+    A: jnp.ndarray, dS: jnp.ndarray, dk: jnp.ndarray
 ) -> jnp.ndarray:
     """[S]B - [k]A as a T-less (3, NLIMBS, N) projective stack.
 
@@ -196,20 +189,9 @@ def dual_mult_sb_minus_ka(
     cached table of -A built on device and a constant niels table of B.
     Shared by the ed25519 program (cofactored compare follows) and the
     sr25519/ristretto program (ristretto equality follows,
-    ops/sr25519_kernel.py).
-
-    Two window-walk forms, same math:
-    - mosaic=False (XLA default): lax.scan over pre-flipped digit rows.
-    - mosaic=True (the Pallas tile): lax.fori_loop; the window's digit
-      row is picked by a one-hot masked sum because Mosaic lowers
-      neither scan's xs dynamic_slice nor jnp.flip's rev. 64 extra
-      MACs/window are noise next to the point ops.
-
-    `mxu` overrides the fixed-base select engine (default: MXU einsum
-    on the XLA path, VPU one-hot in the mosaic/Pallas path) — the
-    override exists for device A/B attribution (scripts/probe_r3.py)."""
-    if mxu is None:
-        mxu = not mosaic
+    ops/sr25519_kernel.py). The window walk is one lax.scan over
+    pre-flipped digit rows; the fixed-base select rides the MXU
+    (_select_signed)."""
     with jax.named_scope("neg_a_table"):
         TA = _build_neg_a_table(A)  # (9, 4, L, N)
 
@@ -232,25 +214,13 @@ def dual_mult_sb_minus_ka(
         acc = E.point_double(acc)  # T feeds the addition below
         acc = E.point_add_cached(acc, _select_signed(TA, dk_w))
         acc = E.point_add_cached(
-            acc, _select_signed(tb0, ds_w, mxu=mxu), with_t=False
+            acc, _select_signed(tb0, ds_w, mxu=True), with_t=False
         )
         return acc
 
     # the 64-window walk: the profiler's trace names its operations by
     # this scope
     with jax.named_scope("dual_mult"):
-        if mosaic:
-            rows = lax.broadcasted_iota(dS.dtype, dS.shape, 0)  # (64, N)
-
-            def body(w, acc):
-                sel = (rows == 63 - w).astype(dS.dtype)  # MSB-first walk
-                return step(
-                    acc,
-                    jnp.sum(dS * sel, axis=0),
-                    jnp.sum(dk * sel, axis=0),
-                )
-
-            return lax.fori_loop(0, 64, body, acc0)
 
         def scan_body(acc, xs):
             ds_w, dk_w = xs
@@ -262,24 +232,16 @@ def dual_mult_sb_minus_ka(
         return acc
 
 
-def _scalar_mult_check(
-    yA, signA, yR, signR, dS, dk, mosaic=False, dual_fn=None
-) -> jnp.ndarray:
+def _scalar_mult_check(yA, signA, yR, signR, dS, dk) -> jnp.ndarray:
     """Core device program. Batch axis minor.
 
     yA/yR: (L, N) field elements; signA/signR: (N,) int32;
     dS/dk: (64, N) int32 radix-16 digits, little-endian.
-    Returns ok: (N,) bool. `dual_fn` overrides the dual scalar-mult
-    (the segmented Pallas kernel plugs in here; everything around it —
-    decompression, cofactor clearing, the projective compare — stays
-    XLA, which fuses those fine)."""
+    Returns ok: (N,) bool."""
     with jax.named_scope("decode_points"):
         A, okA = E.decompress(yA, signA)
         R, okR = E.decompress(yR, signR)
-    if dual_fn is None:
-        acc = dual_mult_sb_minus_ka(A, dS, dk, mosaic=mosaic)
-    else:
-        acc = dual_fn(A, dS, dk)
+    acc = dual_mult_sb_minus_ka(A, dS, dk)
     with jax.named_scope("final_check"):
         # ZIP-215 cofactored equation, rearranged so nothing needs T:
         # [8]([S]B - [k]A) == [8]R  <=>  [8]([S]B - [k]A - R) == identity.
@@ -320,8 +282,8 @@ _C8 = _bytes_const(_DELTA16_INT, 17)
 _L8 = _bytes_const(_L_INT, 32)
 
 # (32, 1) AND-mask clearing the sign bit of byte row 31 — the
-# mask-select form of `.at[31].set(b & 0x7F)`; jnp scatter updates
-# have no Pallas TPU lowering (Mosaic: "Unimplemented ... scatter")
+# mask-select form of `.at[31].set(b & 0x7F)` (history, as
+# _onehot_select: Mosaic lowered no scatter update)
 _TOPCLEAR = np.full((32, 1), 0xFF, dtype=np.int32)
 _TOPCLEAR[31, 0] = 0x7F
 
@@ -389,8 +351,8 @@ def _mod_l_dev(d: jnp.ndarray) -> jnp.ndarray:
     lo = jnp.pad(x[:32], ((0, 1), (0, 0)))
     x = _norm8(lo - _mul_c8(x[32:], 33), 34)
     l8_33 = jnp.asarray(np.pad(_L8, ((0, 1), (0, 0))))
-    # x[32], not x[-1]: jnp lowers negative indices via dynamic_slice,
-    # which Mosaic (Pallas TPU) cannot lower
+    # x[32], not x[-1]: jnp lowers negative indices via dynamic_slice
+    # (history, as _onehot_select: Mosaic could not lower it)
     neg = (x[32] < 0).astype(jnp.int32)
     x = x + neg[None, :] * l8_33
     x = _norm8(x, 34)
@@ -408,10 +370,10 @@ def _lt_const_dev(rows: jnp.ndarray, const8: np.ndarray) -> jnp.ndarray:
     Most-significant-byte-first scan; shared by the S < L check here
     and the ristretto s < p canonicity check (ops/sr25519_kernel.py).
 
-    The decided/lt lattice is int32 0/1, not bool: a scalar-True
-    jnp.where operand materializes as an i8 constant that Mosaic must
-    trunci to i1 — 'Unsupported target bitwidth for truncation'
-    (found via scripts/aot_bisect.py against the local v5e topology)."""
+    The decided/lt lattice is int32 0/1 and not bool (history, as
+    _onehot_select: a scalar-True jnp.where operand became an i8
+    constant that Mosaic had to truncate to i1, 'Unsupported target
+    bitwidth for truncation')."""
     cb = np.asarray(const8)[:, 0]
     lt = jnp.zeros(rows.shape[1], dtype=jnp.int32)
     decided = jnp.zeros(rows.shape[1], dtype=jnp.int32)
@@ -436,17 +398,11 @@ def _nibbles_dev(b: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([lo, hi], axis=1).reshape(64, b.shape[1])
 
 
-def _verify_tile(pk_b, sig_b, dig_b, mosaic: bool = False, dual_fn=None) -> jnp.ndarray:
+def _verify_tile(pk_b, sig_b, dig_b) -> jnp.ndarray:
     """The full device program: byte rows in, validity bitmap out.
 
     pk_b (32, N), sig_b (64, N) uint8/int32 byte rows; dig_b (64, N)
-    SHA-512(R||A||M) byte rows. Returns (N,) bool.
-
-    Pure jnp on values — the same body runs as a jitted XLA program
-    (CPU and fallback) and, with mosaic=True (Mosaic-lowerable window
-    walk, see dual_mult_sb_minus_ka), as the per-tile body of the
-    fused Pallas kernel (ops/ed25519_pallas.py). `dual_fn` swaps in the
-    segmented Pallas dual-mult while the rest stays XLA."""
+    SHA-512(R||A||M) byte rows. Returns (N,) bool."""
     pk = pk_b.astype(jnp.int32)
     sig = sig_b.astype(jnp.int32)
     dig = dig_b.astype(jnp.int32)
@@ -466,246 +422,27 @@ def _verify_tile(pk_b, sig_b, dig_b, mosaic: bool = False, dual_fn=None) -> jnp.
         s_ok = _s_lt_l_dev(s)
         dS = _nibbles_dev(s)
         dk = _nibbles_dev(_mod_l_dev(dig))
-    ok = _scalar_mult_check(
-        yA, signA, yR, signR, dS, dk, mosaic=mosaic, dual_fn=dual_fn
-    )
-    return ok & s_ok
+    return _scalar_mult_check(yA, signA, yR, signR, dS, dk) & s_ok
 
 
-# -- host packing (only SHA-512 and byte joins remain on host) --
+# -- host side: only byte joins remain; the batch, bucket and launch
+# logic is the shared BucketedVerifier (ops/verifier.py) --
+
+_SHA512 = jax.jit(sha512_fixed)
 
 
-def _join_cols(items: Sequence[bytes], width: int, pad: int) -> np.ndarray:
-    """Join n equal-length byte strings into a (width, n+pad) uint8
-    array, batch-minor, zero-padded on the right."""
-    arr = np.frombuffer(b"".join(items), dtype=np.uint8).reshape(-1, width)
-    out = arr.T
-    if pad:
-        return np.pad(out, ((0, 0), (0, pad)))
-    return np.ascontiguousarray(out)
+class Ed25519Verifier(BucketedVerifier):
+    """Bucketed ed25519 batch verifier: the shared body with
+    `_verify_tile` as its program and SHA512(R || A || M), hashed on
+    the device, as the third operand."""
 
+    _TILE = staticmethod(jax.jit(_verify_tile))
 
-def pallas_bucket(b: int) -> int:
-    """Round a bucket up to full Pallas tiles. Rounding small buckets
-    up costs nothing: the VPU lane tile is 128 wide, so an 8-lane XLA
-    program wastes 94% of every vector register anyway."""
-    from .ed25519_pallas import TILE
-
-    return max(TILE, -(-b // TILE) * TILE)
-
-
-def run_with_pallas_fallback(
-    prog, args, *, is_pallas, bucket, proven, compiled, xla_factory, label
-):
-    """Shared dispatch policy for programs that may contain a Pallas
-    kernel (the ed25519 tile/hybrid and the sr25519 hybrid).
-
-    Runs `prog(*args)`. JAX dispatch is asynchronous, so a Mosaic
-    *runtime* failure would surface later at gather()'s np.asarray —
-    past any fallback; block on the first call of each Pallas bucket so
-    device-side kernel failures downgrade HERE. On failure (lowering or
-    first-call runtime), log, permanently swap the bucket's entry in
-    `compiled` to `xla_factory()` (same math, same semantics), count
-    it (tpu_pallas_fallbacks_total — a run that asked for Pallas and
-    got XLA must be able to tell), and re-run. A non-Pallas program
-    failing is a real error and re-raises."""
-    try:
-        ok = prog(*args)
-        if is_pallas and bucket not in proven:
-            jax.block_until_ready(ok)
-            proven.add(bucket)
-        return ok
-    except Exception as e:
-        if not is_pallas:
-            raise
-        import logging
-
-        logging.getLogger("tendermint_tpu.ops").warning(
-            "pallas %s kernel failed for bucket %d; "
-            "falling back to the XLA program: %s",
-            label,
-            bucket,
-            e,
-        )
-        from ..crypto.tpu_verifier import note_pallas_fallback
-
-        note_pallas_fallback()
-        fn = xla_factory()
-        compiled[bucket] = fn
-        return fn(*args)
-
-
-class Ed25519Verifier:
-    """Compiled, bucketed batch verifier.
-
-    One instance caches jitted programs per bucket size. Thread-compatible
-    for the asyncio runtime (verification calls are synchronous device
-    invocations)."""
-
-    def __init__(self, bucket_sizes: Optional[Sequence[int]] = None) -> None:
-        self.bucket_sizes = sorted(bucket_sizes or DEFAULT_BUCKET_SIZES)
-        self._compiled = {}
-        # buckets whose Pallas program has completed on device at least
-        # once (first calls block, see dispatch())
-        self._pallas_proven = set()
-
-    @staticmethod
-    def _is_pallas(prog) -> bool:
-        import sys
-
-        # only consult the pallas module if something already imported
-        # it (i.e. a pallas program could possibly be in `prog`) — the
-        # default XLA path must never pay for, or fail on, this import
-        mod = sys.modules.get(__package__ + ".ed25519_pallas")
-        return mod is not None and (
-            prog is mod.verify_pallas or prog is mod.verify_hybrid
-        )
-
-    def _bucket(self, n: int) -> int:
-        b = bucket_for(n, self.bucket_sizes)
-        if self._pallas_wanted():
-            b = pallas_bucket(b)
-        return b
-
-    @staticmethod
-    def _pallas_wanted() -> Optional[str]:
-        """Fused Pallas kernel gate. Opt-in: the kernels are
-        differential-verified in interpret mode (tests/test_ops_pallas.py)
-        and compile for a v5e ahead of time (scripts/aot_check.py), but
-        none has been timed against the XLA program on a chip. The XLA
-        program remains the default until one has.
-
-        TM_TPU_PALLAS=1|hybrid -> the segmented kernel (Pallas
-        dual-mult inside an XLA program — ~6x smaller Mosaic module);
-        TM_TPU_PALLAS=full -> the monolithic whole-tile kernel."""
-        import os
-
-        if os.environ.get("TM_TPU_NO_PALLAS"):
-            return None
-        if jax.default_backend() != "tpu":
-            return None
-        v = os.environ.get("TM_TPU_PALLAS")
-        if v in ("1", "hybrid"):
-            return "hybrid"
-        if v == "full":
-            return "full"
-        return None
-
-    def _program(self, size: int):
-        """The compiled program for a bucket. One shape-polymorphic
-        jitted function serves every bucket (jit caches per shape
-        internally); the per-size dict exists for overrides — the
-        Pallas fallback swap in dispatch() and ShardedEd25519Verifier's
-        per-bucket sharded programs."""
-        fn = self._compiled.get(size)
-        if fn is None:
-            kind = self._pallas_wanted()
-            if kind == "hybrid":
-                from .ed25519_pallas import verify_hybrid
-
-                fn = verify_hybrid
-            elif kind == "full":
-                from .ed25519_pallas import verify_pallas
-
-                fn = verify_pallas
-            else:
-                fn = _jit_verify_tile()
-            self._compiled[size] = fn
-        return fn
-
-    def verify(
-        self,
-        pubkeys: Sequence[bytes],
-        msgs: Sequence[bytes],
-        sigs: Sequence[bytes],
-    ) -> np.ndarray:
-        """Returns a bool bitmap, one per triple. Malformed inputs are
-        reported invalid rather than raising (the BatchVerifier.add layer
-        enforces sizes upstream)."""
-        return self.gather(self.dispatch(pubkeys, msgs, sigs))
-
-    def dispatch(
-        self,
-        pubkeys: Sequence[bytes],
-        msgs: Sequence[bytes],
-        sigs: Sequence[bytes],
-    ):
-        """Asynchronously launch verification; returns an opaque handle
-        for gather(). Device dispatch is non-blocking in JAX, so several
-        batches can be in flight at once — host packing of the next
-        batch overlaps device work on the last (the verify-ahead
-        pattern from SURVEY §7: stream commits through the device
-        without stalling the consensus loop)."""
-        n = len(pubkeys)
-        if n == 0:
-            return (None, 0, np.zeros(0, dtype=bool))
-        bucket = self._bucket(n)
-        pad = bucket - n
-        with trace.span("pack_rows", n=n, bucket=bucket):
-            size_ok = np.array(
-                [
-                    len(pk) == 32 and len(sig) == 64
-                    for pk, sig in zip(pubkeys, sigs)
-                ],
-                dtype=bool,
-            )
-            if not size_ok.all():
-                pubkeys = [
-                    pk if ok else b"\x00" * 32
-                    for pk, ok in zip(pubkeys, size_ok)
-                ]
-                sigs = [
-                    sig if ok else b"\x00" * 64
-                    for sig, ok in zip(sigs, size_ok)
-                ]
-            # host work is byte joins only; hashing (SHA-512 of
-            # R||A||M), limb unpacking, mod-L, S-canonicality, digits,
-            # and the curve math all run on device
-            pk_b = _join_cols(pubkeys, 32, pad)
-            sig_b = _join_cols(sigs, 64, pad)
-            pre = self._preimage_rows(pubkeys, msgs, sigs, bucket)
-        dig_b = self._digest_rows(pubkeys, msgs, sigs, bucket, pre)
-        prog = self._program(bucket)
-        with trace.span(
-            "device_launch", program=_program_name(prog), bucket=bucket
-        ):
-            ok = run_with_pallas_fallback(
-                prog,
-                (
-                    self._place(pk_b),
-                    self._place(sig_b),
-                    self._place(dig_b),
-                ),
-                is_pallas=self._is_pallas(prog),
-                bucket=bucket,
-                proven=self._pallas_proven,
-                compiled=self._compiled,
-                xla_factory=_jit_verify_tile,
-                label="ed25519",
-            )
-        return (ok, n, size_ok)
-
-    def _place(self, rows):
-        """Byte rows (host or already on device) -> the device array
-        the programs take. The mesh verifiers override this to shard
-        the batch axis from the host (parallel/sharding.py)."""
-        return jnp.asarray(rows)
-
-    def _sha512_program(self):
-        """The jitted SHA-512 the digests run through (the mesh
-        verifiers partition it like the tile)."""
-        return _jit_sha512()
-
-    def _preimage_rows(self, pubkeys, msgs, sigs, bucket):
+    def _pack_operand(self, pubkeys, msgs, sigs, bucket):
         """(64 + len, bucket) rows of R || A || M when every message
         has one length — every sign-bytes in a Commit has the same
         shape — so that the digests stay on device, feeding the verify
-        program without a host round-trip; None otherwise (mixed
-        lengths, or TM_TPU_HOST_SHA512=1)."""
-        import os
-
-        if os.environ.get("TM_TPU_HOST_SHA512"):
-            return None
+        program without a host round-trip; None for mixed lengths."""
         if len(set(map(len, msgs))) != 1:
             return None
         return _join_cols(
@@ -717,41 +454,21 @@ class Ed25519Verifier:
             bucket - len(pubkeys),
         )
 
-    def _digest_rows(self, pubkeys, msgs, sigs, bucket, pre=None):
-        """(64, bucket) rows of SHA512(R || A || M).
-
-        Device-hashed per message-length group (ops/sha512_kernel.py
-        compiles one program per length); `pre` is the single-length
-        case's pre-image (_preimage_rows), joined here when the caller
-        has not. TM_TPU_HOST_SHA512=1 restores hashlib (bench
-        comparisons)."""
-        import os
-
-        n = len(pubkeys)
-        if pre is None:
-            pre = self._preimage_rows(pubkeys, msgs, sigs, bucket)
-        if pre is not None:
-            prog = self._sha512_program()
-            with trace.span(
-                "device_launch", program=_program_name(prog), bucket=bucket
-            ):
-                return prog(self._place(pre))
-        if os.environ.get("TM_TPU_HOST_SHA512"):
-            return _join_cols(
-                [
-                    hashlib.sha512(sig[:32] + pk + msg).digest()
-                    for pk, msg, sig in zip(pubkeys, msgs, sigs)
-                ],
-                64,
-                bucket - n,
-            )
+    def _third_operand(self, pubkeys, msgs, sigs, bucket, packed):
+        """(64, bucket) rows of SHA512(R || A || M), device-hashed
+        (ops/sha512_kernel.py compiles one program per length). `packed`
+        is the single-length case's pre-image: one launch whose digests
+        never leave the device. Mixed lengths take one launch a length
+        group and meet on the host."""
+        if packed is not None:
+            return self._launch(_SHA512, ROWS, bucket, packed)
         groups: dict = {}
         for i, m in enumerate(msgs):
             groups.setdefault(len(m), []).append(i)
         dig = np.zeros((64, bucket), dtype=np.uint8)
         for mlen, idxs in groups.items():
             g = len(idxs)
-            gb = bucket_for(g, self.bucket_sizes)
+            gb = self._bucket(g)
             pre = _join_cols(
                 [
                     sigs[i][:32] + pubkeys[i] + msgs[i]
@@ -760,52 +477,9 @@ class Ed25519Verifier:
                 64 + mlen,
                 gb - g,
             )
-            prog = self._sha512_program()
-            with trace.span(
-                "device_launch", program=_program_name(prog), bucket=gb
-            ):
-                launched = prog(self._place(pre))
-            out = np.asarray(launched)
+            out = np.asarray(self._launch(_SHA512, ROWS, gb, pre))
             dig[:, idxs] = out[:, :g]
         return dig
-
-    def gather(self, handle) -> np.ndarray:
-        """Block on a dispatch() handle and return the bitmap."""
-        ok, n, size_ok = handle
-        if ok is None:
-            return size_ok
-        return np.asarray(ok)[:n] & size_ok
-
-
-def _program_name(prog) -> str:
-    """The traced function's own name (`_verify_tile`, `sha512_fixed`,
-    a Pallas variant's): what the profiler calls the program's
-    executions, less its `jit_` prefix."""
-    return getattr(prog, "__name__", type(prog).__name__)
-
-
-_JIT_VERIFY = None
-_JIT_SHA512 = None
-
-
-def _jit_sha512():
-    """Shared jitted sha512_fixed (one compile per message length +
-    bucket shape inside jax's cache)."""
-    global _JIT_SHA512
-    if _JIT_SHA512 is None:
-        from .sha512_kernel import sha512_fixed
-
-        _JIT_SHA512 = jax.jit(sha512_fixed)
-    return _JIT_SHA512
-
-
-def _jit_verify_tile():
-    """Shared jitted XLA program (shape-polymorphic; compiles once per
-    bucket shape inside jax's own cache)."""
-    global _JIT_VERIFY
-    if _JIT_VERIFY is None:
-        _JIT_VERIFY = jax.jit(_verify_tile)
-    return _JIT_VERIFY
 
 
 _DEFAULT: Optional[Ed25519Verifier] = None

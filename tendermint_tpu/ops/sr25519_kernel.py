@@ -31,7 +31,7 @@ vectors) through crypto/sr25519.py's verify_signature.
 from __future__ import annotations
 
 import threading
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -42,18 +42,15 @@ from ..crypto import ed25519_math as em
 from ..libs import trace
 from . import field25519 as F
 from .ed25519_kernel import (
-    DEFAULT_BUCKET_SIZES,
     _TOPCLEAR,
     _bytes_const,
     _fe_from_bytes_dev,
-    _join_cols,
     _lt_const_dev,
     _nibbles_dev,
-    _program_name,
     _s_lt_l_dev,
-    bucket_for,
     dual_mult_sb_minus_ka,
 )
+from .verifier import BucketedVerifier, _join_cols
 
 __all__ = ["Sr25519Verifier", "batch_verify_host"]
 
@@ -149,15 +146,12 @@ def _ristretto_eq_dev(p3: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
     return eq1 | eq2
 
 
-def _verify_tile_sr(pk_b, sig_b, k_b, dual_fn=None) -> jnp.ndarray:
+def _verify_tile_sr(pk_b, sig_b, k_b) -> jnp.ndarray:
     """The full sr25519 device program: byte rows in, bitmap out.
 
     pk_b (32, N) ristretto pubkey bytes; sig_b (64, N) R || s with the
     schnorrkel v1 marker in bit 511; k_b (32, N) LE bytes of the
-    merlin challenge already reduced mod L on host. Returns (N,) bool.
-    `dual_fn` swaps in the segmented Pallas dual-mult (the same kernel
-    the ed25519 hybrid uses — ops/ed25519_pallas.dual_mult_pallas);
-    ristretto decode and the equality stay XLA."""
+    merlin challenge already reduced mod L on host. Returns (N,) bool."""
     pk = pk_b.astype(jnp.int32)
     sig = sig_b.astype(jnp.int32)
     kb = k_b.astype(jnp.int32)
@@ -171,134 +165,28 @@ def _verify_tile_sr(pk_b, sig_b, k_b, dual_fn=None) -> jnp.ndarray:
     with jax.named_scope("ristretto_decode"):
         A, okA = ristretto_decode_dev(pk)
         R, okR = ristretto_decode_dev(sig[:32])
-    if dual_fn is None:
-        acc = dual_mult_sb_minus_ka(A, dS, dk)  # [s]B - [k]A, T-less
-    else:
-        acc = dual_fn(A, dS, dk)
+    acc = dual_mult_sb_minus_ka(A, dS, dk)  # [s]B - [k]A, T-less
     with jax.named_scope("final_check"):
         return _ristretto_eq_dev(acc, R) & okA & okR & s_ok & marker_ok
 
 
-_JIT_VERIFY_SR = None
-_JIT_VERIFY_SR_HYBRID = None
+class Sr25519Verifier(BucketedVerifier):
+    """Bucketed sr25519 batch verifier: the shared body with
+    `_verify_tile_sr` as its program and the merlin challenges, from
+    the host, as the third operand."""
 
+    _TILE = staticmethod(jax.jit(_verify_tile_sr))
 
-def _jit_verify_tile_sr():
-    global _JIT_VERIFY_SR
-    if _JIT_VERIFY_SR is None:
-        _JIT_VERIFY_SR = jax.jit(_verify_tile_sr)
-    return _JIT_VERIFY_SR
-
-
-def _jit_verify_tile_sr_hybrid():
-    """sr25519 program with the Pallas dual-mult segment (same gating
-    as the ed25519 hybrid: TM_TPU_PALLAS=1, see
-    Ed25519Verifier._pallas_wanted; falls back per-bucket in dispatch
-    if Mosaic rejects the kernel)."""
-    global _JIT_VERIFY_SR_HYBRID
-    if _JIT_VERIFY_SR_HYBRID is None:
-        import functools
-
-        from .ed25519_pallas import dual_mult_pallas
-
-        _JIT_VERIFY_SR_HYBRID = jax.jit(
-            functools.partial(_verify_tile_sr, dual_fn=dual_mult_pallas)
-        )
-    return _JIT_VERIFY_SR_HYBRID
-
-
-class Sr25519Verifier:
-    """Compiled, bucketed sr25519 batch verifier (device XLA program).
-
-    Mirrors ops.ed25519_kernel.Ed25519Verifier's dispatch()/gather()
-    shape: host work is merlin challenges + byte joins; decode, scalar
-    canonicality, and the curve math are one device program per bucket."""
-
-    def __init__(self, bucket_sizes: Optional[Sequence[int]] = None) -> None:
-        self.bucket_sizes = sorted(bucket_sizes or DEFAULT_BUCKET_SIZES)
-        self._compiled: dict = {}
-        # buckets whose hybrid (Pallas dual-mult) program has completed
-        # on device at least once — first calls block, see dispatch()
-        self._pallas_proven: set = set()
-
-    def _bucket(self, n: int) -> int:
-        from .ed25519_kernel import Ed25519Verifier, pallas_bucket
-
-        b = bucket_for(n, self.bucket_sizes)
-        if Ed25519Verifier._pallas_wanted():
-            b = pallas_bucket(b)
-        return b
-
-    def _program(self, size: int):
-        """The compiled program for a bucket — one shape-polymorphic
-        jitted function by default; the per-size dict exists for
-        overrides (ShardedSr25519Verifier's mesh-partitioned programs,
-        tendermint_tpu.parallel.sharding; the per-bucket Pallas
-        fallback in dispatch)."""
-        fn = self._compiled.get(size)
-        if fn is None:
-            from .ed25519_kernel import Ed25519Verifier
-
-            if Ed25519Verifier._pallas_wanted():
-                fn = _jit_verify_tile_sr_hybrid()
-            else:
-                fn = _jit_verify_tile_sr()
-            self._compiled[size] = fn
-        return fn
-
-    def _place(self, rows):
-        """Host byte rows -> the device array the program takes (see
-        Ed25519Verifier._place; the mesh verifier shards here)."""
-        return jnp.asarray(rows)
-
-    def verify(
-        self,
-        pubkeys: Sequence[bytes],
-        msgs: Sequence[bytes],
-        sigs: Sequence[bytes],
-    ) -> np.ndarray:
-        return self.gather(self.dispatch(pubkeys, msgs, sigs))
-
-    def dispatch(
-        self,
-        pubkeys: Sequence[bytes],
-        msgs: Sequence[bytes],
-        sigs: Sequence[bytes],
-    ):
-        """Asynchronously launch verification; returns a handle for
-        gather(). Malformed sizes are reported invalid per-index."""
+    def _third_operand(self, pubkeys, msgs, sigs, bucket, packed):
+        """(32, bucket) rows of the merlin Fiat-Shamir challenges,
+        vectorized per message-length group (crypto/sr25519.py
+        challenge_batch — one native keccakf_n permutation call per
+        transcript step)."""
         from ..crypto.sr25519 import challenge_batch
 
         n = len(pubkeys)
-        if n == 0:
-            return (None, 0, np.zeros(0, dtype=bool))
-        bucket = self._bucket(n)
-        pad = bucket - n
-        with trace.span("pack_rows", n=n, bucket=bucket):
-            size_ok = np.array(
-                [
-                    len(pk) == 32 and len(sig) == 64
-                    for pk, sig in zip(pubkeys, sigs)
-                ],
-                dtype=bool,
-            )
-            if not size_ok.all():
-                pubkeys = [
-                    pk if ok else b"\x00" * 32
-                    for pk, ok in zip(pubkeys, size_ok)
-                ]
-                sigs = [
-                    sig if ok else b"\x00" * 64
-                    for sig, ok in zip(sigs, size_ok)
-                ]
-            pk_b = _join_cols(pubkeys, 32, pad)
-            sig_b = _join_cols(sigs, 64, pad)
-        # host: the merlin Fiat-Shamir challenges, vectorized per
-        # message-length group (crypto/sr25519.py challenge_batch —
-        # one native keccakf_n permutation call per transcript step),
-        # and their 32-byte rows
         with trace.span("merlin_challenges", n=n):
-            k_b = _join_cols(
+            return _join_cols(
                 [
                     k.to_bytes(32, "little")
                     for k in challenge_batch(
@@ -306,38 +194,8 @@ class Sr25519Verifier:
                     )
                 ],
                 32,
-                pad,
+                bucket - n,
             )
-        prog = self._program(bucket)
-        from .ed25519_kernel import run_with_pallas_fallback
-
-        with trace.span(
-            "device_launch", program=_program_name(prog), bucket=bucket
-        ):
-            ok = run_with_pallas_fallback(
-                prog,
-                (
-                    self._place(pk_b),
-                    self._place(sig_b),
-                    self._place(k_b),
-                ),
-                is_pallas=(
-                    _JIT_VERIFY_SR_HYBRID is not None
-                    and prog is _JIT_VERIFY_SR_HYBRID
-                ),
-                bucket=bucket,
-                proven=self._pallas_proven,
-                compiled=self._compiled,
-                xla_factory=_jit_verify_tile_sr,
-                label="sr25519",
-            )
-        return (ok, n, size_ok)
-
-    def gather(self, handle) -> np.ndarray:
-        ok, n, size_ok = handle
-        if ok is None:
-            return size_ok
-        return np.asarray(ok)[:n] & size_ok
 
 
 _DEFAULT: Optional[Sr25519Verifier] = None

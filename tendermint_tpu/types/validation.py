@@ -13,8 +13,8 @@ Every path here consults the process-wide verified-signature cache
 (crypto.sigcache) BEFORE batch assembly and populates it on success:
 only cache misses are assembled, so a LastCommit whose precommits were
 gossip-verified re-verifies with zero crypto calls, and device buckets
-pad to the real miss count. TM_TPU_NO_SIGCACHE=1 restores the uncached
-behavior exactly (same errors, same tallies — just slower).
+pad to the real miss count. A sigcache.disabled() scope restores the
+uncached behavior exactly (same errors, same tallies — just slower).
 
 The WARM path additionally does zero encoding and (near-)zero per-vote
 Python work (PERF.md "Warm path"): sign-bytes come from the commit-
@@ -618,9 +618,9 @@ def _verify_commit_batch_vector(
         # before, in this mode, against this exact set composition and
         # these exact live powers, short-circuits to the
         # (deterministic) success in O(1) probes. Failures are never
-        # recorded, the token components die with any mutation, and
-        # TM_TPU_NO_SIGCACHE / TM_TPU_NO_COMMIT_MEMO disable the whole
-        # consult.
+        # recorded, the token components die with any mutation, and a
+        # sigcache.disabled() / commit_memo_disabled() scope disables
+        # the whole consult.
         ckey_commit = None
         memo_hit = False
         if use_cache and sigcache.commit_memo_enabled():

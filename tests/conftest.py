@@ -32,6 +32,30 @@ from tendermint_tpu.ops import compile_cache  # noqa: E402
 compile_cache.enable()
 
 
+def parse_walk_cpu_s() -> float:
+    """CPU seconds this thread takes, now, to parse and walk every
+    source of the package once: the unit of the whole-package
+    analyzers' run-time budgets (tests/test_tmrace.py, test_tmlive.py).
+    Every analyzer starts with exactly this step, so a budget of so many
+    units follows the machine's speed at this moment (tier-1's six
+    workers share the cores, and sandboxes differ by a factor of two)
+    and the package's size, and pins what the analysis costs on top."""
+    import ast
+    import glob
+    import time
+
+    root = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tendermint_tpu",
+    )
+    t0 = time.thread_time()
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path, encoding="utf-8") as f:
+            for _ in ast.walk(ast.parse(f.read())):
+                pass
+    return time.thread_time() - t0
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_executables():
     """Drop compiled-executable references after every test module.

@@ -5,7 +5,7 @@ production code."""
 
 
 class BadSharded:
-    """Mimics _MeshSharded's constructor contract but skips the
+    """Mimics a sharded verifier's constructor contract but skips the
     round-up that makes every bucket divide by the mesh."""
 
     def __init__(self, mesh, bucket_sizes=None):

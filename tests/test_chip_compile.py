@@ -217,13 +217,14 @@ def test_sharded_tile_on_four_chips(compile_for_chip, four_chips, lanes, key):
     """A mesh verifier's tile over the four described chips, at the
     bucket a streamed chunk lands in: 512 lanes a chip."""
     from tendermint_tpu import parallel
+    from tendermint_tpu.ops.verifier import LANES
 
     name, rows = SHARDED_TILES[key]
     v = getattr(parallel, name)(four_chips)
     n = v._bucket(lanes)
     mat = NamedSharding(four_chips, P(None, "sig"))
     _lowered, compiled = compile_for_chip(
-        v._program(n), *(_rows(r, n, mat) for r in rows)
+        v._program(v._TILE, LANES), *(_rows(r, n, mat) for r in rows)
     )
     _assert_batch_axis_partitioned(compiled, n, set(rows))
     _assert_fits(compiled)
@@ -233,13 +234,15 @@ def test_sharded_sha512_on_four_chips(compile_for_chip, four_chips, lanes):
     """The mesh verifier's SHA-512 over R || A || a 115-byte sign-bytes
     (the benchmark's one length), partitioned like the tile: the
     digests leave each chip as its own quarter and never gather."""
+    from tendermint_tpu.ops.ed25519_kernel import _SHA512
+    from tendermint_tpu.ops.verifier import ROWS
     from tendermint_tpu.parallel import ShardedEd25519Verifier
 
     v = ShardedEd25519Verifier(four_chips)
     n = v._bucket(lanes)
     mat = NamedSharding(four_chips, P(None, "sig"))
     lowered, compiled = compile_for_chip(
-        v._sha512_program(), _rows(64 + 115, n, mat)
+        v._program(_SHA512, ROWS), _rows(64 + 115, n, mat)
     )
     assert "stablehlo.while" not in lowered.as_text()
     _assert_batch_axis_partitioned(compiled, n, (64 + 115, 64))

@@ -248,32 +248,6 @@ def test_no_cpu_arm(where, tmp_path):
         assert "needs a TPU" in proc.stderr
 
 
-def test_pallas_fallback_is_counted():
-    """TM_TPU_PALLAS is opt-in and off the smoke's path, but a run that
-    asks for a Pallas program and gets the XLA one must be able to
-    tell: the swap is counted where the smoke (and /metrics) reads it.
-    A failing XLA program is a real error and is not swapped."""
-    from tendermint_tpu.ops.ed25519_kernel import run_with_pallas_fallback
-
-    def refused(*_args):
-        raise RuntimeError("Mosaic refused the kernel")
-
-    compiled = {128: refused}
-    before = tpu_verifier.stats()["pallas_fallbacks"]
-    kw = dict(
-        bucket=128, proven=set(), compiled=compiled,
-        xla_factory=lambda: (lambda *args: "xla verdicts"), label="ed25519",
-    )
-    assert run_with_pallas_fallback(refused, (), is_pallas=True, **kw) == (
-        "xla verdicts"
-    )
-    assert tpu_verifier.stats()["pallas_fallbacks"] == before + 1
-    assert compiled[128] is not refused
-    with pytest.raises(RuntimeError, match="Mosaic refused"):
-        run_with_pallas_fallback(refused, (), is_pallas=False, **kw)
-    assert tpu_verifier.stats()["pallas_fallbacks"] == before + 1
-
-
 @pytest.mark.parametrize(
     "platforms, backend, want",
     [

@@ -201,12 +201,6 @@ def test_mixed_message_lengths_device_digests(verifier):
     assert ok.tolist() == [True, True, False, True, True, True]
 
 
-def test_host_sha512_env_knob(verifier, monkeypatch):
-    monkeypatch.setenv("TM_TPU_HOST_SHA512", "1")
-    pks, msgs, sigs = _sign_set(5, b"knob")
-    assert verifier.verify(pks, msgs, sigs).all()
-
-
 def test_recode_signed_value_preserving():
     """_recode_signed must re-express the radix-16 value exactly with
     digits in [-8, 7] — including maximal carry-propagation runs (all

@@ -188,3 +188,180 @@ def test_mesh_install_shards_sr25519(mesh):
         assert ok and bitmap == [True] * 8
     finally:
         tpu_verifier.uninstall()
+
+
+# ---------------------------------------------------------------------------
+# the one verifier body (ops/verifier.py): both key classes, both placements
+
+
+_SHARED: dict = {}  # (key, placement) -> verifier: each compiles once
+
+
+def _make_verifier(key, placement, mesh, sizes):
+    from tendermint_tpu.ops.ed25519_kernel import Ed25519Verifier
+    from tendermint_tpu.ops.sr25519_kernel import Sr25519Verifier
+    from tendermint_tpu.parallel import ShardedSr25519Verifier
+
+    single, sharded = {
+        "ed25519": (Ed25519Verifier, ShardedEd25519Verifier),
+        "sr25519": (Sr25519Verifier, ShardedSr25519Verifier),
+    }[key]
+    if placement == "mesh":
+        return sharded(mesh, list(sizes))
+    return single(list(sizes))
+
+
+def _verifier_case(key, placement, mesh):
+    """(a verifier with buckets 8 and 32, a signed set of 11, the
+    seam's batch class) for a key class on one device or over the
+    suite's eight-device mesh."""
+    if (key, placement) not in _SHARED:
+        _SHARED[key, placement] = _make_verifier(key, placement, mesh, (8, 32))
+    sign_set = _sign_set if key == "ed25519" else _sr_sign_set
+    seam = (
+        tpu_verifier.TpuEd25519BatchVerifier
+        if key == "ed25519"
+        else tpu_verifier.TpuSr25519BatchVerifier
+    )
+    return _SHARED[key, placement], sign_set(11, b"one-body-" + key.encode()), seam
+
+
+CASES = [
+    (key, placement)
+    for key in ("ed25519", "sr25519")
+    for placement in ("one", "mesh")
+]
+
+
+@pytest.mark.parametrize("key, placement", CASES)
+def test_bitmap_equals_the_cpu_factorys(mesh, key, placement):
+    """One corrupted signature and one malformed-size entry: every
+    well-formed lane reads as the CPU batch verifier reads it, and the
+    malformed one is invalid at its own index, not an exception."""
+    from tendermint_tpu.crypto.keys import pubkey_from_type_and_bytes
+
+    v, (pks, msgs, sigs), _seam = _verifier_case(key, placement, mesh)
+    sigs[3] = sigs[3][:40] + bytes([sigs[3][40] ^ 1]) + sigs[3][41:]
+    cpu = crypto_batch.cpu_factory(key)()
+    for pk, m, s in zip(pks, msgs, sigs):
+        cpu.add(pubkey_from_type_and_bytes(key, pk), m, s)
+    want = cpu.verify()[1]
+    assert want == [i != 3 for i in range(11)]
+    sigs[7] = sigs[7][:63]  # the add() layer would have refused it
+    want[7] = False
+    assert v.verify(pks, msgs, sigs).tolist() == want
+
+
+@pytest.mark.parametrize("key, placement", CASES)
+def test_bucket_rule_has_one_home(mesh, key, placement):
+    """`_bucket(n)` holds n, comes from the configured sizes and is a
+    multiple of the mesh; the seam's pad-waste for a dispatch is that
+    bucket less n, under a mesh too (the seam asks the verifier)."""
+    from tendermint_tpu.crypto.keys import pubkey_from_type_and_bytes
+
+    devices = 8 if placement == "mesh" else 1
+    r = _make_verifier(key, placement, mesh, (4, 12, 30))  # never dispatched
+    assert r.bucket_sizes == ([8, 16, 32] if devices == 8 else [4, 12, 30])
+    for n in (1, 4, 5, 11, 13, 30):
+        assert r._bucket(n) >= n and r._bucket(n) in r.bucket_sizes
+    for n in (1, 31, 33, 20_001):  # oversized included
+        assert r._bucket(n) >= n and r._bucket(n) % devices == 0
+    v, (pks, msgs, sigs), seam = _verifier_case(key, placement, mesh)
+    assert tpu_verifier._bucket_of(v, 11) == v._bucket(11)
+    bv = seam(verifier=v)
+    for pk, m, s in zip(pks, msgs, sigs):
+        bv.add(pubkey_from_type_and_bytes(key, pk), m, s)
+    before = tpu_verifier.stats()["pad_waste"]
+    ok, bitmap = bv.verify()
+    assert ok and bitmap == [True] * 11
+    assert tpu_verifier.stats()["pad_waste"] - before == v._bucket(11) - 11
+
+
+@pytest.mark.parametrize("key, placement", CASES)
+def test_one_dispatch_opens_the_named_spans(mesh, key, placement):
+    """The benchmark reads these by name: `pack_rows`, `device_launch`
+    with `program` the traced function's own name, and under a mesh
+    one `shard_place` a host array with the bucket's share a chip."""
+    from tendermint_tpu.libs import trace
+
+    v, (pks, msgs, sigs), _seam = _verifier_case(key, placement, mesh)
+    trace.disable()
+    trace.reset()
+    trace.enable()
+    try:
+        assert v.verify(pks, msgs, sigs).all()
+        spans = trace.snapshot()
+    finally:
+        trace.disable()
+        trace.reset()
+    bucket = v._bucket(11)
+    named = lambda name: [s for s in spans if s.name == name]  # noqa: E731
+    (pack,) = named("pack_rows")
+    assert pack.attrs["n"] == 11 and pack.attrs["bucket"] == bucket
+    launches = named("device_launch")
+    assert [s.attrs["program"] for s in launches] == (
+        ["sha512_fixed", "_verify_tile"]
+        if key == "ed25519"
+        else ["_verify_tile_sr"]
+    )
+    assert all(s.attrs["bucket"] == bucket for s in launches)
+    assert len(named("merlin_challenges")) == (key == "sr25519")
+    places = named("shard_place")
+    if placement == "one":
+        assert places == []
+        return
+    # ed25519: the pre-image under the SHA-512 launch, then pubkeys and
+    # signatures under the tile's (the digests are on the mesh already);
+    # sr25519: all three operands come from the host
+    assert len(places) == 3
+    launch_ids = {s.span_id for s in launches}
+    for s in places:
+        assert s.parent_id in launch_ids
+        assert s.attrs["devices"] == 8
+        assert s.attrs["lanes_per_device"] == bucket // 8
+
+
+def test_no_environment_switch_on_the_verification_path():
+    """The path a commit's signatures take reads two deployment
+    settings from the environment and nothing else: a switch that
+    selects a code path there would be a configuration no cell runs."""
+    import ast
+    import glob
+    import os
+
+    root = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "tendermint_tpu",
+    )
+    files = sorted(
+        glob.glob(os.path.join(root, "ops", "*.py"))
+        + glob.glob(os.path.join(root, "parallel", "*.py"))
+        + [
+            os.path.join(root, *p.split("/"))
+            for p in (
+                "crypto/tpu_verifier.py",
+                "crypto/sigcache.py",
+                "crypto/batch.py",
+                "types/validation.py",
+            )
+        ]
+    )
+    assert len(files) > 12
+    allowed = {
+        "crypto/tpu_verifier.py": "TM_TPU_GATHER_DEADLINE_S",
+        "ops/compile_cache.py": "JAX_COMPILATION_CACHE_DIR",
+    }
+    seen = set()
+    for path in files:
+        rel = os.path.relpath(path, root).replace(os.sep, "/")
+        text = open(path, encoding="utf-8").read()
+        for node in ast.walk(ast.parse(text)):
+            name = getattr(node, "attr", None) or getattr(node, "id", None)
+            if name not in ("environ", "environb", "getenv", "putenv"):
+                continue
+            line = text.splitlines()[node.lineno - 1]
+            assert allowed.get(rel, "no variable") in line, (
+                f"{rel}:{node.lineno} reads the environment: {line.strip()}"
+            )
+            seen.add(rel)
+    assert seen == set(allowed)

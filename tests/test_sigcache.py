@@ -117,11 +117,11 @@ def test_promotion_survives_rotation():
         assert sigcache.seen(*hot)  # each consult re-promotes
 
 
-def test_env_gate_disables(monkeypatch):
-    monkeypatch.setenv("TM_TPU_NO_SIGCACHE", "1")
-    assert not sigcache.enabled()
-    sigcache.add(b"\x01" * 32, b"m", b"\x02" * 64)
-    assert not sigcache.seen(b"\x01" * 32, b"m", b"\x02" * 64)
+def test_disabled_scope_drops_inserts_and_misses():
+    with sigcache.disabled():
+        assert not sigcache.enabled()
+        sigcache.add(b"\x01" * 32, b"m", b"\x02" * 64)
+        assert not sigcache.seen(b"\x01" * 32, b"m", b"\x02" * 64)
     assert sigcache.entries() == 0
 
 
@@ -179,10 +179,11 @@ def test_commit_memo_gates():
         assert not sigcache.seen_commit(key)
 
 
-def test_commit_memo_env_gate(monkeypatch):
-    monkeypatch.setenv("TM_TPU_NO_COMMIT_MEMO", "1")
-    assert sigcache.enabled()  # triples unaffected
-    assert not sigcache.commit_memo_enabled()
+def test_commit_memo_scope_leaves_triples_on():
+    with sigcache.commit_memo_disabled():
+        assert sigcache.enabled()  # triples unaffected
+        assert not sigcache.commit_memo_enabled()
+    assert sigcache.commit_memo_enabled()
 
 
 # -- safety: failures never cached, errors identical warm/cold/disabled --

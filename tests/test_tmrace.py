@@ -17,6 +17,7 @@ import time
 
 import pytest
 
+from .conftest import parse_walk_cpu_s
 from tendermint_tpu.analysis import lockwatch, tmrace
 from tendermint_tpu.analysis.tmlint import (
     Violation,
@@ -77,12 +78,23 @@ def test_race_baseline_is_checked_in_and_empty():
 
 def test_full_package_run_under_budget():
     """Runtime budget: the race pass runs on every tier-1 invocation
-    and must stay under 10 s for the whole package (measured ~5 s for
-    160+ modules, call-graph build included)."""
-    t0 = time.monotonic()
+    and must stay bounded for the whole package, call-graph build
+    included. The pin was 10 s of wall time, twice the ~5 s the pass
+    took when it was set; under tier-1's six workers, and on a sandbox
+    whose CPU runs the pass alone in 12-15 s, that measured the
+    machine. It is now 32 parse-and-walks of the package in CPU time of
+    this thread (conftest.parse_walk_cpu_s, taken before and after):
+    the pass costs 14-17 of them, so the headroom is the same factor
+    of two and a blow-up still fails."""
+    unit = parse_walk_cpu_s()
+    t0 = time.thread_time()
     tmrace.analyze()
-    elapsed = time.monotonic() - t0
-    assert elapsed < 10.0, f"tmrace full-package run took {elapsed:.1f}s"
+    elapsed = time.thread_time() - t0
+    unit = (unit + parse_walk_cpu_s()) / 2
+    assert elapsed < 32 * unit, (
+        f"tmrace full-package run took {elapsed:.1f}s of CPU, "
+        f"{elapsed / unit:.1f} parse-and-walks of the package"
+    )
 
 
 # ---------------------------------------------------------------------------

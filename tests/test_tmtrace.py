@@ -43,16 +43,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EXPECTED_ROOT_IDS = {
     "ops/ed25519_kernel.py:_verify_tile",
     "ops/ed25519_kernel.py:sha512_fixed",
-    "ops/ed25519_pallas.py:verify_pallas",
-    "ops/ed25519_pallas.py:dual_mult_pallas",
-    "ops/ed25519_pallas.py:verify_hybrid",
     "ops/merkle_kernel.py:S.inner_hash_batch",
     "ops/merkle_kernel.py:_verify_program",
     "ops/sr25519_kernel.py:_verify_tile_sr",
-    "ops/sr25519_kernel.py:functools.partial(_verify_tile_sr, "
-    "dual_fn=dual_mult_pallas)",
-    "parallel/sharding.py:type(self)._TILE_FN",
-    "parallel/sharding.py:sha512_fixed",
+    "ops/verifier.py:shared.__wrapped__",
 }
 
 
@@ -149,9 +143,8 @@ def test_every_device_jit_root_in_golden(pkg):
 
 def test_golden_records_static_args_and_buckets():
     golden = shapemodel.load_golden()
-    vp = golden["roots"]["ops/ed25519_pallas.py:verify_pallas"]
-    assert vp["static_argnames"] == ["interpret", "tile"]
     tile = golden["roots"]["ops/ed25519_kernel.py:_verify_tile"]
+    assert tile["static_argnames"] == [] and tile["static_argnums"] == []
     from tendermint_tpu.config import DEFAULT_BUCKET_SIZES
 
     for b in DEFAULT_BUCKET_SIZES:
@@ -266,7 +259,7 @@ def test_fixture_suppressions_silence_every_form():
 
 
 def test_divisibility_real_classes_pass():
-    """The production _MeshSharded rounding keeps every bucket
+    """The production ops/verifier.py rounding keeps every bucket
     divisible by every virtual mesh width."""
     assert shardcheck.divisibility_violations() == []
 
@@ -486,27 +479,6 @@ def test_baseline_roundtrip(tmp_path):
     # one extra identical-fingerprint finding still fails
     extra = rep.violations + [rep.violations[0]]
     assert new_violations(extra, load_baseline(path))
-
-
-def test_mosaic_probe_contract():
-    """The toolchain probe (satellite of this PR: gates
-    test_mosaic_jaxpr_clean) returns the recorded shape and its
-    banned-prim walker actually detects a real gather."""
-    import jax
-    import jax.numpy as jnp
-
-    from tendermint_tpu.ops import toolchain
-
-    probe = toolchain.mosaic_probe()
-    assert set(probe) == {"clean", "introduced", "jax_version"}
-    assert isinstance(probe["clean"], bool)
-    # a genuine dynamic gather is always detected
-    bad = toolchain.banned_prims_of(
-        lambda x, i: x[i],
-        jax.ShapeDtypeStruct((16,), jnp.int32),
-        jax.ShapeDtypeStruct((4,), jnp.int32),
-    )
-    assert "gather" in bad
 
 
 # ---------------------------------------------------------------------------
