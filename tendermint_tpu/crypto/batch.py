@@ -13,13 +13,14 @@ from __future__ import annotations
 import threading
 from typing import Callable, Optional
 
-from ..libs import trace
+from ..libs import heap, trace
 from .keys import BatchVerifier, PubKey
 
 __all__ = [
     "create_batch_verifier",
     "cpu_factory",
     "drain_and_cache",
+    "drain_classes",
     "supports_batch_verifier",
     "register_device_factory",
     "device_factory_installed",
@@ -199,6 +200,62 @@ def drain_and_cache(verifier: BatchVerifier, cache_keys) -> tuple:
         sigcache.add_keys_bulk(proven)
         span.set(keys=len(proven))
     return ok, bits
+
+
+def drain_classes(pending: dict) -> dict:
+    """Drain the per-key-class miss batches of one verification in two
+    phases, so that every class's device work is in flight before the
+    host blocks on any of it. `pending` maps a key type to its items:
+    (pub_key, sign_bytes, signature, index, cache key) tuples.
+
+    Phase 1, a class at a time: add() its triples to its verifier (a
+    device verifier streams full chunks from inside add()) and launch()
+    its remainder. A class whose launches cost the host byte rows alone
+    goes before one that makes an operand on the host (`host_operand`:
+    sr25519's merlin), so that the device starts soonest and the
+    costlier host work runs under device time; among equals, in
+    `pending`'s order. Phase 2, in the same order: drain_and_cache()
+    each, so a class's cache is populated under the next one's tiles.
+    Every class is verified whatever an earlier one answered, and the
+    heap settles once, after the last gather. Returns key type ->
+    (all_ok, bitmap aligned with the items).
+
+    One `batch_drain` span a call: `classes`, the verifiers drained,
+    and `overlapped`, those whose every launch was enqueued before the
+    first gather began. If anything raises, what was launched is
+    abandoned and nothing of it reaches the cache. With nothing
+    pending (every triple a cache hit) there is no drain and no span."""
+    if not pending:
+        return {}
+    with trace.span(
+        "batch_drain", classes=len(pending)
+    ) as span, heap.deferred():
+        batches = [
+            (
+                key_type,
+                items,
+                create_batch_verifier(items[0][0], size_hint=len(items)),
+            )
+            for key_type, items in pending.items()
+        ]
+        batches.sort(key=lambda batch: batch[2].host_operand)
+        try:
+            overlapped = 0
+            for key_type, items, bv in batches:
+                with trace.span("batch_add", key=key_type, sigs=len(items)):
+                    for pub_key, sb, sig, _idx, _ckey in items:
+                        bv.add(pub_key, sb, sig)
+                    if bv.launch():
+                        overlapped += 1
+            span.set(overlapped=overlapped)
+            return {
+                key_type: drain_and_cache(bv, [it[4] for it in items])
+                for key_type, items, bv in batches
+            }
+        except BaseException:
+            for _key_type, _items, bv in batches:
+                bv.abandon()
+            raise
 
 
 def native_cpu_affinity() -> int:

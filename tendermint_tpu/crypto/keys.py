@@ -103,6 +103,24 @@ class BatchVerifier(ABC):
     @abstractmethod
     def verify(self) -> Tuple[bool, List[bool]]: ...
 
+    # True when launching a window costs the host more than joining
+    # byte rows (a device verifier whose kernel takes an operand made
+    # on the host). crypto.batch.drain_classes launches such a class
+    # last, so that its host work runs under the other classes' device
+    # time.
+    host_operand = False
+
+    def launch(self) -> bool:
+        """Start whatever is queued without waiting for it, so that the
+        caller can fill another verifier before it blocks in verify().
+        True when the whole batch is then in flight. A verifier that
+        works on the host has nothing to start: verify() does it all."""
+        return False
+
+    def abandon(self) -> None:
+        """Forget what launch() started: the caller is raising and will
+        never call verify(). Nothing to forget on the host."""
+
     def __len__(self) -> int:  # number of queued items; override if cheap
         raise NotImplementedError
 

@@ -378,7 +378,11 @@ class _TpuBatchVerifier(BatchVerifier):
     device compute instead of serializing in front of it; verify()
     dispatches the remainder and gathers every in-flight handle in add
     order. The chunk matches a configured bucket so no new program
-    shapes are compiled.
+    shapes are compiled. On any backend launch() dispatches the
+    remainder ahead of verify(), at the bucket verify() would have
+    used, so that a caller with several key classes
+    (crypto.batch.drain_classes) has them all in flight before it
+    blocks at the first gather.
 
     Fault containment: every triple is retained (as references) until
     verify() returns, so ANY device failure — a raising dispatch, a
@@ -411,6 +415,9 @@ class _TpuBatchVerifier(BatchVerifier):
         self._last_bucket = 0
         self._pad_waste = 0
         self._cold_dispatch = False
+        # seconds launch() spent packing and launching the remainder:
+        # verify() reports them in its host_prep_s
+        self._launch_s = 0.0
 
     @staticmethod
     def _kernel_module():
@@ -458,6 +465,66 @@ class _TpuBatchVerifier(BatchVerifier):
         self._pks, self._msgs, self._sigs = [], [], []
         _m_batches.inc()
 
+    def _launch_window(self, span_name: str) -> None:
+        """Launch the pending window ahead of verify(), under a span of
+        `span_name`, if the route can take it: injected verifiers only
+        promise verify(), so the dispatch()/gather() pair has to be
+        there, and the route fully healthy (state(), not allow(): such
+        a launch must never consume the one half-open admission ticket
+        the factory gate hands out). A faulted async launch must not
+        raise out of add() or launch(): the window stays queued, and
+        verify() sees the recorded fault and drains everything on CPU."""
+        v = self._backing()
+        if not (
+            hasattr(v, "dispatch")
+            and hasattr(v, "gather")
+            and _breaker(self.KEY_TYPE).state() == _breaker_mod.CLOSED
+        ):
+            return
+        with trace.span(
+            span_name,
+            key=self.KEY_TYPE,
+            n=len(self._pks),
+            chunk=len(self._handles),
+            mesh_devices=_mesh_devices(v),
+        ) as span:
+            try:
+                self._dispatch_pending(v)
+            except Exception as e:
+                self._stream_fault = e
+            span.set(bucket=self._last_bucket)
+
+    @property
+    def host_operand(self) -> bool:
+        """Whether the backing verifier makes its tile's third operand
+        on the host (ops/verifier.py `host_operand`: sr25519's merlin
+        challenges are, ed25519's SHA-512 is a device program)."""
+        return bool(getattr(self._backing(), "host_operand", False))
+
+    def launch(self) -> bool:
+        """Dispatch the remainder now and return without gathering: the
+        first half of verify()'s work, for a caller that has another
+        class to fill. verify() then finds the handles and an empty
+        window and goes straight to the gather. The triples stay until
+        verify() returns, so every containment property is verify()'s."""
+        if self._pks and self._stream_fault is None:
+            t0 = time.perf_counter()
+            self._launch_window("tpu_early_dispatch")
+            self._launch_s += time.perf_counter() - t0
+        return (
+            bool(self._handles)
+            and not self._pks
+            and self._stream_fault is None
+        )
+
+    def abandon(self) -> None:
+        """Drop the in-flight handles and the queue (the device ends
+        what it was given; nobody reads the result)."""
+        self._handles = []
+        self._pks, self._msgs, self._sigs = [], [], []
+        self._all = []
+        self._stream_fault = None
+
     def add(self, pub_key: PubKey, message: bytes, signature: bytes) -> None:
         if pub_key.type() != self.KEY_TYPE:
             raise TypeError(
@@ -476,34 +543,7 @@ class _TpuBatchVerifier(BatchVerifier):
             and self._streaming()
             and self._stream_fault is None
         ):
-            v = self._backing()
-            # injected verifiers only promise verify(); stream solely
-            # when the dispatch()/gather() pair is actually there —
-            # and only onto a fully healthy route (state(), not
-            # allow(): a chunk launch must never consume the one
-            # half-open admission ticket the factory gate hands out)
-            if (
-                hasattr(v, "dispatch")
-                and hasattr(v, "gather")
-                and _breaker(self.KEY_TYPE).state() == _breaker_mod.CLOSED
-            ):
-                with trace.span(
-                    "tpu_stream_dispatch",
-                    key=self.KEY_TYPE,
-                    n=len(self._pks),
-                    chunk=len(self._handles),
-                    mesh_devices=_mesh_devices(v),
-                ) as span:
-                    try:
-                        self._dispatch_pending(v)
-                    except Exception as e:
-                        # a faulted async launch must not raise out of
-                        # add() — its contract is malformed-input
-                        # errors only. The window stays queued;
-                        # verify() sees the recorded fault and drains
-                        # everything on CPU.
-                        self._stream_fault = e
-                    span.set(bucket=self._last_bucket)
+            self._launch_window("tpu_stream_dispatch")
 
     def verify(self) -> Tuple[bool, List[bool]]:
         """Drains the queue: a verifier is a one-shot batch (matching
@@ -518,9 +558,12 @@ class _TpuBatchVerifier(BatchVerifier):
         (`host_prep_s`, tpu_host_prep_seconds), everything after is the
         `tpu_gather` child span, the host blocked on the device
         (tpu_gather_wait_seconds). The chunks add() streamed earlier
-        are `tpu_stream_dispatch` spans of their own. Backings without
-        the dispatch()/gather() pair (injected test verifiers) report
-        one undivided wall time.
+        are `tpu_stream_dispatch` spans of their own; a remainder that
+        launch() dispatched earlier is a `tpu_early_dispatch` span, and
+        its seconds are in `host_prep_s` all the same: the remainder's
+        packing and launch are one quantity wherever they run. Backings
+        without the dispatch()/gather() pair (injected test verifiers)
+        report one undivided wall time.
 
         Any device fault — including a mis-shaped bitmap or a lane the
         device invalidated that the CPU disproves — re-verifies the
@@ -557,10 +600,14 @@ class _TpuBatchVerifier(BatchVerifier):
                     and _breaker(self.KEY_TYPE).state() == _breaker_mod.OPEN
                 ):
                     raise _RoutedToCpu()
-                if self._handles:
+                if self._handles or (
+                    hasattr(v, "dispatch") and hasattr(v, "gather")
+                ):
+                    # the remainder, unless launch() sent it ahead (one
+                    # launch of the whole batch where nothing streamed)
                     if self._pks:
                         self._dispatch_pending(v)
-                    host_prep = time.perf_counter() - t0
+                    host_prep = time.perf_counter() - t0 + self._launch_s
                     got: List[bool] = []
                     try:
                         with trace.span(
@@ -588,28 +635,6 @@ class _TpuBatchVerifier(BatchVerifier):
                         # __len__ would keep reporting in-flight work
                         self._handles = []
                     bits = got
-                elif hasattr(v, "dispatch") and hasattr(v, "gather"):
-                    # split verify() at the same boundary the streaming
-                    # path uses (gather(dispatch()) is v.verify())
-                    self._account_dispatch(v, len(self._pks))
-                    if faults.armed():
-                        faults.fire("tpu.dispatch", key=self.KEY_TYPE)
-                    handle = v.dispatch(self._pks, self._msgs, self._sigs)
-                    host_prep = time.perf_counter() - t0
-                    _m_batches.inc()
-                    with trace.span(
-                        "tpu_gather",
-                        hist=_m_gather_wait,
-                        handles=1,
-                        sigs=total,
-                    ):
-                        bits = _gather_guarded(v, handle, self.KEY_TYPE)
-                    if len(bits) != total:
-                        raise DeviceFault(
-                            f"mis-shaped device result: {len(bits)} "
-                            f"lanes for {total} signatures"
-                        )
-                    device_sigs = total
                 else:
                     self._account_dispatch(v, len(self._pks))
                     if faults.armed():
@@ -654,10 +679,12 @@ class _TpuBatchVerifier(BatchVerifier):
                 warm=not self._cold_dispatch,
                 mesh_devices=_mesh_devices(v),
             )
-            # every handle is gathered: if a dispatch of this batch (a
-            # chunk add() streamed, or the remainder) traced, compiled
-            # or loaded a program, its temporaries are dead and what it
-            # left behind is not going to die
+            # every handle of this batch is gathered: if a dispatch of
+            # it (a chunk add() streamed, or the remainder) traced,
+            # compiled or loaded a program, its temporaries are dead
+            # and what it left behind is not going to die. A caller
+            # with another class still in flight holds the settle back
+            # to its last gather (heap.deferred)
             heap.settle()
         _m_sigs.inc(device_sigs)
         return all(bits), bits

@@ -22,12 +22,13 @@ compiles a device program never marks, never settles, never freezes.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import threading
 
 from . import metrics as M
 
-__all__ = ["mark_dirty", "settle", "thaw", "stats"]
+__all__ = ["deferred", "mark_dirty", "settle", "thaw", "stats"]
 
 _m_settles = M.new_counter(
     "heap",
@@ -45,6 +46,7 @@ _m_frozen = M.new_gauge(
 
 _lock = threading.Lock()
 _dirty = False  # guarded by _lock
+_deferrals = 0  # open deferred() scopes, guarded by _lock
 
 
 def mark_dirty() -> None:
@@ -59,10 +61,11 @@ def mark_dirty() -> None:
 
 def settle() -> bool:
     """Collect and freeze if the heap was marked since the last settle;
-    otherwise nothing, at the cost of one lock. True when it froze."""
+    otherwise nothing, at the cost of one lock. True when it froze.
+    Inside a `deferred()` scope the mark stays and nothing is frozen."""
     global _dirty
     with _lock:
-        if not _dirty:
+        if not _dirty or _deferrals:
             return False
         _dirty = False
     gc.collect()
@@ -75,6 +78,24 @@ def settle() -> bool:
     _m_settles.inc()
     _m_frozen.set(gc.get_freeze_count())
     return True
+
+
+@contextlib.contextmanager
+def deferred():
+    """Hold every settle back to the end of this scope, and settle
+    there once. For a seam call that keeps several batches in flight:
+    the first batch's gather is not the moment "every handle is
+    gathered", and a freeze there would take the other batches' live
+    handles and rows into the permanent generation."""
+    global _deferrals
+    with _lock:
+        _deferrals += 1
+    try:
+        yield
+    finally:
+        with _lock:
+            _deferrals -= 1
+        settle()
 
 
 def thaw() -> None:

@@ -176,6 +176,7 @@ class Sr25519Verifier(BucketedVerifier):
     the host, as the third operand."""
 
     _TILE = staticmethod(jax.jit(_verify_tile_sr))
+    host_operand = True
 
     def _third_operand(self, pubkeys, msgs, sigs, bucket, packed):
         """(32, bucket) rows of the merlin Fiat-Shamir challenges,

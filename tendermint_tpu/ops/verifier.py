@@ -73,6 +73,10 @@ class BucketedVerifier:
     is a synchronous device invocation."""
 
     _TILE = None  # staticmethod(jax.jit(tile function))
+    # whether `_third_operand` is host work (sr25519's merlin) and not
+    # a device program of its own (ed25519's SHA-512): the seam launches
+    # a class that packs byte rows alone first (crypto.batch.drain_classes)
+    host_operand = False
 
     def __init__(
         self, bucket_sizes: Optional[Sequence[int]] = None, mesh=None

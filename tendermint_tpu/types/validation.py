@@ -39,11 +39,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from ..crypto import sigcache
-from ..crypto.batch import (
-    create_batch_verifier,
-    drain_and_cache,
-    supports_batch_verifier,
-)
+from ..crypto.batch import drain_classes, supports_batch_verifier
 from ..libs import trace
 from .block_id import BlockID
 from .commit import (
@@ -271,7 +267,8 @@ def verify_triples_grouped(triples) -> None:
     ):
         use_cache = sigcache.enabled()
         hits = misses = 0
-        # key type -> [(pk, sign_bytes, signature, cache key)]: assembly
+        # key type -> [(pk, sign_bytes, signature, position, cache key)]
+        # (crypto.batch.drain_classes' item shape): assembly
         # is deferred so each group's size_hint is its OWN miss count —
         # previously every group got size_hint=len(triples), so in mixed
         # sets each device bucket padded to the merged total
@@ -313,22 +310,15 @@ def verify_triples_grouped(triples) -> None:
                         sigcache.add_key(ckey)
                     continue
                 pending.setdefault(pk.type(), []).append(
-                    (pk, sb, sig, ckey)
+                    (pk, sb, sig, n, ckey)
                 )
             route.set(inline=inline)
         if use_cache:
             sigcache.observe(hits, misses)
             trace.add_attrs(sigcache_hits=hits, sigcache_misses=misses)
-        for key_type, items in pending.items():
-            with trace.span("batch_add", key=key_type, sigs=len(items)):
-                bv = create_batch_verifier(
-                    items[0][0], size_hint=len(items)
-                )
-                for pk, sb, sig, _ckey in items:
-                    bv.add(pk, sb, sig)
-            ok, _bits = drain_and_cache(bv, [it[3] for it in items])
-            if not ok:
-                raise InvalidCommitError("wrong signature in merged batch")
+        verdicts = drain_classes(pending)
+        if not all(ok for ok, _bits in verdicts.values()):
+            raise InvalidCommitError("wrong signature in merged batch")
 
 
 def verify_commit_light_bulk(chain_id: str, rows) -> None:
@@ -906,18 +896,15 @@ def _verify_commit_batch_scalar(
 
 
 def _drain_pending(commit: Commit, pending: dict) -> None:
-    """Drain the per-key-type miss batches, populating the cache for
-    proven triples, and raise the reference error for the LOWEST bad
-    commit index across groups."""
+    """Drain the per-key-type miss batches (crypto.batch.drain_classes:
+    every class launched before any is gathered), populating the cache
+    for proven triples, and raise the reference error for the LOWEST
+    bad commit index across groups."""
     first_bad: Optional[int] = None
-    for key_type, items in pending.items():
-        with trace.span("batch_add", key=key_type, sigs=len(items)):
-            bv = create_batch_verifier(items[0][0], size_hint=len(items))
-            for pub_key, sb, sig, _idx, _ckey in items:
-                bv.add(pub_key, sb, sig)
-        ok, valid_sigs = drain_and_cache(bv, [it[4] for it in items])
+    for key_type, (ok, valid_sigs) in drain_classes(pending).items():
         if ok:
             continue
+        items = pending[key_type]
         bad = [
             items[i][3]
             for i, sig_ok in enumerate(valid_sigs)

@@ -318,7 +318,6 @@ def test_merged_triples_warm_and_group_sized(monkeypatch):
     the merged total."""
     hints = []
     from tendermint_tpu.crypto import batch as crypto_batch
-    from tendermint_tpu.types import validation
 
     real_create = crypto_batch.create_batch_verifier
 
@@ -326,8 +325,10 @@ def test_merged_triples_warm_and_group_sized(monkeypatch):
         hints.append((pk.type(), size_hint))
         return real_create(pk, size_hint=size_hint)
 
+    # the drain both validation entry points share creates the
+    # verifiers (crypto.batch.drain_classes)
     monkeypatch.setattr(
-        validation, "create_batch_verifier", spying_create
+        crypto_batch, "create_batch_verifier", spying_create
     )
     vals, privs = make_validators(3)
     bid = make_block_id(b"\x0d")

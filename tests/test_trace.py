@@ -280,20 +280,25 @@ PHASE_PARENTS = {
     "sign_bytes": "batch_accumulate",
     "sigcache_probe": "batch_accumulate",
     "batch_route": "batch_accumulate",
-    "batch_add": "batch_accumulate",
+    "batch_drain": "batch_accumulate",
+    "batch_add": "batch_drain",
     "tpu_stream_dispatch": "batch_add",
-    "tpu_dispatch": "batch_accumulate",
+    "tpu_early_dispatch": "batch_add",
+    "tpu_dispatch": "batch_drain",
     "tpu_gather": "tpu_dispatch",
-    "sigcache_populate": "batch_accumulate",
+    # the proven keys under the drain, the commit memo's entry after it
+    "sigcache_populate": ("batch_drain", "batch_accumulate"),
 }
 
 
 def test_commit_verification_phase_tree():
     """One commit verification is one tree: every phase of section B
-    under its parent, the streamed chunks under `batch_add`, the gather
-    under `tpu_dispatch`, the kernels' packing and launches under
-    either dispatch span, and next to nothing of `batch_accumulate`
-    left without a name."""
+    under its parent, the drain's span around `batch_add` and
+    `tpu_dispatch`, the streamed chunks and the early launch of the
+    remainder under `batch_add`, the gather under `tpu_dispatch`, the
+    kernels' packing and launches under the two launching spans and
+    none left under `tpu_dispatch`, and next to nothing of
+    `batch_accumulate` left without a name."""
     pytest.importorskip("jax")
     trace.enable(capacity=65536)
     with _device_seam(chunk=8):
@@ -310,7 +315,7 @@ def test_commit_verification_phase_tree():
         tree = [s for s in spans if s.root_id == full.span_id]
         for s in tree:
             if s.name in PHASE_PARENTS:
-                assert by_id[s.parent_id].name == PHASE_PARENTS[s.name], s.name
+                assert by_id[s.parent_id].name in PHASE_PARENTS[s.name], s.name
         names = [s.name for s in tree]
         for name in PHASE_PARENTS:
             assert name in names, (name, light)
@@ -323,11 +328,16 @@ def test_commit_verification_phase_tree():
             and s.attrs["key"] == "ed25519"
             for s in streamed
         )
+        (early,) = [s for s in tree if s.name == "tpu_early_dispatch"]
+        assert early.attrs == {
+            "key": "ed25519", "n": 6 if light else 4, "bucket": 8,
+            "chunk": len(streamed), "mesh_devices": 1,
+        }  # fmt: skip
         for leaf in ("pack_rows", "device_launch"):
             under = {
                 by_id[s.parent_id].name for s in tree if s.name == leaf
             }
-            assert under == {"tpu_stream_dispatch", "tpu_dispatch"}, leaf
+            assert under == {"tpu_stream_dispatch", "tpu_early_dispatch"}, leaf
         launches = [s for s in tree if s.name == "device_launch"]
         # a tile and its SHA-512 a dispatch
         assert len(launches) == 2 * (len(streamed) + 1)
@@ -335,7 +345,11 @@ def test_commit_verification_phase_tree():
             "_verify_tile", "sha512_fixed"
         }
         (dispatch,) = [s for s in tree if s.name == "tpu_dispatch"]
-        assert dispatch.attrs["host_prep_s"] >= 0.0
+        # the remainder's packing and launch, wherever they ran
+        # (rounded to the microsecond)
+        assert dispatch.attrs["host_prep_s"] >= early.dur_us / 1e6 - 1e-6
+        (drain,) = [s for s in tree if s.name == "batch_drain"]
+        assert drain.attrs == {"classes": 1, "overlapped": 1}
         assert "device_wall_s" not in dispatch.attrs
         (gather,) = [s for s in tree if s.name == "tpu_gather"]
         assert gather.attrs["handles"] == len(streamed) + 1
