@@ -658,15 +658,16 @@ def phase_light(n_vals: int, n_hops: int, seed: int, log: CompileLog) -> dict:
         sent.group(min(window, n_hops - first) * quorum)
     # verify_commit_light_bulk: every hop in one merged batch
     sent.group(n_hops * quorum)
-    # the forged chain: whole windows up to the bad one, the bad window
-    # as one merged batch that fails, then that window again hop by hop
-    # up to and including the bad hop
+    # the forged chain: whole windows up to the bad one, then the bad
+    # window once, as the merged batch that names the bad hop (hop by
+    # hop up to and including it where the window is one hop)
     before_bad = (bad_hop - 1) // window * window
     for first in range(0, before_bad, window):
         sent.group(window * quorum)
     if window > 1:
         sent.group(min(window, n_hops - before_bad) * quorum)
-    sent.group(quorum, times=bad_hop - before_bad)
+    else:
+        sent.group(quorum, times=bad_hop - before_bad)
     out = {
         "phase": f"light-{n_vals}",
         "validators": n_vals,

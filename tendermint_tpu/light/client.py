@@ -16,6 +16,7 @@ import time
 from dataclasses import dataclass
 from typing import List, Optional
 
+from ..libs import trace
 from ..libs.log import get_logger
 from ..types.evidence import LightClientAttackEvidence
 from ..types.light import LightBlock
@@ -191,16 +192,28 @@ class Client:
             raise LightClientError(
                 "closest trusted header is outside the trusting period"
             )
-        if target is None:
-            target = await self._from_primary(height)
-            target.validate_basic(self.chain_id)
-        if self.sequential:
-            verified = await self._verify_sequential(trusted, target, now_ns)
-        else:
-            verified = await self._verify_skipping(trusted, target, now_ns)
-        await self._detect_divergence(verified, now_ns)
-        self.store.save_light_block(verified)
-        self.store.prune(self.pruning_size)
+        with trace.span(
+            "light_sync",
+            from_height=trusted.height,
+            to_height=height,
+            mode="sequential" if self.sequential else "skipping",
+        ):
+            if target is None:
+                target = await self._from_primary(height)
+                target.validate_basic(self.chain_id)
+            if self.sequential:
+                verified = await self._verify_sequential(
+                    trusted, target, now_ns
+                )
+            else:
+                verified = await self._verify_skipping(
+                    trusted, target, now_ns
+                )
+            with trace.span("light_divergence", witnesses=len(self.witnesses)):
+                await self._detect_divergence(verified, now_ns)
+            with trace.span("light_store_save", blocks=1):
+                self.store.save_light_block(verified)
+                self.store.prune(self.pruning_size)
         return verified
 
     def _closest_trusted_below(self, height: int) -> Optional[LightBlock]:
@@ -217,7 +230,12 @@ class Client:
         on host, then every commit's signatures go to the device as ONE
         merged batch (the hop-per-device-call form pays a dispatch per
         header — at 10k headers that is 10k round-trips for work the
-        chip finishes in milliseconds). Any window failure falls back
+        chip finishes in milliseconds). A wrong signature comes back
+        from the merged batch with its hop named
+        (verify_adjacent_batch): the hops before it are saved and the
+        hop's own error raised, with no height fetched or verified
+        twice. Any other window failure (a header-chain check, which
+        runs before any signature of the window is checked) falls back
         to the reference's one-hop-at-a-time loop for the exact error
         and store state."""
         from ..crypto.batch import group_affinity
@@ -240,28 +258,40 @@ class Client:
         while cur.height < target.height:
             first = cur.height + 1
             last = min(first + window - 1, target.height)
+            chunk: List[LightBlock] = []
             try:
                 chunk = await self._fetch_range(
                     first, min(last, target.height - 1)
                 )
                 if last == target.height:
                     chunk.append(target)
-                for b in chunk:
-                    if b.height < target.height:
-                        b.validate_basic(self.chain_id)
-                # all header-chain checks in hop order, then every
-                # commit through ONE sigcache-aware bulk verification
-                # (merged probe + grouped batch cold, M memo probes
-                # warm — types/validation.verify_commit_light_bulk)
-                verify_adjacent_batch(
-                    self.chain_id,
-                    cur.signed_header,
-                    chunk,
-                    self.trust_options.period_ns,
-                    now_ns,
-                    self.max_clock_drift_ns,
-                )
+                with trace.span(
+                    "light_window", hops=len(chunk), first=first, last=last
+                ):
+                    with trace.span("light_header_checks", hops=len(chunk)):
+                        for b in chunk:
+                            if b.height < target.height:
+                                b.validate_basic(self.chain_id)
+                    # all header-chain checks in hop order, then every
+                    # commit through ONE sigcache-aware bulk
+                    # verification (merged probe + grouped batch cold,
+                    # M memo probes warm —
+                    # types/validation.verify_commit_light_bulk)
+                    verify_adjacent_batch(
+                        self.chain_id,
+                        cur.signed_header,
+                        chunk,
+                        self.trust_options.period_ns,
+                        now_ns,
+                        self.max_clock_drift_ns,
+                    )
             except Exception as e:
+                hop = getattr(e, "hop", None)
+                if hop is not None:
+                    # the merged batch named the hop: the hops before
+                    # it are verified, and the error is that hop's own
+                    self._save_interim(chunk[:hop], target)
+                    raise
                 # reference-exact fallback: refetch and verify one hop
                 # at a time so the first failing height raises its own
                 # error with every prior hop verified and saved. Logged
@@ -273,22 +303,35 @@ class Client:
                     last=last,
                     err=repr(e),
                 )
-                for h in range(first, last + 1):
-                    if h == target.height:
-                        interim = target
-                    else:
-                        interim = await self._from_primary(h)
-                        interim.validate_basic(self.chain_id)
-                    self._verify_hop(cur, interim, now_ns)
-                    if h < target.height:
-                        self.store.save_light_block(interim)
-                    cur = interim
+                with trace.span(
+                    "light_fallback",
+                    hops=last - first + 1,
+                    reason=type(e).__name__,
+                ):
+                    for h in range(first, last + 1):
+                        if h == target.height:
+                            interim = target
+                        else:
+                            interim = await self._from_primary(h)
+                            interim.validate_basic(self.chain_id)
+                        self._verify_hop(cur, interim, now_ns)
+                        if h < target.height:
+                            self.store.save_light_block(interim)
+                        cur = interim
                 continue
-            for b in chunk:
-                if b.height < target.height:
-                    self.store.save_light_block(b)
+            self._save_interim(chunk, target)
             cur = chunk[-1]
         return target
+
+    def _save_interim(
+        self, blocks: List[LightBlock], target: LightBlock
+    ) -> None:
+        """Save a window's verified blocks; the target is saved by
+        _verify_forwards, after the witnesses have been asked."""
+        interim = [b for b in blocks if b.height < target.height]
+        with trace.span("light_store_save", blocks=len(interim)):
+            for b in interim:
+                self.store.save_light_block(b)
 
     async def _fetch_range(self, first: int, last: int) -> List[LightBlock]:
         """Fetch heights [first, last] ascending: ONE bulk
@@ -302,7 +345,8 @@ class Client:
         if last < first:
             return []
         try:
-            got = list(await self.primary.light_blocks(first, last))
+            with trace.span("light_fetch", first=first, last=last, bulk=True):
+                got = list(await self.primary.light_blocks(first, last))
             if [b.height for b in got] == list(range(first, last + 1)):
                 return got
             self.logger.info(
@@ -481,7 +525,10 @@ class Client:
         last_err: Optional[Exception] = None
         for provider in [self.primary] + list(self.witnesses):
             try:
-                lb = await provider.light_block(height)
+                with trace.span(
+                    "light_fetch", first=height, last=height, bulk=False
+                ):
+                    lb = await provider.light_block(height)
             except Exception as e:
                 last_err = e
                 continue

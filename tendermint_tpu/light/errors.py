@@ -38,7 +38,12 @@ class NewValSetCantBeTrustedError(LightClientError):
 
 class InvalidHeaderError(LightClientError):
     """The header failed basic or signature validation — the provider
-    is faulty (reference: light/errors.go ErrInvalidHeader)."""
+    is faulty (reference: light/errors.go ErrInvalidHeader). `hop` is
+    the place of the failing block among verify_adjacent_batch's
+    blocks, where the bulk verification could name it: every block
+    before it is then verified. None otherwise."""
+
+    hop = None
 
 
 class VerificationError(LightClientError):

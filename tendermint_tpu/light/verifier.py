@@ -13,14 +13,15 @@ Those paths consult the process-wide verified-signature cache
 (crypto.sigcache), which matters here twice over: verify_non_adjacent
 checks the SAME commit against two validator sets (the trusted set's
 trust-level check, then 2/3 of its own set) — the second pass re-meets
-every triple the first pass just proved; and the sequential window
-fallback (light/client.py re-verifying per commit after a merged-batch
-failure) only re-pays for the actually-bad commit, since the good
-commits' triples were cached by the merged attempt.
+every triple the first pass just proved; and the per-hop fallback of
+the sequential window (light/client.py, after a failure the merged
+batch could not place) re-pays only for what the merged attempt did
+not prove.
 """
 
 from __future__ import annotations
 
+from ..libs import trace
 from ..types.light import SignedHeader
 from ..types.validation import (
     Fraction,
@@ -240,31 +241,40 @@ def verify_adjacent_batch(
     tally per commit — no sign-bytes encoding, no per-triple cache
     keys, no crypto — and a cold pass is one merged bulk sigcache
     probe + one grouped batch verify for ALL M commits instead of M
-    independent verifies. Signature failures surface as
-    InvalidHeaderError without hop attribution; callers needing the
-    reference's exact failing hop fall back to the per-hop
-    verify_adjacent loop (light/client.py's sequential window does)."""
+    independent verifies. A wrong signature surfaces as the
+    InvalidHeaderError verify_adjacent raises for that hop (the text of
+    the same commit error) with `hop`, the failing block's place in
+    `blocks`, on it: the blocks before it are verified, and a caller
+    saves them without verifying anything twice (light/client.py's
+    sequential window does). A failure without `hop` (a header-chain
+    check, a tally, a key type the merged check cannot place) says
+    nothing about the signatures before it; callers needing the
+    reference's exact failing hop then fall back to the per-hop
+    verify_adjacent loop."""
     blocks = list(blocks)
     prev = trusted_header
     rows = []
-    for b in blocks:
-        adjacent_header_checks(
-            chain_id, prev, b.signed_header, b.validator_set,
-            trusting_period_ns, now_ns, max_clock_drift_ns,
-        )
-        rows.append(
-            (
-                b.validator_set,
-                b.signed_header.commit.block_id,
-                b.signed_header.header.height,
-                b.signed_header.commit,
+    with trace.span("light_header_checks", hops=len(blocks)):
+        for b in blocks:
+            adjacent_header_checks(
+                chain_id, prev, b.signed_header, b.validator_set,
+                trusting_period_ns, now_ns, max_clock_drift_ns,
             )
-        )
-        prev = b.signed_header
+            rows.append(
+                (
+                    b.validator_set,
+                    b.signed_header.commit.block_id,
+                    b.signed_header.header.height,
+                    b.signed_header.commit,
+                )
+            )
+            prev = b.signed_header
     try:
         verify_commit_light_bulk(chain_id, rows)
     except Exception as e:
-        raise InvalidHeaderError(str(e)) from e
+        err = InvalidHeaderError(str(e))
+        err.hop = getattr(e, "row", None)
+        raise err from e
 
 
 def verify(

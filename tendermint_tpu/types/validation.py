@@ -33,6 +33,7 @@ checked by `scripts/lint.py --memo-audit` (docs/static_analysis.md).
 
 from __future__ import annotations
 
+import bisect
 from dataclasses import dataclass
 from typing import Callable, Optional
 
@@ -79,7 +80,15 @@ class Fraction:
 
 
 class InvalidCommitError(ValueError):
-    pass
+    """A commit failed verification. Where a bulk verification can say
+    which of its inputs failed, the error carries it: `position`, the
+    place in verify_triples_grouped's merged triple list; `row` and
+    `index`, the commit among verify_commit_light_bulk's rows and the
+    vote in it. None where the failure is not attributed."""
+
+    position: Optional[int] = None
+    row: Optional[int] = None
+    index: Optional[int] = None
 
 
 class NotEnoughVotingPowerError(InvalidCommitError):
@@ -190,49 +199,58 @@ def collect_commit_light(
     block_id: BlockID,
     height: int,
     commit: Commit,
-) -> list:
+) -> tuple:
     """verify_commit_light's host-side half: run every non-signature
     check (set size, height, block ID, 2/3 tally with the same
-    early-exit) and return the (pub_key, sign_bytes, signature)
-    triples verify_commit_light would have signature-checked — without
-    checking them. Callers fold triples from MANY commits into one
-    device batch (the light client's sequential group sync,
-    light/client.py); any triple failing there must be re-verified
-    per-commit for the reference's exact error. Mirrors the tally
-    semantics of types/validation.go:55-85."""
+    early-exit) and return (triples, indexes): the (pub_key,
+    sign_bytes, signature) triples verify_commit_light would have
+    signature-checked — without checking them — and the commit index
+    of each triple's vote. Callers fold triples from MANY commits into
+    one device batch (verify_commit_light_bulk) and name a failing
+    triple's vote from its index. Mirrors the tally semantics of
+    types/validation.go:55-85."""
     _verify_basic(vals, commit, height, block_id)
     voting_power_needed = vals.total_voting_power() * 2 // 3
     flags = commit.block_id_flags_array()
     if flags is not None:
         # prefix-sum form of the early-exit tally (the same
-        # _prefix_crossing plan as the vectorized verify_commit_light):
-        # the crossing index is the exact vote the reference loop below
-        # returns after, so the collected triples are identical — and
-        # the per-index encodes hit the commit-scoped sign-bytes memo
-        powers = vals.powers_array()
-        tallied, end = _prefix_crossing(
-            np.where(flags == BLOCK_ID_FLAG_COMMIT, powers, 0),
-            voting_power_needed,
-        )
-        if end is None:
-            raise NotEnoughVotingPowerError(tallied, voting_power_needed)
-        validators = vals.validators
-        signatures = commit.signatures
-        return [
-            (
-                validators[i].pub_key,
-                commit.vote_sign_bytes(chain_id, i),
-                signatures[i].signature,
+        # _prefix_crossing plan as the vectorized verify_commit_light,
+        # under the same two spans): the crossing index is the exact
+        # vote the reference loop below returns after, so the collected
+        # triples are identical — and the per-index encodes hit the
+        # commit-scoped sign-bytes memo
+        with trace.span("commit_plan") as plan:
+            powers = vals.powers_array()
+            tallied, end = _prefix_crossing(
+                np.where(flags == BLOCK_ID_FLAG_COMMIT, powers, 0),
+                voting_power_needed,
             )
-            for i in np.flatnonzero(
+            if end is None:
+                raise NotEnoughVotingPowerError(
+                    tallied, voting_power_needed
+                )
+            indexes = np.flatnonzero(
                 flags[:end] == BLOCK_ID_FLAG_COMMIT
             ).tolist()
-        ]
+            plan.set(processed=len(indexes))
+        validators = vals.validators
+        signatures = commit.signatures
+        with trace.span("sign_bytes", rows=len(indexes)):
+            vsb = commit.vote_sign_bytes
+            return [
+                (
+                    validators[i].pub_key,
+                    vsb(chain_id, i),
+                    signatures[i].signature,
+                )
+                for i in indexes
+            ], indexes
     # scalar reference loop (kept for hostile flag encodings); lazy
     # per-index encode: this early-exit variant skips nil votes and
     # stops at 2/3, so a full precompute would pay for rows it discards
     tallied = 0
     out = []
+    indexes = []
     for idx, commit_sig in enumerate(commit.signatures):
         if not commit_sig.is_for_block():
             continue
@@ -245,9 +263,10 @@ def collect_commit_light(
                 commit_sig.signature,
             )
         )
+        indexes.append(idx)
         tallied += val.voting_power
         if tallied > voting_power_needed:
-            return out
+            return out, indexes
     raise NotEnoughVotingPowerError(tallied, voting_power_needed)
 
 
@@ -257,11 +276,17 @@ def verify_triples_grouped(triples) -> None:
     per key type — the same grouping _verify_commit_batch applies
     within one commit. Triples already proven by the verified-signature
     cache (crypto.sigcache) are skipped before assembly; the rest
-    populate it on success, so the per-commit re-verify after a merged
-    failure only pays for the actually-bad commit. Raises
-    InvalidCommitError on any failure with no index attribution:
-    callers re-verify per commit for the precise error
-    (light/client.py sequential window fallback)."""
+    populate it as far as they are proven. On a false bit raises an
+    InvalidCommitError whose `position` is the LOWEST failing place in
+    `triples` over every key class, which the caller maps back to its
+    commit and vote (verify_commit_light_bulk). Every place below it
+    is then proven: a cache hit or a true bit. The one failure without
+    a position is a key type no batch verifier is registered for (an
+    embedder's; every type in the tree has one): it is checked inline
+    while routing and raises before the batched classes have answered,
+    so a lower place may still be bad; callers then re-verify per
+    commit for the precise error (light/client.py's per-hop
+    fallback)."""
     with trace.span(
         "batch_accumulate", sigs=len(triples), merged=True
     ):
@@ -316,9 +341,13 @@ def verify_triples_grouped(triples) -> None:
         if use_cache:
             sigcache.observe(hits, misses)
             trace.add_attrs(sigcache_hits=hits, sigcache_misses=misses)
-        verdicts = drain_classes(pending)
-        if not all(ok for ok, _bits in verdicts.values()):
-            raise InvalidCommitError("wrong signature in merged batch")
+        lowest = _drain_lowest_bad(pending)
+        if lowest is not None:
+            err = InvalidCommitError(
+                f"wrong signature in merged batch (#{lowest})"
+            )
+            err.position = lowest
+            raise err
 
 
 def verify_commit_light_bulk(chain_id: str, rows) -> None:
@@ -336,44 +365,75 @@ def verify_commit_light_bulk(chain_id: str, rows) -> None:
     collected triples from ALL cold commits are proven in ONE merged
     call (verify_triples_grouped: one bulk sigcache set-intersection,
     one grouped batch verify); only then is each cold commit's memo
-    recorded. A signature failure raises InvalidCommitError with no
-    index attribution — callers needing the reference's exact
-    per-commit error re-verify per commit (the same contract as
-    verify_triples_grouped, used by light/client.py's window
+    recorded. A wrong signature raises the error verify_commit_light
+    raises for its commit — the same class and text, `wrong signature
+    (#<idx>): <signature hex>` of the lowest bad vote of the FIRST bad
+    row — with `row` and `index` on the exception: every row before it
+    is then proven and its memo recorded, no other's. A failure the
+    merged check cannot place (verify_triples_grouped's inline key
+    types) propagates without them, and callers needing the exact
+    per-commit error re-verify per commit (light/client.py's per-hop
     fallback)."""
     rows = list(rows)
     with trace.span("verify_commit_light_bulk", commits=len(rows)):
         use_memo = sigcache.enabled() and sigcache.commit_memo_enabled()
         triples: list = []
-        cold_keys: list = []
+        # a cold row: (row number, its first place in `triples`, the
+        # commit index of each of its triples, its memo key)
+        cold: list = []
         hits = 0
-        for vals, block_id, height, commit in rows:
+        for n, (vals, block_id, height, commit) in enumerate(rows):
             _verify_basic(vals, commit, height, block_id)
             ckey = None
             if use_memo:
-                needed = vals.total_voting_power() * 2 // 3
-                ckey = _commit_memo_key(
-                    chain_id, vals, commit, needed, False, True,
-                    vals.powers_array(),
-                )
-                if sigcache.seen_commit(ckey):
+                with trace.span("commit_plan") as plan:
+                    needed = vals.total_voting_power() * 2 // 3
+                    ckey = _commit_memo_key(
+                        chain_id, vals, commit, needed, False, True,
+                        vals.powers_array(),
+                    )
+                    memo_hit = sigcache.seen_commit(ckey)
+                    plan.set(memo_hit=memo_hit)
+                if memo_hit:
                     hits += 1
                     continue
-            triples.extend(
-                collect_commit_light(
-                    chain_id, vals, block_id, height, commit
-                )
+            found, indexes = collect_commit_light(
+                chain_id, vals, block_id, height, commit
             )
-            if ckey is not None:
-                cold_keys.append(ckey)
+            cold.append((n, len(triples), indexes, ckey))
+            triples.extend(found)
         if use_memo:
             trace.add_attrs(
-                sigcache_commit_hits=hits, commits_cold=len(cold_keys)
+                sigcache_commit_hits=hits, commits_cold=len(cold)
             )
+        proven = len(cold)
+        failure: Optional[InvalidCommitError] = None
         if triples:
-            verify_triples_grouped(triples)
-        for ckey in cold_keys:
-            sigcache.add_commit(ckey)
+            try:
+                verify_triples_grouped(triples)
+            except InvalidCommitError as e:
+                if e.position is None:
+                    raise
+                # the cold row that holds the failing place: the last
+                # one that starts at or below it
+                proven = (
+                    bisect.bisect_right(
+                        [start for _n, start, _i, _k in cold], e.position
+                    )
+                    - 1
+                )
+                n, start, indexes, _ckey = cold[proven]
+                idx = indexes[e.position - start]
+                signature = rows[n][3].signatures[idx].signature
+                failure = InvalidCommitError(
+                    f"wrong signature (#{idx}): {signature.hex()}"
+                )
+                failure.row, failure.index = n, idx
+        for _n, _start, _indexes, ckey in cold[:proven]:
+            if ckey is not None:
+                sigcache.add_commit(ckey)
+        if failure is not None:
+            raise failure
 
 
 def _verify_basic(
@@ -895,27 +955,35 @@ def _verify_commit_batch_scalar(
     _drain_pending(commit, pending)
 
 
-def _drain_pending(commit: Commit, pending: dict) -> None:
+def _drain_lowest_bad(pending: dict) -> Optional[int]:
     """Drain the per-key-type miss batches (crypto.batch.drain_classes:
     every class launched before any is gathered), populating the cache
-    for proven triples, and raise the reference error for the LOWEST
-    bad commit index across groups."""
-    first_bad: Optional[int] = None
+    for proven triples, and return the LOWEST index (an item's fourth
+    field) whose signature failed, over every class; None when all
+    verified. A class's items are in index order, so its first bad one
+    is its lowest."""
+    lowest: Optional[int] = None
     for key_type, (ok, valid_sigs) in drain_classes(pending).items():
         if ok:
             continue
-        items = pending[key_type]
         bad = [
-            items[i][3]
-            for i, sig_ok in enumerate(valid_sigs)
+            item[3]
+            for item, sig_ok in zip(pending[key_type], valid_sigs)
             if not sig_ok
         ]
         if not bad:
             raise RuntimeError(
                 "BUG: batch verification failed with no invalid signatures"
             )
-        if first_bad is None or bad[0] < first_bad:
-            first_bad = bad[0]
+        if lowest is None or bad[0] < lowest:
+            lowest = bad[0]
+    return lowest
+
+
+def _drain_pending(commit: Commit, pending: dict) -> None:
+    """Drain one commit's miss batches and raise the reference error
+    for the LOWEST bad commit index across groups."""
+    first_bad = _drain_lowest_bad(pending)
     if first_bad is not None:
         raise InvalidCommitError(
             f"wrong signature (#{first_bad}): "

@@ -105,7 +105,7 @@ def test_the_manifest_has_the_cell_its_configuration_and_its_readers():
         assert m["layer"] == "mesh (parallel/sharding.py)" and m["moves"] == "commits_per_s"
         assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", m["name"] + ".py"))
     four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
-    assert four == 1 <= max(1, len(MANIFEST["workloads"]) // 2)
+    assert 1 <= four <= max(1, len(MANIFEST["workloads"]) // 2)
 
 
 def test_the_cell_rehearses_correct_over_four_devices(tiny):

@@ -504,11 +504,15 @@ def test_property_collect_commit_light_matches_scalar(seed):
         ctx = scalar_reference() if scalar else contextlib.nullcontext()
         with ctx:
             try:
-                triples = collect_commit_light(
+                triples, indexes = collect_commit_light(
                     CHAIN_ID, vals, bid, 1, commit
                 )
+                assert [
+                    commit.signatures[i].signature for i in indexes
+                ] == [sig for _pk, _sb, sig in triples]
                 return [
-                    (pk.bytes(), sb, sig) for pk, sb, sig in triples
+                    (pk.bytes(), sb, sig, i)
+                    for (pk, sb, sig), i in zip(triples, indexes)
                 ], None
             except Exception as e:  # noqa: BLE001
                 return None, f"{type(e).__name__}: {e}"
