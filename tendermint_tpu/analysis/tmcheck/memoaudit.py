@@ -155,6 +155,13 @@ CATALOG: List[MemoEntry] = [
         "re-assignment",
     ),
     MemoEntry(
+        "types/validator.py", "ValidatorSet.key_classes", "consensus",
+        "each validator's key type as a code, and the PubKey objects, "
+        "for routing a commit's cache misses to their batch verifiers; "
+        "cleared by _reindex and by the _VAL_MUT_EPOCH hook on in-place "
+        "pub_key re-assignment, beside pubkeys_bytes",
+    ),
+    MemoEntry(
         "types/validator.py", "ValidatorSet.powers_array", "consensus",
         "voting powers for the vectorized tallies; cleared by _reindex "
         "and by the _VAL_MUT_EPOCH hook on in-place voting_power "

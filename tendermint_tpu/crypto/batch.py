@@ -11,12 +11,13 @@ default, exactly like the reference keeps pure-Go as the default.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional, Sequence
 
 from ..libs import heap, trace
 from .keys import BatchVerifier, PubKey
 
 __all__ = [
+    "Columns",
     "create_batch_verifier",
     "cpu_factory",
     "drain_and_cache",
@@ -202,29 +203,64 @@ def drain_and_cache(verifier: BatchVerifier, cache_keys) -> tuple:
     return ok, bits
 
 
+class Columns(NamedTuple):
+    """One key class's cache misses awaiting batch verification, as
+    parallel columns: row i is the triple (pub_keys[i], messages[i],
+    signatures[i]) with key_bytes[i] == pub_keys[i].bytes(), its place
+    in the caller's own numbering (a commit index, a place in a merged
+    triple list) and its verified-signature cache key, None where the
+    cache was off at assembly. Rows are in ascending `positions`. A
+    producer with the misses in hand builds the columns whole (slices
+    of what its plan and its cache probe already hold); one that walks
+    vote by vote starts from new() and append()s."""
+
+    pub_keys: Sequence
+    key_bytes: Sequence
+    messages: Sequence
+    signatures: Sequence
+    positions: Sequence
+    cache_keys: Sequence
+
+    @classmethod
+    def new(cls) -> "Columns":
+        return cls([], [], [], [], [], [])
+
+    def append(
+        self, pub_key, key_bytes, message, signature, position, cache_key
+    ) -> None:
+        self.pub_keys.append(pub_key)
+        self.key_bytes.append(key_bytes)
+        self.messages.append(message)
+        self.signatures.append(signature)
+        self.positions.append(position)
+        self.cache_keys.append(cache_key)
+
+
 def drain_classes(pending: dict) -> dict:
     """Drain the per-key-class miss batches of one verification in two
     phases, so that every class's device work is in flight before the
-    host blocks on any of it. `pending` maps a key type to its items:
-    (pub_key, sign_bytes, signature, index, cache key) tuples.
+    host blocks on any of it. `pending` maps a key type to its Columns.
 
-    Phase 1, a class at a time: add() its triples to its verifier (a
-    device verifier streams full chunks from inside add()) and launch()
-    its remainder. A class whose launches cost the host byte rows alone
-    goes before one that makes an operand on the host (`host_operand`:
-    sr25519's merlin), so that the device starts soonest and the
-    costlier host work runs under device time; among equals, in
-    `pending`'s order. Phase 2, in the same order: drain_and_cache()
-    each, so a class's cache is populated under the next one's tiles.
-    Every class is verified whatever an earlier one answered, and the
-    heap settles once, after the last gather. Returns key type ->
-    (all_ok, bitmap aligned with the items).
+    Phase 1, a class at a time: one add_many() hands its columns to its
+    verifier (a device verifier streams full chunks from inside it) and
+    launch() sends its remainder. A class whose launches cost the host
+    byte rows alone goes before one that makes an operand on the host
+    (`host_operand`: sr25519's merlin), so that the device starts
+    soonest and the costlier host work runs under device time; among
+    equals, in `pending`'s order. Phase 2, in the same order:
+    drain_and_cache() each, so a class's cache is populated under the
+    next one's tiles. Every class is verified whatever an earlier one
+    answered, and the heap settles once, after the last gather. Returns
+    key type -> (all_ok, bitmap aligned with the columns).
 
     One `batch_drain` span a call: `classes`, the verifiers drained,
     and `overlapped`, those whose every launch was enqueued before the
-    first gather began. If anything raises, what was launched is
-    abandoned and nothing of it reaches the cache. With nothing
-    pending (every triple a cache hit) there is no drain and no span."""
+    first gather began. One `batch_add` span a class: `sigs`, its rows,
+    and `bulk`, those of them that entered through a verifier's own
+    add_many() (0 for one that inherits the loop over add()). If
+    anything raises, what was launched is abandoned and nothing of it
+    reaches the cache. With nothing pending (every triple a cache hit)
+    there is no drain and no span."""
     if not pending:
         return {}
     with trace.span(
@@ -233,27 +269,42 @@ def drain_classes(pending: dict) -> dict:
         batches = [
             (
                 key_type,
-                items,
-                create_batch_verifier(items[0][0], size_hint=len(items)),
+                cols,
+                create_batch_verifier(
+                    cols.pub_keys[0], size_hint=len(cols.positions)
+                ),
             )
-            for key_type, items in pending.items()
+            for key_type, cols in pending.items()
         ]
         batches.sort(key=lambda batch: batch[2].host_operand)
         try:
             overlapped = 0
-            for key_type, items, bv in batches:
-                with trace.span("batch_add", key=key_type, sigs=len(items)):
-                    for pub_key, sb, sig, _idx, _ckey in items:
-                        bv.add(pub_key, sb, sig)
+            for key_type, cols, bv in batches:
+                sigs = len(cols.positions)
+                takes_columns = (
+                    type(bv).add_many is not BatchVerifier.add_many
+                )
+                with trace.span(
+                    "batch_add",
+                    key=key_type,
+                    sigs=sigs,
+                    bulk=sigs if takes_columns else 0,
+                ):
+                    bv.add_many(
+                        cols.pub_keys,
+                        cols.messages,
+                        cols.signatures,
+                        cols.key_bytes,
+                    )
                     if bv.launch():
                         overlapped += 1
             span.set(overlapped=overlapped)
             return {
-                key_type: drain_and_cache(bv, [it[4] for it in items])
-                for key_type, items, bv in batches
+                key_type: drain_and_cache(bv, cols.cache_keys)
+                for key_type, cols, bv in batches
             }
         except BaseException:
-            for _key_type, _items, bv in batches:
+            for _key_type, _cols, bv in batches:
                 bv.abandon()
             raise
 

@@ -103,6 +103,22 @@ class BatchVerifier(ABC):
     @abstractmethod
     def verify(self) -> Tuple[bool, List[bool]]: ...
 
+    def add_many(
+        self, pub_keys, messages, signatures, key_bytes=None
+    ) -> None:
+        """add() for whole columns: the triples
+        (pub_keys[i], messages[i], signatures[i]) in order, with add()'s
+        checks and add()'s effects. `key_bytes`, where the caller holds
+        it, is the column of pub_keys[i].bytes(); a verifier that
+        overrides this to take a batch without per-triple Python
+        (crypto/tpu_verifier.py) reads it and calls no method a key.
+        crypto.batch.drain_classes hands every class over through here;
+        add() stays for a caller with one triple."""
+        for pub_key, message, signature in zip(
+            pub_keys, messages, signatures
+        ):
+            self.add(pub_key, message, signature)
+
     # True when launching a window costs the host more than joining
     # byte rows (a device verifier whose kernel takes an operand made
     # on the host). crypto.batch.drain_classes launches such a class

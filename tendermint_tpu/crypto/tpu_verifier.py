@@ -373,7 +373,8 @@ class _TpuBatchVerifier(BatchVerifier):
     per-index rather than raising at verify time.
 
     On a TPU backend, full STREAM_CHUNK-sized slices are dispatched
-    asynchronously AS add() fills them, so the host-side assembly loop
+    asynchronously AS add() (or add_many(), a column at a time) fills
+    them, so the host-side assembly loop
     (sign-bytes, address lookups — ~2 us/sig in VerifyCommit) overlaps
     device compute instead of serializing in front of it; verify()
     dispatches the remainder and gathers every in-flight handle in add
@@ -544,6 +545,56 @@ class _TpuBatchVerifier(BatchVerifier):
             and self._stream_fault is None
         ):
             self._launch_window("tpu_stream_dispatch")
+
+    def add_many(
+        self, pub_keys, messages, signatures, key_bytes=None
+    ) -> None:
+        """add() a column at a time: the same checks, made over the
+        whole columns before anything is queued, and the same streaming
+        rule, the window filled by slices. A key's type is asked of one
+        key a Python class (PubKey.type() names the class's curve, not
+        the instance); with `key_bytes` no method is called a key."""
+        n = len(pub_keys)
+        if not (len(messages) == len(signatures) == n):
+            raise ValueError("columns of unequal length")
+        if key_bytes is None:
+            key_bytes = [pub_key.bytes() for pub_key in pub_keys]
+        elif len(key_bytes) != n:
+            raise ValueError("columns of unequal length")
+        for cls in set(map(type, pub_keys)):
+            pub_key = next(pk for pk in pub_keys if type(pk) is cls)
+            if pub_key.type() != self.KEY_TYPE:
+                raise TypeError(
+                    f"{type(self).__name__} requires {self.KEY_TYPE} keys"
+                )
+        if not set(map(len, signatures)) <= {64}:
+            raise ValueError("malformed signature size")
+        # add()'s bytes() of each, paid only where something is not
+        if not set(map(type, messages)) <= {bytes}:
+            messages = list(map(bytes, messages))
+        if not set(map(type, signatures)) <= {bytes}:
+            signatures = list(map(bytes, signatures))
+        self._all.extend(zip(pub_keys, messages, signatures))
+        chunk = self.STREAM_CHUNK
+        # asked only of a column that can fill a window, as add() asks
+        # only at a full one: the answer may start the backend
+        stream = len(self._pks) + n >= chunk and self._streaming()
+        at = 0
+        while at < n:
+            streaming = stream and self._stream_fault is None
+            # up to a full window; one triple at a time past a window
+            # the route would not take, each trying again as add() does
+            end = (
+                min(n, at + max(chunk - len(self._pks), 1))
+                if streaming
+                else n
+            )
+            self._pks.extend(key_bytes[at:end])
+            self._msgs.extend(messages[at:end])
+            self._sigs.extend(signatures[at:end])
+            at = end
+            if streaming and len(self._pks) >= chunk:
+                self._launch_window("tpu_stream_dispatch")
 
     def verify(self) -> Tuple[bool, List[bool]]:
         """Drains the queue: a verifier is a one-shot batch (matching

@@ -34,13 +34,15 @@ checked by `scripts/lint.py --memo-audit` (docs/static_analysis.md).
 from __future__ import annotations
 
 import bisect
+from collections import defaultdict
 from dataclasses import dataclass
+from operator import attrgetter, itemgetter
 from typing import Callable, Optional
 
 import numpy as np
 
 from ..crypto import sigcache
-from ..crypto.batch import drain_classes, supports_batch_verifier
+from ..crypto.batch import Columns, drain_classes, supports_batch_verifier
 from ..libs import trace
 from .block_id import BlockID
 from .commit import (
@@ -49,7 +51,7 @@ from .commit import (
     Commit,
     CommitSig,
 )
-from .validator import ValidatorSet
+from .validator import ValidatorSet, key_type_codes
 
 __all__ = [
     "BATCH_VERIFY_THRESHOLD",
@@ -290,57 +292,43 @@ def verify_triples_grouped(triples) -> None:
     with trace.span(
         "batch_accumulate", sigs=len(triples), merged=True
     ):
+        if not triples:
+            return
         use_cache = sigcache.enabled()
-        hits = misses = 0
-        # key type -> [(pk, sign_bytes, signature, position, cache key)]
-        # (crypto.batch.drain_classes' item shape): assembly
-        # is deferred so each group's size_hint is its OWN miss count —
-        # previously every group got size_hint=len(triples), so in mixed
-        # sets each device bucket padded to the merged total
-        pending: dict = {}
-        # one bulk set-intersection over the whole merged window
-        # replaces the per-triple generation probes (the light client's
-        # 32-hop sequential windows are ~5k triples)
-        keys: list = []
-        hit_set: set = set()
+        # the merged window as columns, a row a triple; assembly is
+        # deferred so each class's size_hint is its OWN miss count, not
+        # the merged total every device bucket would otherwise pad to
+        pub_keys, messages, signatures = zip(*triples)
+        key_bytes = keys = None
+        # the misses' places in `triples`, ascending; None: all of them
+        places = None
         if use_cache:
+            # one bulk set-intersection over the whole merged window
+            # replaces the per-triple generation probes (the light
+            # client's 32-hop sequential windows are ~5k triples)
             with trace.span("sigcache_probe") as probe:
-                keys = [
-                    sigcache.key_for(pk.bytes(), sb, sig)
-                    for pk, sb, sig in triples
-                ]
-                hit_set = sigcache.seen_keys_bulk(keys)
-                probe.set(
-                    hits=len(hit_set), misses=len(keys) - len(hit_set)
-                )
-        with trace.span("batch_route") as route:
-            inline = 0
-            for n, (pk, sb, sig) in enumerate(triples):
-                ckey = None
-                if use_cache:
-                    ckey = keys[n]
-                    if ckey in hit_set:
-                        hits += 1
-                        continue
-                    misses += 1
-                if not supports_batch_verifier(pk):
-                    inline += 1
-                    if not pk.verify_signature(sb, sig):
-                        if use_cache:  # keep the scanned hit/miss counts
-                            sigcache.observe(hits, misses)
-                        raise InvalidCommitError(
-                            "wrong signature in merged batch"
-                        )
-                    if ckey is not None:
-                        sigcache.add_key(ckey)
-                    continue
-                pending.setdefault(pk.type(), []).append(
-                    (pk, sb, sig, n, ckey)
-                )
-            route.set(inline=inline)
-        if use_cache:
+                key_bytes = [pk.bytes() for pk in pub_keys]
+                keys = list(zip(key_bytes, messages, signatures))
+                places, hits, misses = _cache_misses(keys)
+                probe.set(hits=hits, misses=misses)
             sigcache.observe(hits, misses)
             trace.add_attrs(sigcache_hits=hits, sigcache_misses=misses)
+        with trace.span("batch_route") as route:
+            if key_bytes is None:
+                key_bytes = [pk.bytes() for pk in pub_keys]
+                keys = [None] * len(triples)
+            pending, inline = _route_misses(
+                *key_type_codes(pub_keys),
+                Columns(
+                    pub_keys, key_bytes, messages, signatures,
+                    range(len(triples)), keys,
+                ),
+                places,
+                lambda _position, _signature: InvalidCommitError(
+                    "wrong signature in merged batch"
+                ),
+            )
+            route.set(inline=inline)
         lowest = _drain_lowest_bad(pending)
         if lowest is not None:
             err = InvalidCommitError(
@@ -635,18 +623,16 @@ def _verify_commit_batch_vector(
         # tally
         if count_all_signatures:
             tallied = int(powers[flags == BLOCK_ID_FLAG_COMMIT].sum())
-            idx_list = np.flatnonzero(
-                flags != BLOCK_ID_FLAG_ABSENT
-            ).tolist()
+            idx_arr = np.flatnonzero(flags != BLOCK_ID_FLAG_ABSENT)
         elif look_up_by_index:
             tallied, end = _prefix_crossing(
                 np.where(flags == BLOCK_ID_FLAG_COMMIT, powers, 0),
                 voting_power_needed,
             )
-            idx_list = np.flatnonzero(
+            idx_arr = np.flatnonzero(
                 (flags if end is None else flags[:end])
                 == BLOCK_ID_FLAG_COMMIT
-            ).tolist()
+            )
         else:
             fb = np.flatnonzero(flags == BLOCK_ID_FLAG_COMMIT)
             addr_index = vals._addr_index
@@ -662,7 +648,8 @@ def _verify_commit_batch_vector(
                 np.where(vi >= 0, powers[np.maximum(vi, 0)], 0),
                 voting_power_needed,
             )
-            idx_list = (fb if end is None else fb[:end]).tolist()
+            idx_arr = fb if end is None else fb[:end]
+        idx_list = idx_arr.tolist()
 
         # --- commit-level memo: a commit this process fully verified
         # before, in this mode, against this exact set composition and
@@ -684,88 +671,57 @@ def _verify_commit_batch_vector(
         trace.add_attrs(sigcache_commit_hit=True)
         return
 
-    # key type -> [(pub_key, sign_bytes, signature, commit idx, cache
-    # key)]: the cache misses awaiting batch verification
-    pending: dict[str, list] = {}
-    # key type -> supports_batch_verifier (cached: at 10k signatures the
-    # repeated registry lookup was a measurable slice of the scan)
-    batchable: dict[str, bool] = {}
-
     if look_up_by_index:
-        validators = vals.validators
+        # the processed votes as columns aligned with idx_list (whole
+        # lists where nobody is absent): sign-bytes, then key bytes and
+        # signatures, zipped into the cache keys where the cache is on
         with trace.span("sign_bytes", rows=len(idx_list)):
             if count_all_signatures:
-                rows = commit.sign_bytes_batch(chain_id)
+                # None exactly at the absent indexes, the complement of
+                # idx_list
+                sb_col = _take(commit.sign_bytes_batch(chain_id), idx_list)
             else:
                 # early-exit variant: encode only the processed prefix,
                 # in one pass and memoized — no discarded rows are paid
-                # for, and the lookups below are memo reads
-                rows = None
+                # for
                 vsb = commit.vote_sign_bytes
-                prefix_rows = [vsb(chain_id, i) for i in idx_list]
-        misses = idx_list
+                sb_col = [vsb(chain_id, i) for i in idx_list]
+        pkb_col = keys = None
+        # the misses' places in idx_list, ascending; None: all of them
+        places = None
         if use_cache:
             with trace.span("sigcache_probe") as probe:
-                pkb = vals.pubkeys_bytes()
-                if rows is not None:
-                    # rows is None exactly at absent indexes, i.e.
-                    # exactly the complement of idx_list — the zip form
-                    # skips three indexed lookups per signature vs
-                    # iterating idx_list
-                    keys = [
-                        (b, r, cs.signature)
-                        for b, r, cs in zip(pkb, rows, sigs)
-                        if r is not None
-                    ]
-                else:
-                    keys = [
-                        (pkb[i], r, sigs[i].signature)
-                        for i, r in zip(idx_list, prefix_rows)
-                    ]
-                hit_set = sigcache.seen_keys_bulk(keys)
-                hits_n = len(hit_set)
-                if hits_n == len(keys):
-                    misses = []
-                else:
-                    misses = [
-                        i
-                        for i, k in zip(idx_list, keys)
-                        if k not in hit_set
-                    ]
-                probe.set(hits=hits_n, misses=len(misses))
-            sigcache.observe(hits_n, len(misses))
+                pkb_col, sig_col = _key_and_signature_columns(
+                    vals, sigs, idx_list
+                )
+                keys = list(zip(pkb_col, sb_col, sig_col))
+                places, hits_n, misses_n = _cache_misses(keys)
+                probe.set(hits=hits_n, misses=misses_n)
+            sigcache.observe(hits_n, misses_n)
             trace.add_attrs(
-                sigcache_hits=hits_n, sigcache_misses=len(misses)
+                sigcache_hits=hits_n, sigcache_misses=misses_n
             )
         with trace.span("batch_route") as route:
-            inline = 0
-            for i in misses:
-                pub_key = validators[i].pub_key
-                sb = rows[i] if rows is not None else vsb(chain_id, i)
-                sig = sigs[i].signature
-                key_type = pub_key.type()
-                can_batch = batchable.get(key_type)
-                if can_batch is None:
-                    can_batch = batchable[key_type] = (
-                        supports_batch_verifier(pub_key)
-                    )
-                if not can_batch:
-                    inline += 1
-                    if not pub_key.verify_signature(sb, sig):
-                        raise InvalidCommitError(
-                            f"wrong signature (#{i}): {sig.hex()}"
-                        )
-                    if use_cache:
-                        sigcache.add_key((pub_key.bytes(), sb, sig))
-                else:
-                    pending.setdefault(key_type, []).append(
-                        (
-                            pub_key, sb, sig, i,
-                            (pub_key.bytes(), sb, sig)
-                            if use_cache
-                            else None,
-                        )
-                    )
+            if pkb_col is None:
+                pkb_col, sig_col = _key_and_signature_columns(
+                    vals, sigs, idx_list
+                )
+                keys = [None] * len(idx_list)
+            types, codes, pub_keys = vals.key_classes()
+            if len(idx_list) != len(pub_keys):
+                codes = codes[idx_arr]
+            pending, inline = _route_misses(
+                types,
+                codes,
+                Columns(
+                    _take(pub_keys, idx_list), pkb_col, sb_col, sig_col,
+                    idx_list, keys,
+                ),
+                places,
+                lambda i, sig: InvalidCommitError(
+                    f"wrong signature (#{i}): {sig.hex()}"
+                ),
+            )
             route.set(inline=inline)
     else:
         # trusting: per-index replay of the reference body over the
@@ -774,6 +730,11 @@ def _verify_commit_batch_vector(
         _seen_key = sigcache.seen_key
         hits_n = misses_n = 0
         seen_vals: dict[int, int] = {}
+        # key type -> Columns: the cache misses awaiting batch
+        # verification
+        pending: dict[str, Columns] = defaultdict(Columns.new)
+        # key type -> supports_batch_verifier
+        batchable: dict[str, bool] = {}
         with trace.span("batch_route") as route:
             inline = 0
             for idx in idx_list:
@@ -791,10 +752,11 @@ def _verify_commit_batch_vector(
                 seen_vals[val_idx] = idx
                 vote_sign_bytes = commit.vote_sign_bytes(chain_id, idx)
                 pub_key = val.pub_key
+                key_bytes = pub_key.bytes()
                 ckey = None
                 if use_cache:
                     ckey = (
-                        pub_key.bytes(), vote_sign_bytes, commit_sig.signature
+                        key_bytes, vote_sign_bytes, commit_sig.signature
                     )
                     if _seen_key(ckey):
                         hits_n += 1
@@ -820,11 +782,9 @@ def _verify_commit_batch_vector(
                     if ckey is not None:
                         sigcache.add_key(ckey)
                 else:
-                    pending.setdefault(key_type, []).append(
-                        (
-                            pub_key, vote_sign_bytes, commit_sig.signature,
-                            idx, ckey,
-                        )
+                    pending[key_type].append(
+                        pub_key, key_bytes, vote_sign_bytes,
+                        commit_sig.signature, idx, ckey,
                     )
             route.set(inline=inline)
         if use_cache:
@@ -867,9 +827,8 @@ def _verify_commit_batch_scalar(
     tallied = 0
     hits = misses = 0
     seen_vals: dict[int, int] = {}
-    # key type -> [(pub_key, sign_bytes, signature, commit idx, cache
-    # key)]: the cache misses awaiting batch verification
-    pending: dict[str, list] = {}
+    # key type -> Columns: the cache misses awaiting batch verification
+    pending: dict[str, Columns] = defaultdict(Columns.new)
     # key type -> supports_batch_verifier
     batchable: dict[str, bool] = {}
     # one templated pass for all sign-bytes when every signature will
@@ -902,13 +861,12 @@ def _verify_commit_batch_scalar(
             else commit.vote_sign_bytes(chain_id, idx)
         )
         pub_key = val.pub_key
+        key_bytes = pub_key.bytes()
         ckey = None
         if use_cache:
             # inline sigcache.key_for — the tuple IS the key, and the
             # call overhead is measurable at 10k signatures
-            ckey = (
-                pub_key.bytes(), vote_sign_bytes, commit_sig.signature
-            )
+            ckey = (key_bytes, vote_sign_bytes, commit_sig.signature)
             if _seen_key(ckey):
                 hits += 1
                 if count_sig(commit_sig):
@@ -940,8 +898,9 @@ def _verify_commit_batch_scalar(
             if ckey is not None:
                 sigcache.add_key(ckey)
         else:
-            pending.setdefault(key_type, []).append(
-                (pub_key, vote_sign_bytes, commit_sig.signature, idx, ckey)
+            pending[key_type].append(
+                pub_key, key_bytes, vote_sign_bytes,
+                commit_sig.signature, idx, ckey,
             )
         if count_sig(commit_sig):
             tallied += val.voting_power
@@ -955,28 +914,101 @@ def _verify_commit_batch_scalar(
     _drain_pending(commit, pending)
 
 
+def _cache_misses(keys) -> tuple:
+    """(places, hits, misses) of one bulk cache probe: the places in
+    `keys` of those the cache does not hold, ascending (None: all of
+    them, nothing hit), and the two counts."""
+    hit_set = sigcache.seen_keys_bulk(keys)
+    if not hit_set:
+        return None, 0, len(keys)
+    places = [j for j, key in enumerate(keys) if key not in hit_set]
+    return places, len(keys) - len(places), len(places)
+
+
+def _take(column, places):
+    """The rows of `column` at `places` (ascending and distinct), in
+    that order; the column itself where they are all of it."""
+    if len(places) == len(column):
+        return column
+    if not places:
+        return ()
+    if len(places) == 1:  # itemgetter with one place returns the row bare
+        return (column[places[0]],)
+    return itemgetter(*places)(column)
+
+
+def _key_and_signature_columns(vals: ValidatorSet, sigs, idx_list) -> tuple:
+    """(key bytes, signatures) of the votes at idx_list, as columns."""
+    return (
+        _take(vals.pubkeys_bytes(), idx_list),
+        list(map(attrgetter("signature"), _take(sigs, idx_list))),
+    )
+
+
+def _route_misses(types, codes, rows: Columns, places, wrong) -> tuple:
+    """Group the cache misses of one verification by key class, a
+    masked select a class and no Python a vote. `rows` holds every
+    processed vote as columns, `codes` (np.uint8, aligned with them)
+    the place of each vote's key type in `types`, `places` the misses'
+    places in the columns, ascending (None: every row is one). Returns
+    (pending, inline): key type -> the Columns of its misses, for
+    crypto.batch.drain_classes, in the order of each class's lowest
+    miss; and how many misses had a key type without a batch verifier.
+    Those are verified here, one at a time in ascending place over all
+    such types, each proven one recorded in the cache, and the first to
+    fail raises wrong(its position, its signature) before any batched
+    class is drained."""
+    if places is not None:
+        places = np.asarray(places, dtype=np.intp)
+        codes = codes[places]
+    classes = []
+    for code, key_type in enumerate(types):
+        at = np.flatnonzero(codes == code)
+        if at.size:
+            if places is not None:
+                at = places[at]
+            classes.append((int(at[0]), key_type, at))
+    classes.sort()
+    pending: dict[str, Columns] = {}
+    unbatched = []
+    for first, key_type, at in classes:
+        if supports_batch_verifier(rows.pub_keys[first]):
+            at = at.tolist()
+            pending[key_type] = Columns(*(_take(col, at) for col in rows))
+        else:
+            unbatched.append(at)
+    inline = 0
+    if unbatched:
+        for j in np.sort(np.concatenate(unbatched)).tolist():
+            inline += 1
+            signature = rows.signatures[j]
+            if not rows.pub_keys[j].verify_signature(
+                rows.messages[j], signature
+            ):
+                raise wrong(rows.positions[j], signature)
+            if rows.cache_keys[j] is not None:
+                sigcache.add_key(rows.cache_keys[j])
+    return pending, inline
+
+
 def _drain_lowest_bad(pending: dict) -> Optional[int]:
     """Drain the per-key-type miss batches (crypto.batch.drain_classes:
     every class launched before any is gathered), populating the cache
-    for proven triples, and return the LOWEST index (an item's fourth
-    field) whose signature failed, over every class; None when all
-    verified. A class's items are in index order, so its first bad one
-    is its lowest."""
+    for proven triples, and return the LOWEST position whose signature
+    failed, over every class; None when all verified. A class's rows
+    are in ascending position, so its first bad one is its lowest."""
     lowest: Optional[int] = None
     for key_type, (ok, valid_sigs) in drain_classes(pending).items():
         if ok:
             continue
-        bad = [
-            item[3]
-            for item, sig_ok in zip(pending[key_type], valid_sigs)
-            if not sig_ok
-        ]
-        if not bad:
+        try:
+            bad = pending[key_type].positions[valid_sigs.index(False)]
+        except ValueError:
             raise RuntimeError(
                 "BUG: batch verification failed with no invalid signatures"
-            )
-        if lowest is None or bad[0] < lowest:
-            lowest = bad[0]
+            ) from None
+        if lowest is None or bad < lowest:
+            lowest = bad
     return lowest
 
 

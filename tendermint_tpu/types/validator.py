@@ -58,6 +58,24 @@ _VAL_MUT_EPOCH = [object()]
 _EPOCH_FIELDS = frozenset({"voting_power", "pub_key", "address"})
 
 
+def key_type_codes(pub_keys) -> tuple:
+    """(key types, codes): the distinct PubKey.type() values of
+    `pub_keys` in order of first appearance, and for each key the place
+    of its type among them, as a read-only np.uint8 array: what batch
+    routing groups signatures by, one masked select a key class
+    (types/validation.py)."""
+    import numpy as np
+
+    types: Dict[str, int] = {}
+    codes = np.fromiter(
+        (types.setdefault(pk.type(), len(types)) for pk in pub_keys),
+        dtype=np.uint8,
+        count=len(pub_keys),
+    )
+    codes.setflags(write=False)
+    return tuple(types), codes
+
+
 @dataclass
 class Validator:
     pub_key: PubKey
@@ -152,6 +170,7 @@ class ValidatorSet:
         self._proto_memo: Optional[tuple] = None
         self._fp_token: Optional[object] = None
         self._pkb_memo: Optional[tuple] = None
+        self._key_classes_memo: Optional[tuple] = None
         self._powers_memo: Optional[tuple] = None
         valz = [v.copy() for v in validators] if validators else []
         self._update_with_change_set(valz, allow_deletes=False)
@@ -255,6 +274,26 @@ class ValidatorSet:
         self._pkb_memo = (epoch, pkb)
         return pkb
 
+    def key_classes(self) -> tuple:
+        """(key types, codes, pub_keys), aligned with self.validators:
+        the distinct PubKey.type() values in order of first appearance,
+        for each validator the place of its key's type among them (a
+        np.uint8 array, read-only), and the PubKey objects themselves.
+        What batch routing groups a commit's cache misses by
+        (types/validation.py): one masked select a key class, no
+        attribute read or method call a vote. Memoized beside
+        pubkeys_bytes() under the same validator-mutation epoch and
+        cleared by _reindex() like it, so an in-place pub_key
+        re-assignment can never route a vote to the old key's class."""
+        epoch = _VAL_MUT_EPOCH[0]
+        memo = self._key_classes_memo
+        if memo is not None and memo[0] is epoch:
+            return memo[1]
+        pub_keys = [v.pub_key for v in self.validators]
+        classes = key_type_codes(pub_keys) + (pub_keys,)
+        self._key_classes_memo = (epoch, classes)
+        return classes
+
     def total_voting_power(self) -> int:
         if self._total_voting_power == 0:
             self._update_total_voting_power()
@@ -270,6 +309,7 @@ class ValidatorSet:
         new._proto_memo = None
         new._fp_token = None  # copies diverge independently: own token
         new._pkb_memo = None
+        new._key_classes_memo = None
         new._powers_memo = None
         return new
 
@@ -281,6 +321,7 @@ class ValidatorSet:
         self._proto_memo = None
         self._fp_token = None
         self._pkb_memo = None
+        self._key_classes_memo = None
         self._powers_memo = None
 
     def _update_total_voting_power(self) -> None:

@@ -137,8 +137,10 @@ def test_the_manifest_has_the_cell_its_configuration_and_its_readers():
     assert len(config["chain_id"]) == 13 and len(config["guarantees"]) == 4
     assert traffic["driver"] == "light_sync" and traffic["window_hops"] == 32
     assert (traffic["ring_segments"], traffic["warmup_segments"], traffic["corrupt_every"]) == (16, 2, 32)
-    new = MANIFEST["per_layer"][-len(LIGHT_METRICS) :]
+    new = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in new] == list(LIGHT_METRICS)
+    at = MANIFEST["per_layer"].index(new[0])
+    assert MANIFEST["per_layer"][at : at + len(new)] == new  # added as one block
     for m in new:
         assert m["workloads"] == [CELL] and m["moves"] == "commits_per_s"
         assert m["layer"] == "light client (light/client.py, light/verifier.py)"
@@ -319,6 +321,8 @@ def test_a_traced_rehearsal_reports_the_light_clients_metrics(tiny):
         assert got[name]["value"] > 0, name
     assert got["sigcache_hit_share"]["value"] == 0 and got["window_compiles"]["value"] == 0
     assert got["drain_overlapped_classes"]["value"] == 1
+    # every merged window crossed the seam as columns
+    assert got["bulk_add_share"] == {"value": 100.0, "unit": "%"}
     # a tile and its SHA-512 a window, two windows a clean sync
     assert 3 < got["device_launches"]["value"] <= 4
     # 27 signatures in a 32-lane bucket

@@ -286,6 +286,8 @@ def test_a_traced_rehearsal_over_a_constructed_trace_reports_the_mesh_metrics(ti
     assert got["shard_place_host_ms"]["value"] > 0
     # one tile a class and one SHA-512, six host arrays among them
     assert got["device_launches"]["value"] == 3
+    # both key classes crossed the seam as columns
+    assert got["bulk_add_share"] == {"value": 100.0, "unit": "%"}
     for name in ("launch_host_ms", "gather_wait_ms", "sigverify_roofline", "verify_mfu",
                  "kernel_device_ms", "device_idle_share", "pad_waste_share", "window_compiles"):
         assert name in got, name

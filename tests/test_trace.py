@@ -355,7 +355,12 @@ def test_commit_verification_phase_tree():
         assert gather.attrs["handles"] == len(streamed) + 1
         assert gather.attrs["sigs"] == (14 if light else 20)
         (add,) = [s for s in tree if s.name == "batch_add"]
-        assert add.attrs == {"key": "ed25519", "sigs": gather.attrs["sigs"]}
+        # the device verifier took the class's columns whole
+        assert add.attrs == {
+            "key": "ed25519",
+            "sigs": gather.attrs["sigs"],
+            "bulk": gather.attrs["sigs"],
+        }
         assert full.attrs["sigcache_misses"] == gather.attrs["sigs"]
         # what the phases leave of batch_accumulate is its self time
         kids = [s for s in tree if s.parent_id == full.span_id]
