@@ -2,7 +2,10 @@
 chip could take for the signatures the traced requests VERIFIED (not
 the padded lanes) — chipbench/work.py's int32 multiply-adds over the
 measured int32 ceiling of peaks.json, or their bytes over HBM's rate,
-whichever is larger — over the trace's program time."""
+whichever is larger — over the trace's program time. That time is
+summed over the chips, so on a mesh chip-seconds of need stand over
+chip-seconds spent and the share needs no `devices` (verify_mfu's
+window is wall time, and does)."""
 
 
 def read(ctx):

@@ -113,14 +113,22 @@ def require_tpu(chips: int) -> dict:
     }
 
 
-def memory_peak_bytes() -> int:
+def _memory_peaks() -> list:
+    """Each chip's peak_bytes_in_use, in jax.devices()'s order."""
     import jax
 
-    peak = 0
-    for d in jax.devices():
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-    return peak
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in jax.devices()]
+
+
+def memory_peak_bytes() -> int:
+    """The peak on the fullest chip."""
+    return max(_memory_peaks())
+
+
+def memory_peak_device() -> int:
+    """Which chip that is: its index in jax.devices()."""
+    peaks = _memory_peaks()
+    return peaks.index(max(peaks))
 
 
 def install_device_path(config: dict) -> dict:
@@ -168,8 +176,8 @@ def wait_for_probe() -> None:
 # -- the program's own counters ---------------------------------------
 
 COUNTERS = (
-    "batches", "sigs", "faults", "pallas_fallbacks", "warm_misses",
-    "pad_waste", "cache_hits", "cache_misses", "memo_hits", "memo_misses",
+    "batches", "sigs", "faults", "warm_misses", "pad_waste",
+    "cache_hits", "cache_misses", "memo_hits", "memo_misses",
 )  # fmt: skip
 
 
@@ -179,9 +187,8 @@ def make_counter_reader():
     def read() -> tuple:
         t, s = tpu_verifier.stats(), sigcache.stats()
         return (
-            t["batches"], t["sigs"], t["faults"], t["pallas_fallbacks"],
-            t["warm_misses"], t["pad_waste"], s["hits"], s["misses"],
-            s["commit_hits"], s["commit_misses"],
+            t["batches"], t["sigs"], t["faults"], t["warm_misses"], t["pad_waste"],
+            s["hits"], s["misses"], s["commit_hits"], s["commit_misses"],
         )  # fmt: skip
 
     return read
@@ -268,9 +275,14 @@ def judge(driver, window: dict, installed: dict, log: CompileLog) -> dict:
     """Hold every request of the window to the reference's verdict and
     to device_accounting's conditions (chip_smoke.py): the program's
     counters moved by exactly what the request sent, no fault, no
-    Pallas swap, no first-touch bucket, no verified-signature or
-    commit-memo hit (the cell is cold by construction), no compile."""
+    first-touch bucket, no compile, and exactly the verified-signature
+    and commit-memo hits the driver's `hits(token)` says the deployment
+    produces: (0, 0) for a driver without one, whose cell is cold by
+    construction. A hit too many is a ring gone warm; a hit too few is
+    a cache that stopped working, a different result and not a slower
+    one."""
     n = len(window["tokens"])
+    hits = getattr(driver, "hits", lambda token: (0, 0))
     wrong, bypassed, compiled = [], [], []
     compile_ends = [t for t, _s in log.between(window["t_open"], window["ends"][-1])] if n else []
     expected = driver.expected(window["tokens"])
@@ -282,14 +294,14 @@ def judge(driver, window: dict, installed: dict, log: CompileLog) -> dict:
             zip(COUNTERS, (b - a for a, b in zip(window["counters"][i], window["counters"][i + 1])))
         )
         batches, sigs = driver.sent(token, installed["min_batch"], installed["chunk"])
+        cache_hits, memo_hits = hits(token)
         if (
             delta["batches"] != batches
             or delta["sigs"] != sigs
             or delta["faults"]
-            or delta["pallas_fallbacks"]
             or delta["warm_misses"]
-            or delta["cache_hits"]
-            or delta["memo_hits"]
+            or delta["cache_hits"] != cache_hits
+            or delta["memo_hits"] != memo_hits
         ):
             bypassed.append(i)
         if any(window["starts"][i] <= t <= window["ends"][i] for t in compile_ends):
@@ -431,6 +443,7 @@ def per_layer(cell, driver, window: dict, verdict: dict, tracer: Tracer,
     first, last = window["counters"][0], window["counters"][-1]
     t_last = window["ends"][-1] if n else window["t_open"]
     ctx = types.SimpleNamespace(
+        config=cell.config,
         driver=driver,
         requests=n,
         tokens=window["tokens"],
@@ -491,6 +504,7 @@ def run_cell(args, prepare=None) -> dict:
     setup_s = time.perf_counter() - _T_PROCESS
     window = run_window(driver, args.seconds, read_counters, tracer)
     device["memory_peak_bytes"] = memory_peak_bytes()
+    device["memory_peak_device"] = memory_peak_device()
 
     # the reference runs only now: the window has closed and the
     # device's peak is read, and none of its time is set-up

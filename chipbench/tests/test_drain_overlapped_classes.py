@@ -1,19 +1,16 @@
-"""`drain_overlapped_classes`: the reader on spans built by hand, and in
-a traced CPU rehearsal of each cell: 2 where the commit mixes two key
-classes, 1 where it has one, nothing on a program without the drain's
-span. Run by hand like the other files here; nothing is a speed.
+"""`drain_overlapped_classes`: the reader on spans built by hand, and
+its manifest entry. The traced rehearsal of each cell (2 where the
+commit mixes two key classes, 1 where it has one) is test_rehearse.py's.
+Nothing here is a speed.
 """
 
 from __future__ import annotations
 
 import os
 
-import pytest
-
-import run as harness
-from test_decode_native_share import lent_peaks  # noqa: F401  (a fixture)
-from test_program_spans import ctx_of, one_request, span
-from test_rehearse import CELLS, MANIFEST, _args, tiny  # noqa: F401  (a fixture)
+from chipbench import run as harness
+from chipbench.tests.rehearsal import metric
+from chipbench.tests.test_program_spans import ctx_of, one_request, span
 
 READ = harness.load_module("layer_metrics", "drain_overlapped_classes").read
 
@@ -36,23 +33,10 @@ def test_reader_on_spans_built_by_hand():
     assert READ(ctx_of([], requests=0)) is None
 
 
-def test_the_metric_is_the_manifests_last_entry_and_has_a_reader():
-    entry = MANIFEST["per_layer"][-1]
-    assert entry == {
+def test_the_metric_is_in_the_manifest_and_has_a_reader():
+    assert metric("drain_overlapped_classes") == {
         "name": "drain_overlapped_classes", "unit": "count", "better": "higher",
         "source": "program_span", "layer": "commit verification (types/validation.py)",
         "moves": "commits_per_s",
     }  # fmt: skip
-    assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", entry["name"] + ".py"))
-
-
-@pytest.mark.parametrize("cell", CELLS)
-def test_a_traced_rehearsal_reads_the_cells_key_classes(tiny, lent_peaks, cell):
-    result = harness.run_cell(_args(cell, trace=1))
-    assert result["correct"], result["checks"]
-    assert result["failed"] == 0
-    classes = len(harness.load_cell(cell).config["key_classes"])
-    assert classes == (1 if cell == "commit-150.catchup" else 2)
-    assert result["metrics"]["drain_overlapped_classes"] == {
-        "value": float(classes), "unit": "count"
-    }
+    assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", "drain_overlapped_classes.py"))

@@ -1,10 +1,10 @@
-"""`light-150.sync` rehearsed by hand, beside the other cells'
-rehearsals (`pytest chipbench/tests`): the cell's own tiny sizes, its
-fixtures and its cases are those of the repo's tier-1 file,
-tests/test_light_sync_cell.py, collected here under this directory's
-conftest (one CPU device). test_rehearse.py's `TINY` is keyed by driver
-and has no entry for `light_sync`, so its cases for this cell fail on
-the lookup: PERF.md, Open questions.
+"""`light-150.sync`'s own cases beside the rehearsals every cell gets in
+test_rehearse.py: the generator's hashes and wire bytes against the
+program's, the reference and the program on clean and corrupted syncs,
+the light client's readers on a sync built by hand. They are those of
+the repo's tier-1 file, tests/test_light_sync_cell.py, collected here
+with that file's own fixtures (its `tiny` stands in for conftest.py's in
+this module).
 """
 
 from tests.test_light_sync_cell import (  # noqa: F401 - collected from here

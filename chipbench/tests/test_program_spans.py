@@ -1,8 +1,8 @@
 """The readers of the program's own span tree (chipbench/span_tree.py,
 the span-read metrics under layer_metrics/, chipbench/stage_time.py):
-on a span list built by hand, on the recorded trace, and in the CPU
-rehearsal of each cell. Run by hand like test_rehearse.py; nothing here
-is a speed.
+on a span list built by hand and on the recorded trace. The traced
+rehearsal of each cell that reads them end to end is test_rehearse.py's.
+Nothing here is a speed.
 """
 
 from __future__ import annotations
@@ -12,9 +12,9 @@ import types
 
 import pytest
 
-import run as harness
+from chipbench import run as harness
 from chipbench import span_tree, stage_time
-from test_rehearse import MANIFEST, _args, tiny  # noqa: F401  (a fixture)
+from chipbench.tests.rehearsal import CELLS, metric
 
 
 def span(sid, name, start, dur, parent=0, root=None, **attrs):
@@ -120,15 +120,14 @@ def test_each_reader_on_the_hand_built_request(metric):
     assert read(ctx_of([], requests=0)) is None
 
 
-def test_the_new_metrics_are_the_manifests_last_entries_and_have_readers():
-    names = [m["name"] for m in MANIFEST["per_layer"]]
-    new = names[12:]
-    assert set(EXPECTED) | {"ladder_device_ms", "decode_points_device_ms"} == set(new)
-    for m in MANIFEST["per_layer"][12:]:
-        assert m["moves"] == "commits_per_s"
-        assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", m["name"] + ".py"))
-    streamed = {m["name"] for m in MANIFEST["per_layer"] if "workloads" in m}
-    assert streamed == {"stream_dispatch_host_ms", "merlin_host_ms"}
+def test_the_span_read_metrics_are_in_the_manifest_and_have_readers():
+    for name in sorted(set(EXPECTED) | {"ladder_device_ms", "decode_points_device_ms"}):
+        assert metric(name)["moves"] == "commits_per_s"
+        assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", name + ".py"))
+    # the streamed chunks' span opens wherever a batch reaches 2,048: not in cell 1's 101
+    assert metric("stream_dispatch_host_ms")["workloads"] == CELLS[1:4]
+    # merlin runs in both 10k cells, and is listed in the one-chip one alone: PERF.md section 7
+    assert metric("merlin_host_ms")["workloads"] == CELLS[1:2]
 
 
 def test_stage_reader_on_the_recorded_trace_finds_no_scope_and_says_so():
@@ -204,42 +203,3 @@ def test_stage_reader_on_a_trace_built_by_hand(tmp_path):
     got = stage_time.stage_seconds(str(path))
     assert got["requests"] == 1 and got["ops"] == 5 and got["staged_ops"] == 4
     assert got["stages"] == pytest.approx({"dual_mult": 5e-6, "decode_points": 1e-6})
-
-
-SPAN_READ = sorted(set(EXPECTED) - {"stream_dispatch_host_ms", "merlin_host_ms"})
-
-
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_the_rehearsal_of_each_cell_reports_the_span_read_metrics(tiny, cell, monkeypatch):  # noqa: F811
-    """On the CPU backend every metric that reads the program's spans
-    and needs no device is in the traced line of each cell; the two that
-    exist only in cell 2 only there, where the rehearsal streams as the
-    chip does, in chunks of 8 (a configured bucket; cell 1's 101
-    signatures stream nothing on the chip either); the two of the device
-    trace in neither."""
-    from tendermint_tpu.crypto import tpu_verifier
-
-    mixed = cell == "commit-10k-mixed.cold"
-    if mixed:
-        seam = tpu_verifier._TpuBatchVerifier
-        monkeypatch.setattr(seam, "_streaming", staticmethod(lambda: True))
-        monkeypatch.setattr(seam, "STREAM_CHUNK", 8)
-    peaks = harness.load_json(os.path.join(harness.HERE, "peaks.json"))
-    real = harness.load_json
-    monkeypatch.setattr(
-        harness, "load_json",
-        lambda p: {"rehearsal": peaks["TPU v5 lite"]} if p.endswith("peaks.json") else real(p),
-    )
-    result = harness.run_cell(_args(cell, trace=1))
-    assert result["correct"], result["checks"]
-    got = result["metrics"]
-    for name in SPAN_READ:
-        assert name in got, name
-    assert ("merlin_host_ms" in got) == mixed
-    assert ("stream_dispatch_host_ms" in got) == mixed
-    assert "ladder_device_ms" not in got and "decode_points_device_ms" not in got
-    # 8 + 8 of 16 validators: a streamed chunk a class, a tile each and
-    # one SHA-512; 9 of 13 on the light path: a tile and its SHA-512
-    assert got["device_launches"]["value"] == (3 if mixed else 2)
-    assert got["span_coverage_share"]["value"] > 90
-    assert got["gather_wait_ms"]["value"] > 0

@@ -24,9 +24,11 @@ those on the same line and name the idle gaps more closely.
               over the device planes that ran anything
   program_s   summed "XLA Modules" durations inside the window (all
               chips): the device time of the programs
-  idle_gaps   the first device's idle intervals inside the window,
-              split by what the host's request thread was doing (the
-              innermost event open there), summed a name
+  idle_gaps   the idle intervals inside the window of every device
+              plane that ran anything, split by what the host's request
+              thread was doing (the innermost event open there), summed
+              a name and averaged over those planes, as busy_s is: they
+              add up to window_s - busy_s
   device_ops  summed "XLA Ops" time an operation, longest first, named
               `<program>:<operation>` by the "XLA Modules" event it ran
               inside (an operation inside a loop is counted in the loop's
@@ -99,6 +101,27 @@ def innermost_segments(events) -> list:
         emit(stack[-1][0])
         stack.pop()
     return out
+
+
+def name_gaps(busy, segments, lo, hi, idle: dict) -> None:
+    """Add to `idle`, a name, the parts of [lo, hi] that one device's
+    merged `busy` leaves uncovered, each split by the segment of
+    `segments` (sorted, no overlap) open at the time."""
+    i = 0
+    for g_lo, g_hi in gaps(busy, lo, hi):
+        covered = 0
+        while i < len(segments) and segments[i][1] <= g_lo:
+            i += 1
+        j = i
+        while j < len(segments) and segments[j][0] < g_hi:
+            s, e, name = segments[j]
+            part = min(e, g_hi) - max(s, g_lo)
+            if part > 0:
+                idle[name] = idle.get(name, 0) + part
+                covered += part
+            j += 1
+        if g_hi - g_lo > covered:
+            idle[BETWEEN] = idle.get(BETWEEN, 0) + (g_hi - g_lo - covered)
 
 
 def short_op(name: str) -> str:
@@ -185,22 +208,9 @@ def reduce(profile) -> dict:
     ]
     segments = innermost_segments(annotations)
     idle: dict = {}
-    i = 0
-    for g_lo, g_hi in gaps(busy_by_device[0], lo, hi):
-        covered = 0
-        while i < len(segments) and segments[i][1] <= g_lo:
-            i += 1
-        j = i
-        while j < len(segments) and segments[j][0] < g_hi:
-            s, e, name = segments[j]
-            part = min(e, g_hi) - max(s, g_lo)
-            if part > 0:
-                idle[name] = idle.get(name, 0) + part
-                covered += part
-            j += 1
-        if g_hi - g_lo > covered:
-            idle[BETWEEN] = idle.get(BETWEEN, 0) + (g_hi - g_lo - covered)
+    for busy in busy_by_device:
+        name_gaps(busy, segments, lo, hi, idle)
     out["idle_gaps"] = [
-        [k, v / 1e9] for k, v in sorted(idle.items(), key=lambda kv: -kv[1])
+        [k, v / len(devices) / 1e9] for k, v in sorted(idle.items(), key=lambda kv: -kv[1])
     ]
     return out
