@@ -4,6 +4,14 @@ reference: internal/blocksync/pool.go (:98-348). Per-height requester
 tasks fan out over peers advertising the height; blocks come back out in
 strict height order via peek_two_blocks so the reactor can verify block
 H with the LastCommit carried in block H+1.
+
+Nothing here polls. The consumer (the reactor's pool routine) sleeps in
+wait_changed() until a block arrives or the peers' range moves; the
+requester-maker until a height is consumed or a peer reports a higher
+one; a requester until its height's event says a block arrived, was
+refused (redo_request) or can no longer come (its peer went away), or
+its fetch timed out. The only timers left are that timeout, the
+caught-up grace and the pause after every peer has been tried.
 """
 
 from __future__ import annotations
@@ -46,7 +54,14 @@ class BlockPool(Service):
         self.max_peer_height = 0
         self._blocks: Dict[int, Tuple[Block, str]] = {}  # height → (block, peer)
         self._requesters: Dict[int, asyncio.Task] = {}
+        # height -> "something about this height changed": its block
+        # arrived, was dropped again, or the peer asked for it is gone
         self._block_events: Dict[int, asyncio.Event] = {}
+        self._asked: Dict[int, str] = {}  # height -> peer of the open fetch
+        # what the consumer reads changed (blocks, peers' range)
+        self._changed = asyncio.Event()
+        # a requester can be made (a height consumed, a higher range)
+        self._room = asyncio.Event()
         self._started_at = 0.0
 
     async def on_start(self) -> None:
@@ -63,25 +78,37 @@ class BlockPool(Service):
             self.peers[peer_id] = peer
         peer.base = base
         peer.height = height
-        self.max_peer_height = max(
-            (p.height for p in self.peers.values() if not p.banned), default=0
-        )
+        self._peers_changed()
 
     def remove_peer(self, peer_id: str) -> None:
-        """Received blocks are kept; live requesters retry other peers."""
+        """Received blocks are kept; the fetches open at this peer are
+        asked of another at once (reference: pool.go removePeer redoes
+        its requesters)."""
         self.peers.pop(peer_id, None)
-        self.max_peer_height = max(
-            (p.height for p in self.peers.values() if not p.banned), default=0
-        )
+        self._peers_changed()
+        self._refetch_from(peer_id)
 
     def ban_peer(self, peer_id: str) -> None:
         """Sent us a bad block (reference: pool.go RedoRequest path)."""
         peer = self.peers.get(peer_id)
         if peer is not None:
             peer.banned = True
+        self._peers_changed()
+        self._refetch_from(peer_id)
+
+    def _peers_changed(self) -> None:
         self.max_peer_height = max(
             (p.height for p in self.peers.values() if not p.banned), default=0
         )
+        self._changed.set()
+        self._room.set()
+
+    def _refetch_from(self, peer_id: str) -> None:
+        """Wake the requesters still waiting for a block from
+        `peer_id`: it will not come."""
+        for h, asked in self._asked.items():
+            if asked == peer_id and h not in self._blocks:
+                self._block_events[h].set()
 
     # -- block intake --
 
@@ -93,9 +120,17 @@ class BlockPool(Service):
         if h not in self._requesters:
             return  # unsolicited height
         self._blocks[h] = (block, peer_id)
-        ev = self._block_events.get(h)
-        if ev is not None:
-            ev.set()
+        self._block_events[h].set()
+        self._changed.set()
+
+    async def wait_changed(self) -> None:
+        """Sleep until what the consumer reads may have changed since
+        this last returned: a block arrived, the peers' range moved,
+        or the caught-up grace ran out."""
+        if not self._changed.is_set():
+            grace = self._started_at + _CAUGHT_UP_GRACE_S - time.monotonic()
+            await _wait(self._changed, grace + 0.01 if grace > 0 else None)
+        self._changed.clear()
 
     # -- ordered consumption (reference: pool.go:218-260) --
 
@@ -123,19 +158,20 @@ class BlockPool(Service):
         if t is not None and not t.done():
             t.cancel()
         self._block_events.pop(h, None)
+        self._asked.pop(h, None)
         self.height = h + 1
         self._tasks = [x for x in self._tasks if not x.done()]
+        self._room.set()
 
     def redo_request(self, height: int) -> None:
         """Verification failed: drop fetched blocks from this height up and
         refetch from other peers (reference: pool.go RedoRequest)."""
         for h in list(self._blocks.keys()):
             if h >= height:
-                block, peer_id = self._blocks.pop(h)
-                ev = self._block_events.get(h)
-                if ev is not None:
-                    ev.clear()
-                # requester for h is still alive and will refetch
+                del self._blocks[h]
+                # the requester for h is still alive: it wakes, finds
+                # its block gone and asks another peer
+                self._block_events[h].set()
 
     def is_caught_up(self) -> bool:
         """reference: pool.go:200-216."""
@@ -149,57 +185,45 @@ class BlockPool(Service):
 
     async def _make_requesters_routine(self) -> None:
         while True:
+            self._room.clear()
             pending = len(self._requesters)
-            if (
+            while (
                 pending < MAX_PENDING_REQUESTS
                 and self.height + pending <= self.max_peer_height
             ):
                 h = self.height + pending
-                if h not in self._requesters:
-                    self._block_events[h] = asyncio.Event()
-                    self._requesters[h] = self.spawn(
-                        self._requester(h), f"req-{h}"
-                    )
-                    continue
-            await asyncio.sleep(0.02)
+                self._block_events[h] = asyncio.Event()
+                self._requesters[h] = self.spawn(
+                    self._requester(h), f"req-{h}"
+                )
+                pending += 1
+            await self._room.wait()
 
     async def _requester(self, height: int) -> None:
         """Fetch `height` from some peer; retry across peers until a block
-        arrives (reference: pool.go bpRequester:415-470)."""
+        arrives, and again if it is refused (reference: pool.go
+        bpRequester:415-470). Ends by being cancelled, when the height
+        is consumed (pop_request)."""
         tried: Set[str] = set()
+        event = self._block_events[height]
         while True:
+            event.clear()
+            if height in self._blocks:
+                # fetched: nothing to do unless redo_request drops it
+                await event.wait()
+                continue
             peer = self._pick_peer(height, tried)
             if peer is None:
                 tried.clear()  # all peers tried; start over
                 await asyncio.sleep(1.0)
                 continue
             tried.add(peer.peer_id)
+            self._asked[height] = peer.peer_id
             self._send_request(height, peer.peer_id)
-            ev = self._block_events.get(height)
-            if ev is None:
-                return
-            # asyncio.wait, not wait_for: on Python 3.10, wait_for
-            # swallows a cancellation that races the event being set
-            # (bpo-42130 family), leaving this requester alive forever
-            # and hanging Service.stop()'s gather. wait() re-raises the
-            # outer cancel unconditionally.
-            waiter = asyncio.ensure_future(ev.wait())
-            try:
-                done, _pending = await asyncio.wait(
-                    {waiter}, timeout=REQUEST_TIMEOUT
-                )
-            finally:
-                waiter.cancel()
-            if waiter not in done:
-                continue  # timeout: try another peer
-            # block arrived (possibly from redo_request → cleared event)
-            while height in self._blocks:
-                await asyncio.sleep(0.1)
-                if height < self.height:
-                    return  # consumed
-            if height < self.height:
-                return
-            ev.clear()  # redo_request dropped it; refetch
+            # back at the top either way: with the block, or without it
+            # (timeout, the peer gone, the block dropped again) to ask
+            # another peer
+            await _wait(event, REQUEST_TIMEOUT)
 
     def _pick_peer(self, height: int, tried: Set[str]) -> Optional[_PoolPeer]:
         candidates = [
@@ -213,3 +237,17 @@ class BlockPool(Service):
         if not candidates:
             return None
         return rng.choice(candidates)
+
+
+async def _wait(event: asyncio.Event, timeout: Optional[float]) -> None:
+    """Until `event` is set or `timeout` seconds have passed (None:
+    however long). asyncio.wait, not wait_for: on Python 3.10, wait_for
+    swallows a cancellation that races the event being set (bpo-42130
+    family), leaving a requester alive forever and hanging
+    Service.stop()'s gather. wait() re-raises the outer cancel
+    unconditionally."""
+    waiter = asyncio.ensure_future(event.wait())
+    try:
+        await asyncio.wait({waiter}, timeout=timeout)
+    finally:
+        waiter.cancel()

@@ -11,8 +11,11 @@ then applied through the BlockExecutor.
 from __future__ import annotations
 
 import asyncio
-from typing import Optional
+import time
+from collections import deque
+from typing import Deque, Optional, Tuple
 
+from ..libs import trace
 from ..libs.log import get_logger
 from ..libs.service import Service
 from ..p2p.channel import Channel
@@ -31,6 +34,7 @@ from .msgs import (
     StatusRequestMessage,
     StatusResponseMessage,
 )
+from .metrics import BlocksyncMetrics
 from .pool import BlockPool
 
 __all__ = [
@@ -43,11 +47,35 @@ BLOCKSYNC_CHANNEL = 0x40
 _STATUS_UPDATE_INTERVAL = 2.0
 
 
-def blocksync_channel_descriptor():
-    """reference: reactor.go:66-75."""
+class _MeteredCodec:
+    """BlocksyncCodec with every inbound decode (the router runs it, on
+    the peer's receive task) under a `blocksync_decode` span and on the
+    reactor's `decode_seconds` counter: a BlockResponse is a whole
+    block, its LastCommit's signatures included."""
+
+    encode = staticmethod(BlocksyncCodec.encode)
+
+    def __init__(self, metrics: BlocksyncMetrics) -> None:
+        self._seconds = metrics.decode_seconds
+
+    def decode(self, data: bytes):
+        t0 = time.perf_counter()
+        with trace.span("blocksync_decode", bytes=len(data)):
+            msg = BlocksyncCodec.decode(data)
+        self._seconds.inc(time.perf_counter() - t0)
+        return msg
+
+
+def blocksync_channel_descriptor(
+    metrics: Optional[BlocksyncMetrics] = None,
+):
+    """reference: reactor.go:66-75. With the reactor's `metrics`, the
+    channel's decodes are counted there."""
     return ChannelDescriptor(
         channel_id=BLOCKSYNC_CHANNEL,
-        message_type=BlocksyncCodec,
+        message_type=(
+            BlocksyncCodec if metrics is None else _MeteredCodec(metrics)
+        ),
         priority=5,
         send_queue_capacity=1000,
         recv_buffer_capacity=1024,
@@ -66,8 +94,10 @@ class BlocksyncReactor(Service):
         block_sync: bool = True,  # start in sync mode?
         consensus_reactor=None,  # switch target when caught up
         event_bus=None,
+        metrics: Optional[BlocksyncMetrics] = None,
     ) -> None:
         super().__init__(name="blocksync", logger=get_logger("blocksync"))
+        self.metrics = metrics if metrics is not None else BlocksyncMetrics()
         self.state = state
         self.block_exec = block_exec
         self.block_store = block_store
@@ -81,6 +111,11 @@ class BlocksyncReactor(Service):
             start_height = state.initial_height
         self.pool = BlockPool(start_height, self._request_block)
         self.synced = False
+        # the last blocks refused: (height, the commit's error, the
+        # providers banned for it), for an operator and the debug bundle
+        self.refusals: Deque[Tuple[int, str, Tuple[str, ...]]] = deque(
+            maxlen=16
+        )
 
     async def on_start(self) -> None:
         self.spawn(self._recv_routine(), "recv")
@@ -212,50 +247,66 @@ class BlocksyncReactor(Service):
                 return
             first, second = self.pool.peek_two_blocks()
             if first is None or second is None:
-                await asyncio.sleep(0.05)
+                with trace.span("blocksync_wait", height=self.pool.height):
+                    await self.pool.wait_changed()
                 continue
             await self._verify_apply(first, second)
 
     async def _verify_apply(self, first, second) -> None:
         """Verify `first` with `second.LastCommit`, then apply
         (reference: reactor.go:452-520)."""
-        first_parts = first.make_part_set()
-        first_id = BlockID(
-            hash=first.hash(), part_set_header=first_parts.header()
-        )
+        height = first.header.height
+        with trace.span("block_parts", height=height):
+            first_parts = first.make_part_set()
         try:
-            # the whole LastCommit of block H+1 in one device batch call
-            verify_commit_light(
-                self.state.chain_id,
-                self.state.validators,
-                first_id,
-                first.header.height,
-                second.last_commit,
-            )
+            # `commits`: the commits whose votes went into one batch
+            with trace.span("blocksync_verify", height=height, commits=1):
+                first_id = BlockID(
+                    hash=first.hash(), part_set_header=first_parts.header()
+                )
+                # the whole LastCommit of block H+1 in one device batch
+                # call
+                verify_commit_light(
+                    self.state.chain_id,
+                    self.state.validators,
+                    first_id,
+                    height,
+                    second.last_commit,
+                )
         except Exception as e:
             self.logger.error(
                 "invalid last commit during block sync",
-                height=first.header.height,
+                height=height,
                 err=str(e),
             )
             # punish both providers and refetch
-            for peer_id in (
-                self.pool.first_block_peer(),
-                self.pool.second_block_peer(),
-            ):
-                if peer_id:
-                    self.pool.ban_peer(peer_id)
-                    await self.channel.send_error(
-                        PeerError(node_id=peer_id, err=f"bad block: {e}")
-                    )
-            self.pool.redo_request(first.header.height)
+            providers = tuple(
+                peer_id
+                for peer_id in (
+                    self.pool.first_block_peer(),
+                    self.pool.second_block_peer(),
+                )
+                if peer_id
+            )
+            self.refusals.append((height, str(e), providers))
+            for peer_id in providers:
+                self.pool.ban_peer(peer_id)
+                await self.channel.send_error(
+                    PeerError(node_id=peer_id, err=f"bad block: {e}")
+                )
+            self.pool.redo_request(height)
+            self.metrics.redo_requests.inc()
             return
 
-        self.block_store.save_block(first, first_parts, second.last_commit)
+        with trace.span("block_store_save", height=height):
+            self.block_store.save_block(
+                first, first_parts, second.last_commit
+            )
         self.state = await self.block_exec.apply_block(
             self.state, first_id, first
         )
         self.pool.pop_request()
+        self.metrics.blocks_applied.inc()
         if self.pool.height % 100 == 0:
             self.logger.info(
                 "block-synced", height=self.pool.height,

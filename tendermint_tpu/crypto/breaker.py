@@ -49,6 +49,7 @@ __all__ = [
     "breaker_for",
     "discard",
     "fresh",
+    "registered",
     "reset_all",
 ]
 
@@ -346,6 +347,13 @@ def breaker_for(name: str, **kwargs) -> CircuitBreaker:
         if b is None:
             b = _REGISTRY[name] = CircuitBreaker(name, **kwargs)
         return b
+
+
+def registered(name: str) -> Optional[CircuitBreaker]:
+    """The live breaker of a route, or None: unlike breaker_for, it
+    creates nothing."""
+    with _REG_LOCK:
+        return _REGISTRY.get(name)
 
 
 def fresh(name: str, **kwargs) -> CircuitBreaker:

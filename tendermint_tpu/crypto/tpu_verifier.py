@@ -834,6 +834,10 @@ _SHARED_VERIFIER = None
 _SHARED_VERIFIER_SR = None
 _MIN_BATCH = DEFAULT_MIN_BATCH
 _INSTALLED = False
+# what the live install was asked for and the breakers it registered:
+# an install that asks for the same again changes nothing
+_INSTALLED_MESH = None
+_INSTALLED_BREAKERS: tuple = ()
 
 # The route breakers (crypto/breaker.py), by name:
 #   "ed25519" / "sr25519"     the batch factories + streaming dispatch
@@ -1003,6 +1007,18 @@ def _on_compile_event(event: str, _duration: float, **_kw) -> None:
         heap.mark_dirty()
 
 
+def _same_install(min_batch: int, mesh) -> bool:
+    """Whether install(min_batch, mesh) would only rebuild what is
+    live: the settings are the live install's and nobody has replaced
+    or dropped its breakers since (breaker.reset_all, a test's own
+    fresh())."""
+    if not _INSTALLED or min_batch != _MIN_BATCH or mesh != _INSTALLED_MESH:
+        return False
+    return all(
+        _breaker_mod.registered(b.name) is b for b in _INSTALLED_BREAKERS
+    )
+
+
 def install(
     min_batch: int = DEFAULT_MIN_BATCH, mesh=None
 ) -> None:
@@ -1010,7 +1026,12 @@ def install(
     ed25519 batches are sharded across it
     (tendermint_tpu.parallel.sharding); otherwise single-chip.
 
-    Each install is a new breaker generation: fresh instances replace
+    An install at the live install's settings (the same min_batch over
+    the same mesh, its breakers still the registered ones) changes
+    nothing: the second Node of a process, a restart in place or a
+    localnet, keeps the verifiers, the breakers' states and the warm
+    buckets, and starts no probe beside traffic. Any other install is
+    a new breaker generation: fresh instances replace
     the registered ones, so a probe still in flight from a superseded
     install publishes into an orphaned object nobody consults — the
     atomicity the old _SR_WARM_GEN counter provided by hand.
@@ -1020,6 +1041,9 @@ def install(
     loaded ends with one full collection and gc.freeze() (libs/heap.py),
     so the collector never walks the traced programs again."""
     global _SHARED_VERIFIER, _SHARED_VERIFIER_SR, _MIN_BATCH, _INSTALLED
+    global _INSTALLED_MESH, _INSTALLED_BREAKERS
+    if _same_install(min_batch, mesh):
+        return
     if not _INSTALLED:  # an install over an install is already listening
         import jax.monitoring
 
@@ -1070,6 +1094,8 @@ def install(
     # would hang node startup); a probe that stalls only delays the
     # device upgrade of single verifies, never a vote
     b_single.probe_now()
+    _INSTALLED_MESH = mesh
+    _INSTALLED_BREAKERS = (b_ed, b_sr, b_single)
     register_device_factory("ed25519", _factory)
     register_device_factory("sr25519", _factory_sr)
     # merged multi-commit batches (light sequential windows) only pay
@@ -1110,6 +1136,7 @@ def uninstall() -> None:
     is thawed (gc.unfreeze()): the CPU seam gets the collector's whole
     heap back, and a later install() freezes again at its next compile."""
     global _SHARED_VERIFIER, _SHARED_VERIFIER_SR, _MIN_BATCH, _INSTALLED
+    global _INSTALLED_MESH, _INSTALLED_BREAKERS
     from .batch import (
         native_cpu_affinity,
         set_group_affinity_fn,
@@ -1131,6 +1158,8 @@ def uninstall() -> None:
         )
     _MIN_BATCH = DEFAULT_MIN_BATCH
     _INSTALLED = False
+    _INSTALLED_MESH = None
+    _INSTALLED_BREAKERS = ()
     _m_mesh_devices.set(0)
     for name in ("ed25519", "sr25519", _SR_SINGLE):
         _breaker_mod.discard(name)

@@ -532,16 +532,21 @@ class Node(Service):
             self.peer_manager.subscribe(),
         )
         from ..blocksync import BlocksyncReactor, blocksync_channel_descriptor
+        from ..blocksync.metrics import BlocksyncMetrics
 
+        bs_metrics = BlocksyncMetrics(self.metrics_registry)
         self.blocksync_reactor = BlocksyncReactor(
             state,
             self.block_exec,
             self.block_store,
-            self.router.open_channel(blocksync_channel_descriptor()),
+            self.router.open_channel(
+                blocksync_channel_descriptor(bs_metrics)
+            ),
             self.peer_manager.subscribe(),
             block_sync=block_sync and not state_sync,
             consensus_reactor=self.consensus_reactor,
             event_bus=self.event_bus,
+            metrics=bs_metrics,
         )
         from ..statesync import StatesyncReactor, statesync_channel_descriptors
 

@@ -290,9 +290,11 @@ class BlockExecutor:
     async def _apply_block_timed(
         self, state: State, block_id: BlockID, block: Block
     ) -> State:
-        self.validate_block(state, block)
+        with trace.span("validate_block"):
+            self.validate_block(state, block)
 
-        responses = await self._exec_block(state, block)
+        with trace.span("exec_block"):
+            responses = await self._exec_block(state, block)
 
         self.store.save_abci_responses(block.header.height, responses)
 
@@ -317,12 +319,16 @@ class BlockExecutor:
         )
 
         # Lock mempool, commit app state, update mempool
-        app_hash, retain_height = await self._commit(new_state, block, responses)
+        with trace.span("abci_commit"):
+            app_hash, retain_height = await self._commit(
+                new_state, block, responses
+            )
         new_state.app_hash = app_hash
 
         self.evpool.update(new_state, list(block.evidence))
 
-        self.store.save(new_state)
+        with trace.span("state_save"):
+            self.store.save(new_state)
 
         if retain_height > 0 and self.block_store is not None:
             try:
@@ -374,10 +380,17 @@ class BlockExecutor:
     def _begin_block_commit_info(
         self, state: State, block: Block
     ) -> abci.LastCommitInfo:
-        """reference: internal/state/execution.go getBeginBlockValidatorInfo."""
-        last_vals = self.store.load_validators(block.header.height - 1)
+        """reference: internal/state/execution.go getBeginBlockValidatorInfo.
+        The validators of the block before are the state's own
+        `last_validators`: validate_block has just held this block's
+        height and LastCommit to them. The store's record is sparse (a
+        pointer to the height the set last changed), so reading it
+        back costs a priority increment a height since then, 150
+        validators x 500 heights a block on a static set; it is asked
+        only for a state that carries none."""
+        last_vals = state.last_validators
         if last_vals is None:
-            last_vals = state.last_validators
+            last_vals = self.store.load_validators(block.header.height - 1)
         return build_last_commit_info(block, last_vals, state.initial_height)
 
     def _begin_block_evidence(

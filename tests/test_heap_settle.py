@@ -122,7 +122,10 @@ def reinstall_over_a_warm_trace_cache_settles_nothing() -> None:
     work = warm()
     before = settles()
     misses = tpu_verifier.stats()["warm_misses"]
-    tpu_verifier.install(min_batch=2)  # _WARM_BUCKETS cleared, jit's cache not
+    # other settings than the live install's, so a new generation (the
+    # same again would keep the warm set): _WARM_BUCKETS cleared, jit's
+    # cache not
+    tpu_verifier.install(min_batch=3)
     frozen = gc.get_freeze_count()
     assert verify(work)[1][0]
     # the bucket is a first touch for the seam's telemetry and no

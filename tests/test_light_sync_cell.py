@@ -118,14 +118,14 @@ def _args(trace=0, seconds=3.0):
 
 
 def test_the_manifest_has_the_cell_its_configuration_and_its_readers():
-    cell = MANIFEST["workloads"][-1]
+    (cell,) = [w for w in MANIFEST["workloads"] if w["name"] == CELL]  # by name: later PRs append
     # four chips for steadiness alone (PERF.md, PR 32): the deployment
     # itself is one device, and no more than half the cells ask for four
     assert cell == dict(cell, name=CELL, config="light-150", traffic="sync", chips=4)
     assert len(cell["why"]) <= 200 and "64" in cell["why"] and "steadiness" in cell["why"]
     four = sum(w["chips"] == 4 for w in MANIFEST["workloads"])
     assert four <= len(MANIFEST["workloads"]) // 2
-    entry = MANIFEST["configs"][-1]
+    (entry,) = [c for c in MANIFEST["configs"] if c["name"] == "light-150"]
     config, traffic = _files()
     assert entry["name"] == config["name"] == "light-150"
     assert entry["file"] == "chipbench/configs/light-150.json"
