@@ -11,7 +11,7 @@ the gate as `trace-unknown-root` until its bucket shapes are
 declared.
 
 Each root records the jit *target* (resolved to an in-package
-function where the receiver is static; `shared.__wrapped__`-style
+function where the receiver is static; `per_chip`-style
 dynamic targets keep their source text as identity), the declared
 `static_argnames`/`static_argnums`, and any `donate_argnums`/
 `donate_argnames` (consumed by shardcheck's donated-reuse rule).

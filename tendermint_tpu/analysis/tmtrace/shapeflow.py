@@ -95,7 +95,7 @@ def _line(pkg: Package, path: str, lineno: int) -> str:
 def _array_params(fi: FuncInfo, root: Optional[JitRoot]) -> Set[str]:
     """The parameters of a jit target that carry traced arrays: the
     ones without defaults, minus declared static args. Config flags
-    (`with_t=True`, `mxu=False`) all carry defaults in this codebase —
+    (`with_t=True`) all carry defaults in this codebase —
     a default marks a trace-time constant."""
     args = fi.node.args
     names = [a.arg for a in args.args]

@@ -18,7 +18,8 @@ Three signature families:
 - *power-of-two*: merkle's `_bucket` (next pow2 ≥ n, min 8) yields an
   unbounded but structured family, recorded symbolically,
 - *mesh-sharded*: ops/verifier.py's per-mesh programs (one jit site
-  partitions whichever shared program it is handed), recorded as the
+  maps whichever shared program it is handed over the chips),
+  recorded as the
   round-up formula over the base bucket table (the live divisibility
   gate proves the formula; the underlying body signatures are the
   ed25519/sr25519/sha512 entries).
@@ -270,7 +271,7 @@ def _build_model() -> Dict[str, RootModel]:
         ],
     )
     add(
-        "ops/verifier.py:shared.__wrapped__",
+        "ops/verifier.py:per_chip",
         "heavy",
         lambda: [
             f"sharded(sig axis): base bucket {b} -> "
@@ -283,8 +284,11 @@ def _build_model() -> Dict[str, RootModel]:
             for b in _buckets()
         ],
         # no direct trace: the bodies are the ed25519/sr25519 tile and
-        # sha512 entries, partitioned alike; mesh placement is proven
-        # by the divisibility gate
+        # sha512 entries, each chip running one on its own shard
+        # (shard_map: the fused walk a TPU's tile holds, ops/
+        # fused_walk.py, is a kernel whose operands are the tile's
+        # lanes and that the compiler cannot partition); mesh
+        # placement is proven by the divisibility gate
         lambda full: [],
     )
     return model

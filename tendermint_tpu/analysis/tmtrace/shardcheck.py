@@ -17,8 +17,11 @@ first multi-chip run — so they are gates here:
 - **trace-bucket-indivisible** (live, run by tracegate): for every
   virtual mesh size 1..8, the *real* sharded verifier classes are
   instantiated against a duck-typed mesh and every bucket they would
-  dispatch must divide by the mesh size — the property
-  `BucketedVerifier.__init__`/`_bucket` (ops/verifier.py) guarantees, checked
+  dispatch must divide by the mesh size, and a chip's share of it
+  must be one tile of the fused window walk or whole tiles
+  (`LANE_TILE`: the kernel's grid has no ragged last step, and the CPU
+  suite, which traces the scan, would never see one) — the properties
+  `BucketedVerifier.__init__`/`_bucket` (ops/verifier.py) guarantee, checked
   against the production rounding code rather than a re-derived
   formula, so a refactor that drops the round-up turns the gate red.
 
@@ -242,9 +245,12 @@ def divisibility_violations(
 ) -> List[Violation]:
     """Instantiate each sharded verifier against duck meshes of every
     virtual width and prove every bucket it would dispatch divides by
-    the mesh — exercising the REAL ops/verifier.py rounding code, not a
-    re-derivation of it. Needs jax importable (tracegate runs it)."""
+    the mesh, into shares the fused walk tiles — exercising the REAL
+    ops/verifier.py rounding code, not a re-derivation of it. Needs jax
+    importable (tracegate runs it)."""
     import numpy as np
+
+    from ...ops.verifier import LANE_TILE
 
     if sharded_classes is None:
         from ...parallel import sharding as sh
@@ -278,12 +284,33 @@ def divisibility_violations(
                     )
                 )
                 continue
-            bad = [b for b in v.bucket_sizes if b % n]
-            bad += [
-                v._bucket(m)
-                for m in probe_sizes
-                if v._bucket(m) % n
+            buckets = set(v.bucket_sizes) | {
+                v._bucket(m) for m in probe_sizes
+            }
+            bad = [b for b in buckets if b % n]
+            ragged = [
+                b
+                for b in buckets
+                if not b % n and b // n > LANE_TILE and b // n % LANE_TILE
             ]
+            if ragged:
+                out.append(
+                    Violation(
+                        rule="trace-bucket-indivisible",
+                        path="parallel/sharding.py",
+                        line=1,
+                        col=0,
+                        message=(
+                            f"{cls.__name__} on a {n}-device mesh "
+                            f"produces bucket(s) {sorted(ragged)} whose "
+                            f"share a chip is above {LANE_TILE} lanes "
+                            f"and no multiple of it — a TPU's fused "
+                            "walk (ops/fused_walk.py) refuses the width "
+                            "when the program is traced"
+                        ),
+                        source="",
+                    )
+                )
             if bad:
                 out.append(
                     Violation(

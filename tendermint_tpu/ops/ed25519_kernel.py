@@ -67,6 +67,7 @@ __all__ = [
     "Ed25519Verifier",
     "batch_verify_host",
     "dual_mult_sb_minus_ka",
+    "walk_form",
     "DEFAULT_BUCKET_SIZES",
     "bucket_for",
 ]
@@ -104,11 +105,9 @@ def _build_neg_a_table(A: jnp.ndarray) -> jnp.ndarray:
 
 def _onehot_select(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """table (K, 4, L, {N|1}), idx (N,) -> (4, L, N) via K-way masked
-    accumulate (no per-lane gather). broadcasted_iota and not arange
-    is history: the form a fused TPU kernel's compiler (Mosaic, which
-    rejects rank-1 iota) could lower. It stays because another form
-    would be another compiled program (the kernel is in git's
-    history, at 8b8c9eb under ops/)."""
+    accumulate (no per-lane gather). broadcasted_iota and not arange:
+    the fused walk's compiler (Mosaic, ops/fused_walk.py) rejects a
+    rank-1 iota."""
     k = table.shape[0]
     js = lax.broadcasted_iota(idx.dtype, (k, idx.shape[0]), 0)
     mask = (idx[None, :] == js).astype(table.dtype)  # (K, N)
@@ -129,9 +128,9 @@ def _recode_signed(d: jnp.ndarray) -> jnp.ndarray:
     malformed inputs).
 
     The generate/propagate lattice is int32 0/1 and not bool, for the
-    same historical reason as _onehot_select's iota: Mosaic could not
-    concatenate or shift i1 vregs ('Invalid vector register cast',
-    found compiling for a described v5e)."""
+    same reason as _onehot_select's iota: Mosaic cannot concatenate or
+    shift i1 vregs ('Invalid vector register cast', found compiling
+    for a described v5e)."""
     g = (d >= 8).astype(d.dtype)
     p = (d == 7).astype(d.dtype)
     shift = 1
@@ -145,35 +144,24 @@ def _recode_signed(d: jnp.ndarray) -> jnp.ndarray:
     return t - 16 * (t >= 8).astype(d.dtype)
 
 
-def _select_signed(
-    table9: jnp.ndarray, e: jnp.ndarray, mxu: bool = False
-) -> jnp.ndarray:
+def _select_signed(table9: jnp.ndarray, e: jnp.ndarray) -> jnp.ndarray:
     """table9 (9, 4, L, {N|1}) cached-form entries for j*P, j = 0..8;
     e (N,) signed digit in [-8, 8] -> (4, L, N) cached |e|*P, negated
     when e < 0 (cached negation = swap (Y-X, Y+X), negate 2dT — no
-    multiplies, edwards.negate_cached's identity applied post-select).
-
-    mxu=True (lane-shared tables only, i.e. the fixed-base B table):
-    the select is a real (9, 4L) x (9, N) contraction, so ride the MXU
-    in f32 instead of spending VPU MACs — exact because limbs < 2^24
-    and the mask is one-hot (Precision.HIGHEST carries the full f32
-    mantissa through the bf16 passes)."""
-    idx = jnp.abs(e)
-    if mxu and table9.shape[-1] == 1:
-        k = table9.shape[0]
-        js = lax.broadcasted_iota(idx.dtype, (k, idx.shape[0]), 0)
-        mask = (idx[None, :] == js).astype(jnp.float32)  # (9, N)
-        tbl = table9[..., 0].reshape(k, -1).astype(jnp.float32)  # (9, 4L)
-        sel = jnp.einsum(
-            "kc,kn->cn", tbl, mask, precision=lax.Precision.HIGHEST
-        )
-        sel = sel.reshape(
-            table9.shape[1], table9.shape[2], idx.shape[0]
-        ).astype(jnp.int32)
-    else:
-        sel = _onehot_select(table9, idx)
+    multiplies, edwards.negate_cached's identity applied post-select)."""
+    sel = _onehot_select(table9, jnp.abs(e))
     sgn = (e < 0)[None, None, :]
     return jnp.where(sgn, E.negate_cached(sel), sel)
+
+
+def walk_form() -> str:
+    """Which form the 64-window walk of a tile program traced now takes:
+    "fused", one Pallas kernel (ops/fused_walk.py), where the backend
+    is a TPU; "scan", the lax.scan program below, anywhere else (the
+    observable ops/sha512_kernel.py picks its unrolled form from). The
+    one place that picks; what a launched program then holds, its
+    verifier reads off the program (ops/verifier.py `_launch`)."""
+    return "fused" if jax.default_backend() == "tpu" else "scan"
 
 
 def dual_mult_sb_minus_ka(
@@ -189,47 +177,77 @@ def dual_mult_sb_minus_ka(
     cached table of -A built on device and a constant niels table of B.
     Shared by the ed25519 program (cofactored compare follows) and the
     sr25519/ristretto program (ristretto equality follows,
-    ops/sr25519_kernel.py). The window walk is one lax.scan over
-    pre-flipped digit rows; the fixed-base select rides the MXU
-    (_select_signed)."""
+    ops/sr25519_kernel.py).
+
+    Two forms of the same arithmetic (walk_form): on a TPU the table,
+    the recode and the walk are one Pallas kernel, 128 lanes a grid
+    step with the intermediates in VMEM; elsewhere one lax.scan over
+    pre-flipped digit rows, which is also the kernel's differential
+    oracle.
+    Either way the walk's operations carry the `dual_mult` scope the
+    profiler's trace names them by."""
+    if walk_form() == "fused":
+        from .fused_walk import fused_walk
+
+        with jax.named_scope("dual_mult"):
+            return fused_walk(A, dS, dk)
     with jax.named_scope("neg_a_table"):
         TA = _build_neg_a_table(A)  # (9, 4, L, N)
-
-    tb0 = _tb0()  # (9, 4, L, 1)
-
     with jax.named_scope("scalar_prep"):
         dS = _recode_signed(dS)
         dk = _recode_signed(dk)
-
-    # The carry is the T-less 3-stack (X, Y, Z): doublings never
-    # read T and the final comparison is projective, so only the ops
-    # feeding an addition materialize T (point ops drop the T output
-    # mul otherwise — 25% of each output multiply).
-    acc0 = E.identity(A.shape[-1])[..., :3, :, :]
-
-    def step(acc, ds_w, dk_w):
-        acc = lax.fori_loop(
-            0, 3, lambda _i, a: E.point_double(a, with_t=False), acc
-        )
-        acc = E.point_double(acc)  # T feeds the addition below
-        acc = E.point_add_cached(acc, _select_signed(TA, dk_w))
-        acc = E.point_add_cached(
-            acc, _select_signed(tb0, ds_w, mxu=True), with_t=False
-        )
-        return acc
-
-    # the 64-window walk: the profiler's trace names its operations by
-    # this scope
+    tb0 = _tb0()  # (9, 4, L, 1)
     with jax.named_scope("dual_mult"):
-
-        def scan_body(acc, xs):
-            ds_w, dk_w = xs
-            return step(acc, ds_w, dk_w), None
-
         acc, _ = lax.scan(
-            scan_body, acc0, (jnp.flip(dS, axis=0), jnp.flip(dk, axis=0))
+            lambda acc, xs: (_window(acc, TA, tb0, *xs), None),
+            _acc0(A),
+            (jnp.flip(dS, axis=0), jnp.flip(dk, axis=0)),
         )
         return acc
+
+
+def _acc0(A: jnp.ndarray) -> jnp.ndarray:
+    """The walk's carry at its start: the identity as the T-less
+    3-stack (X, Y, Z). Doublings never read T and the final comparison
+    is projective, so only the ops feeding an addition materialize T
+    (point ops drop the T output mul otherwise — 25% of each output
+    multiply)."""
+    return E.identity(A.shape[-1])[..., :3, :, :]
+
+
+def _window(acc, TA, tb0, ds_w, dk_w) -> jnp.ndarray:
+    """One window: acc <- 16*acc + dk_w*(-A) + ds_w*B, from the tables
+    TA of -A and tb0 of B."""
+    acc = lax.fori_loop(
+        0, 3, lambda _i, a: E.point_double(a, with_t=False), acc
+    )
+    acc = E.point_double(acc)  # T feeds the addition below
+    acc = E.point_add_cached(acc, _select_signed(TA, dk_w))
+    return E.point_add_cached(acc, _select_signed(tb0, ds_w), with_t=False)
+
+
+def dual_mult_rows(
+    A: jnp.ndarray, dS: jnp.ndarray, dk: jnp.ndarray
+) -> jnp.ndarray:
+    """dual_mult_sb_minus_ka in the forms a TPU kernel's compiler
+    (Mosaic) lowers: the body ops/fused_walk.py runs a 128-lane tile
+    through. A lax.fori_loop whose window picks its digit row by a
+    one-hot masked sum, because Mosaic lowers neither scan's
+    dynamic_slice of xs nor jnp.flip's rev (64 more MACs a window are
+    noise beside the point ops)."""
+    TA = _build_neg_a_table(A)
+    dS = _recode_signed(dS)
+    dk = _recode_signed(dk)
+    tb0 = _tb0()
+    rows = lax.broadcasted_iota(dS.dtype, dS.shape, 0)  # (64, N)
+
+    def body(w, acc):
+        sel = (rows == 63 - w).astype(dS.dtype)  # most significant first
+        return _window(
+            acc, TA, tb0, jnp.sum(dS * sel, axis=0), jnp.sum(dk * sel, axis=0)
+        )
+
+    return lax.fori_loop(0, 64, body, _acc0(A))
 
 
 def _scalar_mult_check(yA, signA, yR, signR, dS, dk) -> jnp.ndarray:
@@ -282,8 +300,10 @@ _C8 = _bytes_const(_DELTA16_INT, 17)
 _L8 = _bytes_const(_L_INT, 32)
 
 # (32, 1) AND-mask clearing the sign bit of byte row 31 — the
-# mask-select form of `.at[31].set(b & 0x7F)` (history, as
-# _onehot_select: Mosaic lowered no scatter update)
+# mask-select form of `.at[31].set(b & 0x7F)` (history: the whole tile
+# was a Mosaic kernel once, at 8b8c9eb, and Mosaic lowered no scatter
+# update; today's kernel, ops/fused_walk.py, holds the dual
+# multiplication alone)
 _TOPCLEAR = np.full((32, 1), 0xFF, dtype=np.int32)
 _TOPCLEAR[31, 0] = 0x7F
 
@@ -352,7 +372,7 @@ def _mod_l_dev(d: jnp.ndarray) -> jnp.ndarray:
     x = _norm8(lo - _mul_c8(x[32:], 33), 34)
     l8_33 = jnp.asarray(np.pad(_L8, ((0, 1), (0, 0))))
     # x[32], not x[-1]: jnp lowers negative indices via dynamic_slice
-    # (history, as _onehot_select: Mosaic could not lower it)
+    # (history, as _TOPCLEAR: Mosaic could not lower it)
     neg = (x[32] < 0).astype(jnp.int32)
     x = x + neg[None, :] * l8_33
     x = _norm8(x, 34)
@@ -371,7 +391,7 @@ def _lt_const_dev(rows: jnp.ndarray, const8: np.ndarray) -> jnp.ndarray:
     and the ristretto s < p canonicity check (ops/sr25519_kernel.py).
 
     The decided/lt lattice is int32 0/1 and not bool (history, as
-    _onehot_select: a scalar-True jnp.where operand became an i8
+    _TOPCLEAR: a scalar-True jnp.where operand became an i8
     constant that Mosaic had to truncate to i1, 'Unsupported target
     bitwidth for truncation')."""
     cb = np.asarray(const8)[:, 0]

@@ -12,13 +12,16 @@ the default device and the module's shared jitted program runs them.
 With one (`tendermint_tpu.parallel.make_mesh`) the layout is 1-D
 data-parallel over the mesh's single `sig` axis: buckets round up to a
 multiple of the mesh so every chip gets an equal shard, rows are
-sharded straight from the host, and the same tile function is
-partitioned along its batch axis, with no cross-device traffic until
-the bitmap's gather.
+sharded straight from the host, and every chip runs the same tile
+function on its own shard (`shard_map`: the program is lane-local, and
+the fused walk inside it, ops/fused_walk.py, is a custom call that the
+compiler could not partition by itself), with no cross-device traffic
+until the bitmap's gather.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import numpy as np
@@ -44,6 +47,11 @@ SIG_AXIS = "sig"
 ROWS = P(None, SIG_AXIS)  # (rows, N) byte matrices
 LANES = P(SIG_AXIS)  # the (N,) bitmap
 
+# lanes a grid step of a TPU's fused window walk takes
+# (ops/fused_walk.py): a chip's share of a bucket is one narrower tile
+# or whole tiles (`BucketedVerifier._round`)
+LANE_TILE = 128
+
 
 def _join_cols(items: Sequence[bytes], width: int, pad: int) -> np.ndarray:
     """Join n equal-length byte strings into a (width, n+pad) uint8
@@ -60,6 +68,56 @@ def _program_name(prog) -> str:
     what the profiler calls the program's executions, less its `jit_`
     prefix."""
     return getattr(prog, "__name__", type(prog).__name__)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_chip(fn, mesh, out: P):
+    """`fn` over `mesh`, each chip running it on its own shard
+    (`shard_map`: the tiles are lane-local, and the fused walk inside
+    them is a kernel the compiler cannot partition by itself). One
+    program a (function, mesh) for the process, as the shared jitted
+    programs are one a function: a verifier built again over the same
+    mesh (a node's re-install) traces and loads nothing anew."""
+    per_chip = jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=ROWS,
+        out_specs=out,
+        check_vma=False,  # a pallas_call declares none
+    )
+    return jax.jit(
+        per_chip,
+        in_shardings=NamedSharding(mesh, ROWS),
+        out_shardings=NamedSharding(mesh, out),
+    )
+
+
+def _holds(jaxpr, primitive: str) -> bool:
+    """Whether `jaxpr`, or a jaxpr nested in one of its equations,
+    applies `primitive`."""
+    nested = jax.core.jaxprs_in_params
+    return any(
+        eqn.primitive.name == primitive
+        or any(_holds(j, primitive) for j in nested(eqn.params))
+        for eqn in jaxpr.eqns
+    )
+
+
+_WALKS: dict = {}  # (tile program, bucket) -> the walk it holds
+
+
+def _walk_of(prog, bucket: int, operands) -> str:
+    """Which window walk the tile program `prog` runs over `operands`,
+    read off what jit traced for them (the trace this launch compiles,
+    or finds compiled): "fused" where it holds a Pallas kernel
+    (ops/fused_walk.py), "scan" where it holds none. Read once a
+    (program, bucket) for the process."""
+    walk = _WALKS.get((prog, bucket))
+    if walk is None:
+        jaxpr = prog.trace(*operands).jaxpr.jaxpr
+        fused = _holds(jaxpr, "pallas_call")
+        walk = _WALKS[prog, bucket] = "fused" if fused else "scan"
+    return walk
 
 
 class BucketedVerifier:
@@ -86,11 +144,15 @@ class BucketedVerifier:
         self.bucket_sizes = sorted(
             {self._round(s) for s in bucket_sizes or DEFAULT_BUCKET_SIZES}
         )
-        # a shared program -> its partitioned twin, over a mesh
-        self._partitioned: dict = {}
 
     def _round(self, b: int) -> int:
-        return -(-b // self._devices) * self._devices
+        """`b` lanes rounded up to an equal share a chip, and a share
+        above LANE_TILE to whole tiles: the fused walk's grid has no
+        ragged last step (a three-chip mesh, an oversized batch)."""
+        share = -(-b // self._devices)
+        if share > LANE_TILE:
+            share = -(-share // LANE_TILE) * LANE_TILE
+        return share * self._devices
 
     def _bucket(self, n: int) -> int:
         """The padded width `n` signatures run at: the smallest
@@ -101,19 +163,12 @@ class BucketedVerifier:
 
     def _program(self, shared, out: P):
         """`shared` (a module's jitted program) as this verifier runs
-        it: itself on one device; over a mesh, its function partitioned
-        along the batch axis of its ROWS inputs and of its output (laid
+        it: itself on one device; over a mesh, its function mapped
+        over the batch axis of its ROWS inputs and of its output (laid
         out as `out`), so that every chip computes its own shard."""
         if self.mesh is None:
             return shared
-        prog = self._partitioned.get(shared)
-        if prog is None:
-            prog = self._partitioned[shared] = jax.jit(
-                shared.__wrapped__,
-                in_shardings=NamedSharding(self.mesh, ROWS),
-                out_shardings=NamedSharding(self.mesh, out),
-            )
-        return prog
+        return _per_chip(shared.__wrapped__, self.mesh, out)
 
     def _place(self, rows):
         """Byte rows (host or already on device) -> the device array
@@ -139,12 +194,17 @@ class BucketedVerifier:
 
     def _launch(self, shared, out: P, bucket: int, *rows):
         """Place `rows` and enqueue one device program over them (JAX
-        dispatch is asynchronous: this returns before the device ends)."""
+        dispatch is asynchronous: this returns before the device ends).
+        A tile's span says which window walk the launched program holds
+        (`_walk_of`); a program without one (SHA-512) says nothing."""
         prog = self._program(shared, out)
         with trace.span(
             "device_launch", program=_program_name(prog), bucket=bucket
-        ):
-            return prog(*[self._place(r) for r in rows])
+        ) as span:
+            placed = [self._place(r) for r in rows]
+            if shared is self._TILE:
+                span.set(walk=_walk_of(prog, bucket, placed))
+            return prog(*placed)
 
     def _pack_operand(self, pubkeys, msgs, sigs, bucket: int):
         """Host rows of the third operand that are byte joins, and so

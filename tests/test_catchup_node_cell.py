@@ -79,7 +79,9 @@ def test_the_manifest_has_the_cell_its_configuration_and_its_readers():
     assert [m["name"] for m in new] == list(NODE_METRICS)
     at = MANIFEST["per_layer"].index(new[0])
     assert MANIFEST["per_layer"][at : at + len(new)] == new  # added as one block, at the end
-    assert at + len(new) == len(MANIFEST["per_layer"])
+    # of the 40 metrics PR 35 found. The lists only grow at their ends (the driver reads an entry put
+    # ahead of accepted ones as an edit of them), so the block stays at 40 and later PRs' follow it
+    assert at == 40 and at + len(new) <= len(MANIFEST["per_layer"])
     for m in new:
         assert m["moves"] == "commits_per_s" and m["layer"] == LAYER
         assert os.path.exists(os.path.join(harness.HERE, "layer_metrics", m["name"] + ".py"))

@@ -81,6 +81,10 @@ def compile_for_chip(topo):
     yield compile
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     cc.reset_cache()
+    # a trace is cached by function and shapes, whatever jit wraps it:
+    # the tiles traced here hold the TPU's kernel (ops/fused_walk.py),
+    # which a later test of this worker, on its CPU, could not run
+    jax.clear_caches()
 
 
 @pytest.fixture(scope="module")

@@ -46,7 +46,7 @@ EXPECTED_ROOT_IDS = {
     "ops/merkle_kernel.py:S.inner_hash_batch",
     "ops/merkle_kernel.py:_verify_program",
     "ops/sr25519_kernel.py:_verify_tile_sr",
-    "ops/verifier.py:shared.__wrapped__",
+    "ops/verifier.py:per_chip",
 }
 
 
@@ -277,6 +277,25 @@ def test_divisibility_seeded_bad_class_fails():
         x.rule == "trace-bucket-indivisible" for x in v
     )
     assert any("12" in x.message for x in v)
+
+
+@pytest.mark.parametrize(
+    "mesh, ragged", [(3, "2049"), (1, "20000"), (4, "20000")]
+)
+def test_divisibility_seeded_ragged_share_fails(mesh, ragged):
+    """A verifier that rounds to the mesh alone (the rule before the
+    fused walk) hands a chip of three 683 lanes, and any placement an
+    oversized batch as it comes: widths the kernel's grid cannot tile.
+    The CPU suite traces the scan and would never see it."""
+    from tendermint_tpu.parallel import sharding as sh
+
+    class MeshOnly(sh.ShardedEd25519Verifier):
+        def _round(self, b):
+            return -(-b // self._devices) * self._devices
+
+    v = shardcheck.divisibility_violations([MeshOnly], mesh_sizes=(mesh,))
+    assert v and all(x.rule == "trace-bucket-indivisible" for x in v)
+    assert any(ragged in x.message and "fused" in x.message for x in v)
 
 
 def test_trace_compile_fail_seeded():
