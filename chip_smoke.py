@@ -171,10 +171,6 @@ def device_accounting(out: dict, sent: Sent, log: CompileLog):
     }
     out["jax"] = log.since(mark)
     check(delta["faults"] == 0, f"{delta['faults']} device fault(s)")
-    check(
-        delta["pallas_fallbacks"] == 0,
-        "a Pallas program fell back to XLA",
-    )
     cpu_served = [s.attrs for s in spans if s.attrs.get("fallback") == "cpu"]
     check(not cpu_served, f"batches served by the CPU route: {cpu_served}")
     check(
@@ -928,10 +924,6 @@ def finish(log: CompileLog, install_row: dict, phase_rows: list) -> dict:
     check(
         stats["faults"] == before["faults"],
         f"device faults: {stats['faults'] - before['faults']}",
-    )
-    check(
-        stats["pallas_fallbacks"] == before["pallas_fallbacks"],
-        "a Pallas program fell back",
     )
     # a reference that quietly asked the device, or a route nobody
     # counted, shows as dispatches no phase accounts for

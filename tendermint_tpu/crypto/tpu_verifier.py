@@ -342,16 +342,25 @@ def _gather_guarded(v, handle, key_type: str) -> List[bool]:
     """One gather with the full containment stack: fault-plane hooks
     (raise/hang fire inside the watchdog so a hang surfaces as
     DeviceTimeout), the deadline, and data-fault mangling applied to
-    the bitmap exactly where a broken device would corrupt it."""
+    the bitmap exactly where a broken device would corrupt it.
+
+    The job is a `gather_job` span on the thread that runs it (the
+    watchdog's, or the caller's where there is no deadline) following
+    the caller's span (`tpu_gather`, `tpu_probe`): the caller's span
+    keeps the whole wait, and what the job does not cover of it is the
+    handoff to the thread and back."""
+    waiting = trace.current()
 
     def call():
-        if faults.armed():
-            faults.fire("tpu.gather", key=key_type)
-        return v.gather(handle)
+        with trace.span("gather_job", follows=waiting, key=key_type) as job:
+            if faults.armed():
+                faults.fire("tpu.gather", key=key_type)
+            out = [bool(b) for b in v.gather(handle)]
+            job.set(lanes=len(out))
+            return out
 
     dl = gather_deadline()
-    out = call() if dl is None else _deadline_call(call, dl)
-    bits = [bool(b) for b in out]
+    bits = call() if dl is None else _deadline_call(call, dl)
     if faults.armed():
         bits = faults.mangle("tpu.gather", bits, key=key_type)
     return bits
@@ -871,11 +880,6 @@ def stats() -> dict:
         "faults": int(_m_device_faults.value()),
         "pad_waste": int(_m_pad_waste.value()),
         "warm_misses": int(_m_warm_misses.value()),
-        # constant: no program falls back since the device layer runs
-        # one program a key class; chipbench/run.py and chip_smoke.py
-        # still read the key, and chipbench/ changes only in a
-        # benchmark PR (PERF.md §7)
-        "pallas_fallbacks": 0,
         "mesh_devices": int(_m_mesh_devices.value()),
         **heap.stats(),
     }

@@ -25,6 +25,14 @@ While tracing is on, every collection of Python's garbage collector
 that lands inside an open span is itself a `gc_collect` child span, so
 a phase's self time leaves the collector's pauses out.
 
+A span can follow a span of another thread (`span(..., follows=s)`):
+the gather's job on its watchdog thread (crypto/tpu_verifier.py)
+follows the caller's `tpu_gather`. It joins the followed span's tree
+(`root_id`) and records `follows` (that span's id) among its attrs, but
+is no child of it (`parent_id` 0): the followed span's self time keeps
+the wait whole. On its own thread it is the current span as any other,
+so its children nest under it.
+
 A mirror (`set_mirror`) puts every span on a second timeline as well:
 crypto/tpu_verifier.install() registers jax.profiler.TraceAnnotation,
 so a profiler capture of a traced process shows the program's phases
@@ -112,6 +120,7 @@ class Span:
         "_t0",
         "_token",
         "_mirrored",
+        "_follows",
     )
 
     def __init__(
@@ -120,12 +129,16 @@ class Span:
         hist=None,
         hist_labels: Optional[Dict[str, str]] = None,
         attrs: Optional[Dict[str, Any]] = None,
+        follows: Optional["Span"] = None,
     ) -> None:
         self.name = name
         self.attrs: Dict[str, Any] = attrs if attrs is not None else {}
         self.span_id = _next_id()
         self.parent_id = 0
         self.root_id = self.span_id
+        self._follows = follows
+        if follows is not None:
+            self.attrs["follows"] = follows.span_id
         self.tid = 0
         self.start_us = 0.0
         self.dur_us = 0.0
@@ -142,10 +155,13 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
-        parent = _current.get()
-        if parent is not None:
-            self.parent_id = parent.span_id
-            self.root_id = parent.root_id
+        if self._follows is not None:
+            self.root_id = self._follows.root_id
+        else:
+            parent = _current.get()
+            if parent is not None:
+                self.parent_id = parent.span_id
+                self.root_id = parent.root_id
         self.tid = threading.get_ident()
         self._token = _current.set(self)
         # the mirror opens before the clock is read and closes after,
@@ -199,16 +215,18 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
-def span(name: str, hist=None, hist_labels=None, **attrs: Any):
+def span(name: str, hist=None, hist_labels=None, follows=None, **attrs: Any):
     """A nestable timed span. With `hist`, the elapsed seconds are also
     observed into that Histogram — so instrumented call sites keep
     their metrics series when tracing is off (the span then degrades to
-    exactly `hist.time()`)."""
+    exactly `hist.time()`). With `follows` (a Span: another thread's
+    `current()`), the span joins that span's tree without being its
+    child (module docstring); None opens an ordinary span."""
     if not _enabled:
         if hist is not None:
             return hist.time(**(hist_labels or {}))
         return NOOP_SPAN
-    return Span(name, hist, hist_labels, attrs)
+    return Span(name, hist, hist_labels, attrs, follows)
 
 
 def add_attrs(**attrs: Any) -> None:

@@ -273,8 +273,14 @@ class BucketedVerifier:
         return (ok, n, size_ok)
 
     def gather(self, handle) -> np.ndarray:
-        """Block on a dispatch() handle and return the bitmap."""
+        """Block on a dispatch() handle and return the bitmap: first the
+        device finishing the program (`gather_ready`, however long it
+        has yet to run or to start), then the copy to the host, which
+        assembles a mesh's shards, and the size mask (`gather_fetch`)."""
         ok, n, size_ok = handle
         if ok is None:
             return size_ok
-        return np.asarray(ok)[:n] & size_ok
+        with trace.span("gather_ready"):
+            ok.block_until_ready()
+        with trace.span("gather_fetch"):
+            return np.asarray(ok)[:n] & size_ok

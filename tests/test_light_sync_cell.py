@@ -335,8 +335,12 @@ def test_a_traced_rehearsal_reports_the_light_clients_metrics(tiny):
     # and the set-up's compiles where the process had the programs
     unlisted = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m}
     off_chip = {"sigverify_roofline", "verify_mfu", "kernel_device_ms", "device_idle_share",
-                "ladder_device_ms", "decode_points_device_ms", "setup_cache_load_s"}  # fmt: skip
+                "ladder_device_ms", "decode_points_device_ms", "setup_cache_load_s",
+                "gather_device_idle_ms"}  # fmt: skip
     assert unlisted - off_chip - set(got) == set()
+    # the gather's split adds up to its wait, less collections in the job
+    split = [got[m]["value"] for m in ("gather_handoff_ms", "gather_ready_ms", "gather_fetch_ms")]
+    assert min(split) >= 0 and sum(split) <= got["gather_wait_ms"]["value"] * (1 + 1e-9)
     assert got["signbytes_host_ms"]["value"] > 0 and got["commit_plan_host_ms"]["value"] > 0
     assert all(isinstance(m["value"], (int, float)) for m in got.values())
     json.dumps(result)

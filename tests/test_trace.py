@@ -8,6 +8,7 @@ import asyncio
 import contextlib
 import gc
 import json
+import threading
 
 import pytest
 
@@ -233,6 +234,105 @@ class TestMirrorRootAndCollector:
         assert trace._on_gc not in gc.callbacks
 
 
+class TestFollows:
+    """`span(..., follows=s)`: a span on another thread (the gather's
+    job on its watchdog, crypto/tpu_verifier.py) in the tree of the
+    span it follows, and no child of it."""
+
+    def _on_a_thread(self, fn):
+        t = threading.Thread(target=fn)
+        t.start()
+        t.join()
+
+    def test_a_follower_shares_the_root_keeps_no_parent_and_nests_its_own(self):
+        trace.enable()
+        seen = {}
+        with trace.span("request"):
+            with trace.span("waiting") as waiting:
+
+                def job():
+                    with trace.span("job", follows=waiting, lanes=2) as j:
+                        seen["current"] = trace.current()
+                        with trace.span("step"):
+                            pass
+                    seen["after"] = trace.current()
+                    seen["job"] = j
+
+                self._on_a_thread(job)
+        step, job, waiting, request = trace.snapshot()
+        assert (step.name, job.name) == ("step", "job")
+        assert job is seen["job"] is seen["current"] and seen["after"] is None
+        assert job.root_id == waiting.root_id == request.span_id
+        assert job.parent_id == 0 and job.tid != waiting.tid
+        assert job.attrs == {"lanes": 2, "follows": waiting.span_id}
+        # its children nest under it, in the followed span's tree
+        assert step.parent_id == job.span_id and step.root_id == request.span_id
+        # the followed span gains no child
+        assert [s for s in trace.snapshot() if s.parent_id == waiting.span_id] == []
+        args = {
+            e["name"]: e["args"]
+            for e in json.loads(trace.to_chrome_trace())["traceEvents"]
+        }
+        assert args["job"]["follows"] == args["waiting"]["span_id"]
+        assert args["job"]["parent_id"] == 0
+        assert args["job"]["root_id"] == args["request"]["span_id"]
+
+    def test_on_the_same_thread_a_follower_is_current_but_no_child(self):
+        trace.enable()
+        with trace.span("waiting") as waiting:
+            with trace.span("job", follows=waiting) as job:
+                assert trace.current() is job
+                with trace.span("step"):
+                    pass
+            assert trace.current() is waiting
+        step, job, waiting = trace.snapshot()
+        assert job.parent_id == 0 and job.root_id == waiting.span_id
+        assert step.parent_id == job.span_id
+
+    def test_none_opens_an_ordinary_span(self):
+        trace.enable()
+        with trace.span("outer") as outer:
+            with trace.span("job", follows=None):
+                pass
+        job, _outer = trace.snapshot()
+        assert job.parent_id == outer.span_id and "follows" not in job.attrs
+
+    def test_disabled_follower_is_the_noop_singleton(self):
+        trace.enable()
+        with trace.span("waiting") as waiting:
+            pass
+        trace.disable()
+        made = []
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(trace.Span, "__init__", lambda *a, **k: made.append(a))
+            assert trace.span("job", follows=waiting, key="k") is trace.NOOP_SPAN
+            assert trace.span("job", follows=None) is trace.NOOP_SPAN
+        assert made == []
+
+    def test_a_follower_is_mirrored_on_its_own_thread(self):
+        log = []
+
+        @contextlib.contextmanager
+        def mirror(name):
+            log.append((name, threading.get_ident()))
+            yield
+
+        trace.set_mirror(mirror)
+        try:
+            trace.enable()
+            with trace.span("waiting") as waiting:
+
+                def job():
+                    with trace.span("job", follows=waiting):
+                        pass
+
+                self._on_a_thread(job)
+            assert [name for name, _tid in log] == ["waiting", "job"]
+            assert log[0][1] == threading.get_ident() != log[1][1]
+        finally:
+            trace.set_mirror(None)
+
+
 @contextlib.contextmanager
 def _device_seam(chunk):
     """The device factories installed on the CPU backend, streaming as
@@ -305,7 +405,8 @@ def test_commit_verification_phase_tree():
         _verify_both(20)  # verify_commit: 8 + 8 + 4; light: 8 + 6
     spans = trace.snapshot()
     by_id = {s.span_id: s for s in spans}
-    roots = [s for s in spans if not s.parent_id]
+    # the gathers' jobs follow their `tpu_gather` (tests/test_gather_spans.py)
+    roots = [s for s in spans if not s.parent_id and "follows" not in s.attrs]
     assert [s.name for s in roots] == [
         "commit_decode", "batch_accumulate",
         "commit_decode", "batch_accumulate",
