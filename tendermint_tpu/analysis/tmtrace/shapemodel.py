@@ -14,7 +14,8 @@ set and fails the drift gate until `scripts/lint.py
 Three signature families:
 
 - *bucketed*: concrete per-bucket avals (the ed25519/sr25519 tiles,
-  sha512 with its symbolic message-length dimension `M`),
+  sha512 and sr25519's merlin challenge with a symbolic message-length
+  dimension `M`),
 - *power-of-two*: merkle's `_bucket` (next pow2 ≥ n, min 8) yields an
   unbounded but structured family, recorded symbolically,
 - *mesh-sharded*: ops/verifier.py's per-mesh programs (one jit site
@@ -31,7 +32,7 @@ exists to force.
 
 Trace cases: each entry also says how to build concrete
 (fn, avals) pairs for the no-TPU compile gate. `cost="fast"` cases
-(sha256/sha512/merkle — <0.5 s each) run in the default tier-1 gate;
+(sha256/sha512/merlin/merkle — <0.5 s each) run in the default tier-1 gate;
 `cost="heavy"` cases (the crypto tiles, ~6-8 s of tracing EACH) run
 only in the full sweep
 (`scripts/lint.py --trace-full`, timed by bench.py's
@@ -76,6 +77,13 @@ def _buckets() -> Tuple[int, ...]:
     from ...config import DEFAULT_BUCKET_SIZES
 
     return tuple(DEFAULT_BUCKET_SIZES)
+
+
+def _merlin_buckets() -> Tuple[int, ...]:
+    """The buckets the sr25519 verifier launches its merlin program at."""
+    from ...config import MERLIN_DEVICE_LANES
+
+    return tuple(b for b in _buckets() if b >= MERLIN_DEVICE_LANES)
 
 
 class TraceCase:
@@ -143,7 +151,7 @@ def _sr_tile_case(b: int) -> TraceCase:
         from ...ops.sr25519_kernel import _verify_tile_sr
 
         return _verify_tile_sr, _avals(
-            ((32, b), "i32"), ((64, b), "i32"), ((32, b), "i32")
+            ((32, b), "i32"), ((64, b), "i32"), ((64, b), "i32")
         )
 
     return TraceCase(
@@ -163,6 +171,20 @@ def _sha512_case(b: int, mlen: int) -> TraceCase:
     return TraceCase(
         "ops/ed25519_kernel.py:sha512_fixed",
         f"sha512@M{mlen}x{b}",
+        "fast",
+        build,
+    )
+
+
+def _merlin_case(b: int, mlen: int) -> TraceCase:
+    def build():
+        from ...ops.merlin_kernel import merlin_challenge
+
+        return merlin_challenge, _avals(((mlen + 64, b), "u8"))
+
+    return TraceCase(
+        "ops/sr25519_kernel.py:merlin_challenge",
+        f"merlin@M{mlen}x{b}",
         "fast",
         build,
     )
@@ -241,7 +263,7 @@ def _build_model() -> Dict[str, RootModel]:
         "ops/sr25519_kernel.py:_verify_tile_sr",
         "heavy",
         lambda: [
-            _sig([(f"32,{b}", "i32"), (f"64,{b}", "i32"), (f"32,{b}", "i32")])
+            _sig([(f"32,{b}", "i32"), (f"64,{b}", "i32"), (f"64,{b}", "i32")])
             for b in _buckets()
         ],
         lambda full: [
@@ -249,6 +271,22 @@ def _build_model() -> Dict[str, RootModel]:
         ]
         if full
         else [],
+    )
+    add(
+        "ops/sr25519_kernel.py:merlin_challenge",
+        "fast",
+        lambda: [
+            _sig([(f"M+64,{b}", "u8")]) + " M∈msg-len"
+            for b in _merlin_buckets()
+        ],
+        lambda full: [
+            _merlin_case(b, REP_MSG_LEN)
+            for b in (
+                _merlin_buckets()
+                if full
+                else (min(_merlin_buckets()), max(_buckets()))
+            )
+        ],
     )
     add(
         "ops/merkle_kernel.py:S.inner_hash_batch",
@@ -283,8 +321,9 @@ def _build_model() -> Dict[str, RootModel]:
             "roundup(b, mesh) per mesh size M∈msg-len"
             for b in _buckets()
         ],
-        # no direct trace: the bodies are the ed25519/sr25519 tile and
-        # sha512 entries, each chip running one on its own shard
+        # no direct trace: the bodies are the ed25519/sr25519 tile,
+        # sha512 and merlin entries (the last two the "64+M" rows),
+        # each chip running one on its own shard
         # (shard_map: the fused walk a TPU's tile holds, ops/
         # fused_walk.py, is a kernel whose operands are the tile's
         # lanes and that the compiler cannot partition); mesh

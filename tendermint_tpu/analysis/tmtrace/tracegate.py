@@ -12,9 +12,10 @@ classes importable.
 Two tiers (rationale in shapemodel.py):
 
 - default (tier-1, part of the <10 s budget): the fast family —
-  sha512 at the min/max buckets, the merkle inner-hash and proof
-  programs — everything that traces in under half a second. The
-  heavy crypto tiles are skipped *with their names recorded in
+  sha512 at the min/max buckets, sr25519's merlin challenge at the
+  narrowest bucket it runs at and the widest, the merkle inner-hash
+  and proof programs — everything that traces in under half a
+  second. The heavy crypto tiles are skipped *with their names recorded in
   stats["skipped_heavy"]*, never silently; tier-1's differential
   tests trace them at small shapes anyway.
 
@@ -110,6 +111,7 @@ def jit_cache_stats() -> dict:
             ("ed25519_tile_cache", K.Ed25519Verifier._TILE),
             ("ed25519_sha512_cache", K._SHA512),
             ("sr25519_tile_cache", SR.Sr25519Verifier._TILE),
+            ("sr25519_merlin_cache", SR._MERLIN),
         ):
             if hasattr(fn, "_cache_size"):
                 out[name] = fn._cache_size()

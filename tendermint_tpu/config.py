@@ -46,6 +46,15 @@ MODE_SEED = "seed"
 # stays a multiple of the 128-lane vector tile and cuts that to 18%.
 DEFAULT_BUCKET_SIZES = (8, 32, 128, 512, 2048, 8192, 12288, 16384)
 
+# The narrowest bucket whose sr25519 merlin challenges a device program
+# makes (ops/sr25519_kernel.py). On a TPU v5e host the program's rows
+# and launch cost the host about as much as 128 transcripts, and half of
+# what 512 do; narrower launches (singles, the install's probe, small
+# sets) keep the host's transcripts and compile no program a message
+# length. Here for the same reason: tmtrace's shape model reads it
+# without importing jax.
+MERLIN_DEVICE_LANES = 512
+
 
 def bucket_for(n: int, sizes) -> int:
     """Smallest configured bucket >= n (`sizes` ascending), or n itself

@@ -245,9 +245,10 @@ def drain_classes(pending: dict) -> dict:
     verifier (a device verifier streams full chunks from inside it) and
     launch() sends its remainder. A class whose launches cost the host
     byte rows alone goes before one that makes an operand on the host
-    (`host_operand`: sr25519's merlin), so that the device starts
-    soonest and the costlier host work runs under device time; among
-    equals, in `pending`'s order. Phase 2, in the same order:
+    at the width it launches (`host_operand`: sr25519's merlin below
+    its device width), so that the device starts soonest and the
+    costlier host work runs under device time; among equals, in
+    `pending`'s order. Phase 2, in the same order:
     drain_and_cache() each, so a class's cache is populated under the
     next one's tiles. Every class is verified whatever an earlier one
     answered, and the heap settles once, after the last gather. Returns
@@ -276,7 +277,9 @@ def drain_classes(pending: dict) -> dict:
             )
             for key_type, cols in pending.items()
         ]
-        batches.sort(key=lambda batch: batch[2].host_operand)
+        batches.sort(
+            key=lambda batch: batch[2].host_operand(len(batch[1].positions))
+        )
         try:
             overlapped = 0
             for key_type, cols, bv in batches:

@@ -119,12 +119,13 @@ class BatchVerifier(ABC):
         ):
             self.add(pub_key, message, signature)
 
-    # True when launching a window costs the host more than joining
-    # byte rows (a device verifier whose kernel takes an operand made
-    # on the host). crypto.batch.drain_classes launches such a class
-    # last, so that its host work runs under the other classes' device
-    # time.
-    host_operand = False
+    def host_operand(self, n: int) -> bool:
+        """True when launching `n` queued triples costs the host more
+        than joining byte rows (a device verifier whose kernel takes an
+        operand made on the host at that width).
+        crypto.batch.drain_classes launches such a class last, so that
+        its host work runs under the other classes' device time."""
+        return False
 
     def launch(self) -> bool:
         """Start whatever is queued without waiting for it, so that the

@@ -3,10 +3,10 @@
 The Fiat-Shamir transcript construction used by schnorrkel/sr25519
 (reference: crypto/sr25519 via the curve25519-voi dependency, which is
 schnorrkel-compatible; merlin spec: merlin.cool, STROBE spec:
-strobe.sourceforge.io). Pure-Python host implementation — transcripts
-hash a few hundred bytes per signature, so this is never the hot path;
-the curve math is (see crypto/ristretto.py and, device-side, the
-ed25519 kernel family).
+strobe.sourceforge.io). The host implementation, and the oracle of the
+device form of schnorrkel's signing transcript (ops/merlin_kernel.py,
+which replays `_Strobe128` over lengths alone to lay the transcript
+out at trace time).
 """
 
 from __future__ import annotations

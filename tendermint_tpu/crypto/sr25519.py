@@ -66,50 +66,57 @@ def _basemul_encode(k: int) -> bytes:
     return rst.encode(rst.mul_base_ct(k))
 
 
-def _signing_transcript(msg: bytes) -> Transcript:
-    """signing_context([]).bytes(msg) (reference: privkey.go:16,48).
-    The state after the two constant appends is identical for every
-    signature, so it is computed once and cloned per call."""
+def _signing_prefix() -> Transcript:
+    """signing_context([]) before its message (reference:
+    privkey.go:16): the state after the two constant appends is
+    identical for every signature, so it is computed once and cloned
+    (or, on the device, laid out as a constant: ops/merlin_kernel.py)."""
     global _SIGNING_PREFIX
     if _SIGNING_PREFIX is None:
         t = Transcript(b"SigningContext")
         t.append_message(b"", b"")  # empty context
         _SIGNING_PREFIX = t
-    t = _SIGNING_PREFIX.clone()
+    return _SIGNING_PREFIX
+
+
+def _signing_transcript(msg: bytes) -> Transcript:
+    """signing_context([]).bytes(msg) (reference: privkey.go:16,48)."""
+    t = _signing_prefix().clone()
     t.append_message(b"sign-bytes", msg)
     return t
 
 
-def _challenge(t: Transcript, pk_bytes: bytes, r_bytes: bytes) -> int:
-    """The schnorrkel Fiat-Shamir challenge k (sign.rs):
-    proto-name, sign:pk, sign:R, then a 512-bit scalar from sign:c."""
+def _challenge_wide(t: Transcript, pk_bytes: bytes, r_bytes: bytes) -> bytes:
+    """The 64 challenge bytes of schnorrkel's Fiat-Shamir (sign.rs):
+    proto-name, sign:pk, sign:R, then 512 bits from sign:c."""
     t.append_message(b"proto-name", b"Schnorr-sig")
     t.append_message(b"sign:pk", pk_bytes)
     t.append_message(b"sign:R", r_bytes)
-    wide = t.challenge_bytes(b"sign:c", 64)
-    return int.from_bytes(wide, "little") % L
+    return t.challenge_bytes(b"sign:c", 64)
 
 
-def challenge_batch(pks, msgs, rs) -> list:
-    """Fiat-Shamir challenges for a whole batch: (G, 64)-vectorized
+def _challenge(t: Transcript, pk_bytes: bytes, r_bytes: bytes) -> int:
+    """The challenge scalar k: the wide bytes reduced mod L."""
+    return int.from_bytes(_challenge_wide(t, pk_bytes, r_bytes), "little") % L
+
+
+def challenge_wides(pks, msgs, rs):
+    """The wide challenge bytes for a whole batch: (G, 64)-vectorized
     merlin transcripts per message-length group (crypto/merlin.py
     TranscriptBatch; the STROBE control flow depends only on lengths),
-    permuted with one native keccakf_n call per step. Returns one
-    scalar int (already reduced mod L) per (pk, msg, R) triple, in
-    input order. This is the host-prep fast path for the sr25519
-    device verifier (ops/sr25519_kernel.py)."""
+    permuted with one native keccakf_n call per step. Returns an (n, 64)
+    uint8 array, a row per (pk, msg, R) triple in input order: the
+    sr25519 tile reduces them mod L itself (ops/sr25519_kernel.py)."""
     import numpy as np
 
     from .merlin import TranscriptBatch
 
-    # ensure the cached signing-context prefix exists
-    _signing_transcript(b"")
-    out: list = [None] * len(msgs)
+    out = np.empty((len(msgs), 64), dtype=np.uint8)
     groups: dict = {}
     for i, m in enumerate(msgs):
         groups.setdefault(len(m), []).append(i)
     for mlen, idxs in groups.items():
-        tb = TranscriptBatch(_SIGNING_PREFIX, len(idxs))
+        tb = TranscriptBatch(_signing_prefix(), len(idxs))
         rows = lambda items, w: np.frombuffer(  # noqa: E731
             b"".join(items), dtype=np.uint8
         ).reshape(len(idxs), w)
@@ -119,12 +126,17 @@ def challenge_batch(pks, msgs, rs) -> list:
         tb.append_message_const(b"proto-name", b"Schnorr-sig")
         tb.append_messages(b"sign:pk", rows([pks[i] for i in idxs], 32))
         tb.append_messages(b"sign:R", rows([rs[i] for i in idxs], 32))
-        wides = tb.challenge_bytes(b"sign:c", 64)
-        for row, i in enumerate(idxs):
-            out[i] = (
-                int.from_bytes(wides[row].tobytes(), "little") % L
-            )
+        out[idxs] = tb.challenge_bytes(b"sign:c", 64)
     return out
+
+
+def challenge_batch(pks, msgs, rs) -> list:
+    """challenge_wides' rows as scalar ints reduced mod L, in input
+    order."""
+    return [
+        int.from_bytes(wide.tobytes(), "little") % L
+        for wide in challenge_wides(pks, msgs, rs)
+    ]
 
 
 def _native_verify_one(

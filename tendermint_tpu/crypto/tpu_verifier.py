@@ -504,12 +504,18 @@ class _TpuBatchVerifier(BatchVerifier):
                 self._stream_fault = e
             span.set(bucket=self._last_bucket)
 
-    @property
-    def host_operand(self) -> bool:
-        """Whether the backing verifier makes its tile's third operand
-        on the host (ops/verifier.py `host_operand`: sr25519's merlin
-        challenges are, ed25519's SHA-512 is a device program)."""
-        return bool(getattr(self._backing(), "host_operand", False))
+    def host_operand(self, n: int) -> bool:
+        """Whether launching `n` triples makes a tile's third operand on
+        the host: the backing's answer (ops/verifier.py `host_operand`:
+        sr25519's merlin challenges in narrow launches; ed25519's
+        SHA-512 is a device program) for the narrowest launch, the
+        remainder past the chunks that stream."""
+        ask = getattr(self._backing(), "host_operand", None)
+        if ask is None:
+            return False
+        if n > self.STREAM_CHUNK and self._streaming():
+            n = n % self.STREAM_CHUNK or self.STREAM_CHUNK
+        return bool(ask(n))
 
     def launch(self) -> bool:
         """Dispatch the remainder now and return without gathering: the

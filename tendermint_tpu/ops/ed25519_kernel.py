@@ -474,32 +474,14 @@ class Ed25519Verifier(BucketedVerifier):
             bucket - len(pubkeys),
         )
 
-    def _third_operand(self, pubkeys, msgs, sigs, bucket, packed):
-        """(64, bucket) rows of SHA512(R || A || M), device-hashed
-        (ops/sha512_kernel.py compiles one program per length). `packed`
-        is the single-length case's pre-image: one launch whose digests
-        never leave the device. Mixed lengths take one launch a length
-        group and meet on the host."""
-        if packed is not None:
-            return self._launch(_SHA512, ROWS, bucket, packed)
-        groups: dict = {}
-        for i, m in enumerate(msgs):
-            groups.setdefault(len(m), []).append(i)
-        dig = np.zeros((64, bucket), dtype=np.uint8)
-        for mlen, idxs in groups.items():
-            g = len(idxs)
-            gb = self._bucket(g)
-            pre = _join_cols(
-                [
-                    sigs[i][:32] + pubkeys[i] + msgs[i]
-                    for i in idxs
-                ],
-                64 + mlen,
-                gb - g,
-            )
-            out = np.asarray(self._launch(_SHA512, ROWS, gb, pre))
-            dig[:, idxs] = out[:, :g]
-        return dig
+    def _operand(self, pubkeys, msgs, sigs, bucket, packed):
+        """(64, bucket) rows of SHA512(R || A || M) for one message
+        length: one launch (ops/sha512_kernel.py compiles one program
+        per length) whose digests stay on the device. `packed` is the
+        pre-image `pack_rows` joined; a length group joins its own."""
+        if packed is None:
+            packed = self._pack_operand(pubkeys, msgs, sigs, bucket)
+        return self._launch(_SHA512, ROWS, bucket, packed)
 
 
 _DEFAULT: Optional[Ed25519Verifier] = None

@@ -10,12 +10,14 @@ is prime order):
 
     [s]B - [k]A == R   (as ristretto255 group elements)
 
-with k the merlin-transcript Fiat-Shamir challenge. The merlin/STROBE
-transcript (Keccak-f permutations over a byte stream) stays on host —
-crypto/merlin.py backed by the native keccakf (tendermint_tpu/native) —
-because message lengths vary per signature; everything from the 32-byte
-challenge onward runs on device:
+with k the merlin-transcript Fiat-Shamir challenge. Its 64 wide bytes
+come from a device program of their own where a launch is wide enough
+to pay for one (ops/merlin_kernel.py, one program a message length,
+MERLIN_DEVICE_LANES), and from the host's batched transcripts below
+that (crypto/sr25519.py challenge_wides over the native keccakf);
+everything from the wide challenge onward runs in the tile:
 
+    k = wide mod L (the reduction the ed25519 tile applies to SHA-512)
     ristretto decode of A and R (RFC 9496 §4.3.1, incl. canonicity)
     s < L canonicality + v1 marker-bit check
     [s]B - [k]A via the shared Horner dual-mult
@@ -38,6 +40,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..config import MERLIN_DEVICE_LANES
 from ..crypto import ed25519_math as em
 from ..libs import trace
 from . import field25519 as F
@@ -46,11 +49,13 @@ from .ed25519_kernel import (
     _bytes_const,
     _fe_from_bytes_dev,
     _lt_const_dev,
+    _mod_l_dev,
     _nibbles_dev,
     _s_lt_l_dev,
     dual_mult_sb_minus_ka,
 )
-from .verifier import BucketedVerifier, _join_cols
+from .merlin_kernel import merlin_challenge
+from .verifier import ROWS, BucketedVerifier, _join_cols
 
 __all__ = ["Sr25519Verifier", "batch_verify_host"]
 
@@ -146,22 +151,23 @@ def _ristretto_eq_dev(p3: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
     return eq1 | eq2
 
 
-def _verify_tile_sr(pk_b, sig_b, k_b) -> jnp.ndarray:
+def _verify_tile_sr(pk_b, sig_b, c_b) -> jnp.ndarray:
     """The full sr25519 device program: byte rows in, bitmap out.
 
     pk_b (32, N) ristretto pubkey bytes; sig_b (64, N) R || s with the
-    schnorrkel v1 marker in bit 511; k_b (32, N) LE bytes of the
-    merlin challenge already reduced mod L on host. Returns (N,) bool."""
+    schnorrkel v1 marker in bit 511; c_b (64, N) LE bytes of the wide
+    merlin challenge, from the device or the host alike. Returns (N,)
+    bool."""
     pk = pk_b.astype(jnp.int32)
     sig = sig_b.astype(jnp.int32)
-    kb = k_b.astype(jnp.int32)
+    wide = c_b.astype(jnp.int32)
     # stage names: one vocabulary with ed25519_kernel._verify_tile
     with jax.named_scope("scalar_prep"):
         marker_ok = (sig[63] >> 7) == 1  # schnorrkel v1 marker bit
         s = sig[32:] & _TOPCLEAR
         s_ok = _s_lt_l_dev(s)
         dS = _nibbles_dev(s)
-        dk = _nibbles_dev(kb)
+        dk = _nibbles_dev(_mod_l_dev(wide))
     with jax.named_scope("ristretto_decode"):
         A, okA = ristretto_decode_dev(pk)
         R, okR = ristretto_decode_dev(sig[:32])
@@ -170,33 +176,55 @@ def _verify_tile_sr(pk_b, sig_b, k_b) -> jnp.ndarray:
         return _ristretto_eq_dev(acc, R) & okA & okR & s_ok & marker_ok
 
 
+_MERLIN = jax.jit(merlin_challenge)
+
+
+def _merlin_rows(pubkeys, msgs, sigs, pad: int) -> np.ndarray:
+    """(len + 64, n + pad) rows of M || A || R, the merlin program's
+    operand, for messages of one length (three joins: a third less
+    host time than one of concatenated rows)."""
+    rows = [
+        _join_cols(pubkeys, 32, pad),
+        _join_cols([sig[:32] for sig in sigs], 32, pad),
+    ]
+    if len(msgs[0]):  # an empty message has no rows to join
+        rows.insert(0, _join_cols(msgs, len(msgs[0]), pad))
+    return np.concatenate(rows)
+
+
 class Sr25519Verifier(BucketedVerifier):
     """Bucketed sr25519 batch verifier: the shared body with
-    `_verify_tile_sr` as its program and the merlin challenges, from
-    the host, as the third operand."""
+    `_verify_tile_sr` as its program and the wide merlin challenges as
+    the third operand, made by a program of their own on the device at
+    MERLIN_DEVICE_LANES and up, by the host below."""
 
     _TILE = staticmethod(jax.jit(_verify_tile_sr))
-    host_operand = True
 
-    def _third_operand(self, pubkeys, msgs, sigs, bucket, packed):
-        """(32, bucket) rows of the merlin Fiat-Shamir challenges,
-        vectorized per message-length group (crypto/sr25519.py
-        challenge_batch — one native keccakf_n permutation call per
-        transcript step)."""
-        from ..crypto.sr25519 import challenge_batch
+    def host_operand(self, n: int) -> bool:
+        """Below MERLIN_DEVICE_LANES the transcripts are host work."""
+        return self._bucket(n) < MERLIN_DEVICE_LANES
 
-        n = len(pubkeys)
-        with trace.span("merlin_challenges", n=n):
-            return _join_cols(
-                [
-                    k.to_bytes(32, "little")
-                    for k in challenge_batch(
-                        pubkeys, msgs, [sig[:32] for sig in sigs]
-                    )
-                ],
-                32,
-                bucket - n,
-            )
+    def _operand(self, pubkeys, msgs, sigs, bucket, packed):
+        """(64, bucket) rows of the wide merlin challenges for one
+        message length, under a `merlin_challenges` span saying which
+        side made them (`form`) and for how many rows (`n`). At
+        MERLIN_DEVICE_LANES and wider, one launch of the merlin program
+        (the span holds its rows' join and the launch), whose
+        challenges stay on the device; narrower, the host's batched
+        transcripts (crypto/sr25519.py challenge_wides, one native
+        keccakf_n call a transcript step)."""
+        n = len(msgs)
+        if bucket >= MERLIN_DEVICE_LANES:
+            with trace.span("merlin_challenges", form="device", n=n):
+                rows = _merlin_rows(pubkeys, msgs, sigs, bucket - n)
+                return self._launch(_MERLIN, ROWS, bucket, rows)
+        from ..crypto.sr25519 import challenge_wides
+
+        wide = np.zeros((64, bucket), dtype=np.uint8)
+        with trace.span("merlin_challenges", form="host", n=n):
+            rs = [sig[:32] for sig in sigs]
+            wide[:, :n] = challenge_wides(pubkeys, msgs, rs).T
+        return wide
 
 
 _DEFAULT: Optional[Sr25519Verifier] = None

@@ -5,7 +5,8 @@ batch to a configured bucket, mask malformed sizes, join the byte rows
 on the host, place them, launch the key class's tile program and gather
 the bitmap. A key class (ops/ed25519_kernel.py, ops/sr25519_kernel.py)
 names its tile program and builds its third operand: SHA-512 digests
-hashed on the device, or merlin challenges from the host.
+hashed on the device, or merlin challenges from a device program or,
+in narrow launches, the host.
 
 Placement is an argument, not a subclass. Without a mesh the rows go to
 the default device and the module's shared jitted program runs them.
@@ -124,17 +125,13 @@ class BucketedVerifier:
     """Compiled, bucketed batch verifier of one key class.
 
     Subclasses set `_TILE`, the module's shared jitted tile program
-    ((32, N) pubkey rows, (64, N) signature rows, a third operand ->
-    (N,) bitmap), and define `_third_operand`. Shapes are bucketed (pad
-    to the next configured size) so that a handful of programs serve
-    every batch. Thread-compatible for the asyncio runtime: a dispatch
-    is a synchronous device invocation."""
+    ((32, N) pubkey rows, (64, N) signature rows, a (64, N) third
+    operand -> (N,) bitmap), and define `_operand`. Shapes are bucketed
+    (pad to the next configured size) so that a handful of programs
+    serve every batch. Thread-compatible for the asyncio runtime: a
+    dispatch is a synchronous device invocation."""
 
     _TILE = None  # staticmethod(jax.jit(tile function))
-    # whether `_third_operand` is host work (sr25519's merlin) and not
-    # a device program of its own (ed25519's SHA-512): the seam launches
-    # a class that packs byte rows alone first (crypto.batch.drain_classes)
-    host_operand = False
 
     def __init__(
         self, bucket_sizes: Optional[Sequence[int]] = None, mesh=None
@@ -212,10 +209,43 @@ class BucketedVerifier:
         class that has none."""
         return None
 
-    def _third_operand(self, pubkeys, msgs, sigs, bucket: int, packed):
-        """(rows, bucket) third operand of the tile, on the host or the
-        device; `packed` is what `_pack_operand` returned."""
+    def host_operand(self, n: int) -> bool:
+        """Whether a launch of `n` signatures makes its third operand
+        on the host (sr25519's merlin in narrow launches) and not in a
+        device program of its own (ed25519's SHA-512): the seam
+        launches a class that packs byte rows alone first
+        (crypto.batch.drain_classes)."""
+        return False
+
+    def _operand(self, pubkeys, msgs, sigs, bucket: int, packed):
+        """(64, bucket) third operand of the tile for messages of one
+        length, on the host or the device; `packed` is what
+        `_pack_operand` returned, None for a length group."""
         raise NotImplementedError
+
+    def _third_operand(self, pubkeys, msgs, sigs, bucket: int, packed):
+        """(64, bucket) third operand of the tile. One message length
+        (every sign-bytes of a Commit): `_operand` over the batch, so
+        what the device makes never leaves it. Mixed lengths: one
+        `_operand` a length group at the group's own bucket, the groups
+        meeting on the host."""
+        if len(set(map(len, msgs))) == 1:
+            return self._operand(pubkeys, msgs, sigs, bucket, packed)
+        groups: dict = {}
+        for i, m in enumerate(msgs):
+            groups.setdefault(len(m), []).append(i)
+        out = np.zeros((64, bucket), dtype=np.uint8)
+        for idxs in groups.values():
+            g = len(idxs)
+            part = self._operand(
+                [pubkeys[i] for i in idxs],
+                [msgs[i] for i in idxs],
+                [sigs[i] for i in idxs],
+                self._bucket(g),
+                None,
+            )
+            out[:, idxs] = np.asarray(part)[:, :g]
+        return out
 
     def verify(
         self,
@@ -263,8 +293,8 @@ class BucketedVerifier:
                     for sig, ok in zip(sigs, size_ok)
                 ]
             # host work is byte joins only (and sr25519's merlin
-            # transcripts); limb unpacking, scalar canonicality, digits
-            # and the curve math all run on device
+            # transcripts in narrow launches); limb unpacking, scalar
+            # canonicality, digits and the curve math all run on device
             pk_b = _join_cols(pubkeys, 32, pad)
             sig_b = _join_cols(sigs, 64, pad)
             packed = self._pack_operand(pubkeys, msgs, sigs, bucket)

@@ -59,8 +59,9 @@ class ShardedEd25519Verifier(K.Ed25519Verifier):
 
 class ShardedSr25519Verifier(SR.Sr25519Verifier):
     """Sr25519Verifier partitioned over a mesh — same layout as the
-    ed25519 variant: 1-D data-parallel over `sig`, host packing
-    (merlin challenges + byte joins) unchanged. Reference analog:
+    ed25519 variant: 1-D data-parallel over `sig`, the merlin program
+    (where the device form runs) partitioned like the tile and its
+    challenges left on the mesh for it. Reference analog:
     crypto/sr25519/batch.go behind the crypto.BatchVerifier seam."""
 
     def __init__(

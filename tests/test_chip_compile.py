@@ -134,8 +134,20 @@ def test_sr25519_tile(compile_for_chip, one_chip, lanes):
         jax.jit(SR._verify_tile_sr),
         _rows(32, lanes, one_chip),
         _rows(64, lanes, one_chip),
-        _rows(32, lanes, one_chip),
+        _rows(64, lanes, one_chip),
     )
+    _assert_fits(compiled)
+
+
+def test_merlin_challenge_at_the_cells_sign_bytes(compile_for_chip, one_chip, lanes):
+    """sr25519's merlin program over M || A || R at the 115-byte
+    sign-bytes the benchmark's commits sign: the 24 rounds stay one
+    loop on the chip too, and the program is small."""
+    from tendermint_tpu.ops.sr25519_kernel import _MERLIN, MERLIN_DEVICE_LANES
+
+    assert lanes >= MERLIN_DEVICE_LANES
+    lowered, compiled = compile_for_chip(_MERLIN, _rows(115 + 64, lanes, one_chip))
+    assert "stablehlo.while" in lowered.as_text()
     _assert_fits(compiled)
 
 
@@ -212,7 +224,7 @@ def _assert_batch_axis_partitioned(compiled, n: int, row_counts) -> None:
 # (verifier, rows of its three inputs)
 SHARDED_TILES = {
     "ed25519": ("ShardedEd25519Verifier", (32, 64, 64)),
-    "sr25519": ("ShardedSr25519Verifier", (32, 64, 32)),
+    "sr25519": ("ShardedSr25519Verifier", (32, 64, 64)),
 }
 
 
@@ -250,5 +262,25 @@ def test_sharded_sha512_on_four_chips(compile_for_chip, four_chips, lanes):
     )
     assert "stablehlo.while" not in lowered.as_text()
     _assert_batch_axis_partitioned(compiled, n, (64 + 115, 64))
+    assert "all-gather" not in compiled.as_text()
+    _assert_fits(compiled)
+
+
+def test_sharded_merlin_on_four_chips(compile_for_chip, four_chips, lanes):
+    """The mesh verifier's merlin program over M || A || R at the
+    benchmark's 115-byte sign-bytes, partitioned like the tile: the
+    challenges leave each chip as its own quarter, the tile's operand
+    where it lies."""
+    from tendermint_tpu.ops.sr25519_kernel import _MERLIN
+    from tendermint_tpu.ops.verifier import ROWS
+    from tendermint_tpu.parallel import ShardedSr25519Verifier
+
+    v = ShardedSr25519Verifier(four_chips)
+    n = v._bucket(lanes)
+    mat = NamedSharding(four_chips, P(None, "sig"))
+    _lowered, compiled = compile_for_chip(
+        v._program(_MERLIN, ROWS), _rows(115 + 64, n, mat)
+    )
+    _assert_batch_axis_partitioned(compiled, n, (115 + 64, 64))
     assert "all-gather" not in compiled.as_text()
     _assert_fits(compiled)

@@ -47,7 +47,7 @@ KERNEL = "tpu_custom_call"  # what a Pallas kernel lowers to
 # key class -> (module, tile function, mesh verifier, rows of the inputs)
 TILES = {
     "ed25519": ("ed25519_kernel", "_verify_tile", "ShardedEd25519Verifier", (32, 64, 64)),
-    "sr25519": ("sr25519_kernel", "_verify_tile_sr", "ShardedSr25519Verifier", (32, 64, 32)),
+    "sr25519": ("sr25519_kernel", "_verify_tile_sr", "ShardedSr25519Verifier", (32, 64, 64)),
 }  # fmt: skip
 
 

@@ -47,8 +47,12 @@ class Recording:
 
     bucket_sizes = (8, 32, 128)
 
-    def __init__(self, key: str, log: list, host_operand: bool) -> None:
-        self.key, self.log, self.host_operand = key, log, host_operand
+    def __init__(self, key: str, log: list, host_operand) -> None:
+        self.key, self.log, self._host = key, log, host_operand
+
+    def host_operand(self, n):
+        """A fixed answer, or one a width (the sr25519 kernel's)."""
+        return self._host(n) if callable(self._host) else self._host
 
     def dispatch(self, pks, msgs, sigs):
         self.log.append(("dispatch", self.key, len(pks)))
@@ -192,6 +196,25 @@ def test_every_class_is_in_flight_before_the_first_gather(first, chunk):
     assert cached(vals, commit) == list(range(N))
 
 
+@pytest.mark.parametrize(
+    "narrowest, sr_first", [(8, False), (4, True)], ids=["host-made", "device-made"]
+)
+def test_the_launch_order_follows_the_width_each_class_launches(narrowest, sr_first):
+    """sr25519 at commit index 0 heads the dict. Where its five rows
+    are narrower than the width its operand moves onto the device at,
+    ed25519 launches first; where they are as wide, neither class makes
+    an operand on the host and the dict's order stands."""
+    vals, bid, commit, _privs = mixed_commit(SR)
+    host = {ED: False, SR: lambda n: n < narrowest}
+    with recording_seam(host_operand=host) as (log, _made):
+        verify_commit(CHAIN_ID, vals, bid, 1, commit)
+    order = [SR, ED] if sr_first else [ED, SR]
+    assert calls(log, "dispatch", "gather") == [
+        ("dispatch", order[0]), ("dispatch", order[1]),
+        ("gather", order[0]), ("gather", order[1]),
+    ]  # fmt: skip
+
+
 def test_the_launch_order_follows_the_verifiers_property_not_its_name():
     """With the backings' `host_operand` the other way round, sr25519
     launches first: the order is read from the verifier object."""
@@ -233,7 +256,7 @@ def test_host_verifiers_have_nothing_to_launch():
     assert drain.attrs == {"classes": 2, "overlapped": 0}
     assert cached(vals, commit) == list(range(N))
     cpu = crypto_batch.create_batch_verifier(vals.validators[0].pub_key)
-    assert cpu.launch() is False and cpu.host_operand is False
+    assert cpu.launch() is False and cpu.host_operand(N) is False
     assert cpu.abandon() is None
 
 

@@ -202,7 +202,10 @@ def test_the_manifest_adds_the_four_readers_as_one_block():
     manifest = harness.load_json(f"{harness.ROOT}/BENCHMARK.json")
     new = [m for m in manifest["per_layer"] if m["name"].startswith("gather_") and m["name"] != "gather_wait_ms"]
     assert [m["name"] for m in new] == [*READERS, "gather_device_idle_ms"]
-    assert manifest["per_layer"][-4:] == new
+    at = manifest["per_layer"].index(new[0])
+    # the block sits where it was appended, after the 47 metrics it found
+    assert at == 47 and at + 4 <= len(manifest["per_layer"])
+    assert manifest["per_layer"][at : at + 4] == new
     for m in new:
         assert "workloads" not in m and m["moves"] == "commits_per_s"
         assert m["unit"] == "ms" and m["better"] == "lower"

@@ -46,6 +46,7 @@ EXPECTED_ROOT_IDS = {
     "ops/merkle_kernel.py:S.inner_hash_batch",
     "ops/merkle_kernel.py:_verify_program",
     "ops/sr25519_kernel.py:_verify_tile_sr",
+    "ops/sr25519_kernel.py:merlin_challenge",
     "ops/verifier.py:per_chip",
 }
 
@@ -114,6 +115,15 @@ def test_fast_tier_records_skipped_heavy(head_report):
     assert (
         "ops/sr25519_kernel.py:_verify_tile_sr" in st["skipped_heavy"]
     )
+
+
+def test_fast_tier_traces_the_merlin_program(head_report):
+    """sr25519's merlin challenge traces in the fast tier, as SHA-512
+    does, at the narrowest bucket the verifier launches it at and the
+    widest."""
+    cases = head_report.stats["per_case_ms"]
+    m, top = shapemodel.REP_MSG_LEN, max(shapemodel._buckets())
+    assert {f"merlin@M{m}x512", f"merlin@M{m}x{top}"} <= set(cases)
 
 
 # ---------------------------------------------------------------------------
